@@ -262,6 +262,25 @@ def test_resident_join_fresh_after_db_swap(tmp_path):
     assert sum(len(v) for v in out) > 0
 
 
+def test_detect_batch_device_fault_is_loud_without_host_fallback(db):
+    """--no-host-fallback reaches the resident join through ScanOptions:
+    a device failure fails detection instead of degrading to the host
+    comparator."""
+    from trivy_tpu import faults
+    from trivy_tpu.detector import detect_all
+    from trivy_tpu.scanner import ScanOptions
+    from trivy_tpu.types import ArtifactDetail
+
+    detail = ArtifactDetail(applications=_sbom())
+    faults.configure("device.dispatch@cve:times=-1")
+    try:
+        with pytest.raises(faults.InjectedFault):
+            detect_all(db, "sbom", detail, ScanOptions(host_fallback=False))
+        assert detect_all(db, "sbom", detail, ScanOptions())  # degrades
+    finally:
+        faults.clear()
+
+
 def test_detect_batch_device_fault_degrades_to_host(db):
     """A device.dispatch@cve fault degrades the WHOLE batch to the host
     comparator with identical findings (the parity oracle), and the

@@ -380,6 +380,83 @@ def test_device_backend_init_failure_degrades_to_host(monkeypatch, tmp_path):
     assert report.results[0].secrets[0].rule_id == "github-pat"
 
 
+def test_device_backend_init_failure_is_loud_under_no_host_fallback(
+    monkeypatch, tmp_path
+):
+    """The CLI's --no-host-fallback reaches the secret analyzer's init
+    rung: a backend that cannot initialize fails the scan."""
+    from trivy_tpu import cli
+    from trivy_tpu.fanal.analyzers import secret as secret_analyzer
+
+    (tmp_path / "tree").mkdir()
+    (tmp_path / "tree" / "gh.txt").write_text(f"token {GHP} end\n")
+
+    def boom(*a, **kw):
+        raise RuntimeError("no accelerator: backend init failed")
+
+    monkeypatch.setattr(
+        "trivy_tpu.secret.tpu_scanner.TpuSecretScanner.__init__", boom
+    )
+    monkeypatch.setattr(secret_analyzer, "_scanner_cache", {})
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        cli.main([
+            "fs", "--scanners", "secret", "--backend", "xla",
+            "--no-host-fallback", "--format", "json", "-q",
+            "--cache-dir", str(tmp_path / "cache"),
+            "-o", str(tmp_path / "out.json"), str(tmp_path / "tree"),
+        ])
+
+
+def test_scan_timeout_fails_the_scan_not_one_analyzer(monkeypatch, tmp_path):
+    """--timeout expiring inside an analyzer's finalize must fail the
+    scan: caught by the analyzer containment it used to drop that
+    analyzer's findings from a report that exited 0 (found on the chip,
+    where a cold compile ran a scan past the 300 s default)."""
+    import time
+
+    from trivy_tpu import cli
+    from trivy_tpu.fanal.analyzers import license as license_analyzer
+    from trivy_tpu.licensing.corpus_texts import FULL_TEXTS
+
+    (tmp_path / "tree").mkdir()
+    (tmp_path / "tree" / "LICENSE").write_text(FULL_TEXTS["MIT"])
+    monkeypatch.setattr(
+        license_analyzer._LicenseBatchAnalyzer, "finalize",
+        lambda self: time.sleep(10),
+    )
+    t0 = time.monotonic()
+    rc = cli.main([
+        "fs", "--scanners", "license", "--backend", "cpu", "--timeout", "1",
+        "--format", "json", "-q", "--cache-dir", str(tmp_path / "cache"),
+        "-o", str(tmp_path / "out.json"), str(tmp_path / "tree"),
+    ])
+    assert rc == 1
+    assert time.monotonic() - t0 < 9
+    assert not (tmp_path / "out.json").exists()
+
+
+@pytest.mark.parametrize("host_fallback", [True, False])
+def test_license_auto_device_probe_failure(monkeypatch, host_fallback):
+    """backend='auto' asks jax which platform it has; a probe that fails
+    degrades to the host scorer, or fails under --no-host-fallback."""
+    import jax
+
+    from trivy_tpu.licensing.classify import LicenseClassifier
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    clf = LicenseClassifier(backend="auto", host_fallback=host_fallback)
+    with obs.scan_context(name="probe", enabled=True) as ctx:
+        if host_fallback:
+            assert clf._use_device(64) is False
+            assert ctx.counters.get("license.degraded", 0) == 1
+        else:
+            with pytest.raises(RuntimeError, match="initialize backend"):
+                clf._use_device(64)
+
+
 def test_license_device_leg_falls_back_to_host():
     from trivy_tpu.licensing.classify import LicenseClassifier
     from trivy_tpu.licensing.corpus_texts import FULL_TEXTS

@@ -173,3 +173,29 @@ def test_finalize_order_secret_before_license():
     )
     names = [a.type.value for a in order]
     assert names.index("secret") < names.index("license-file")
+
+
+def test_license_flag_lands_before_the_file_resolves(monkeypatch):
+    """A file spanning several batches on different transfer streams: the
+    batch holding its license header may finish resolving AFTER the file's
+    last batch. Its flag must still land before the file's result is
+    emitted — the license analyzer reads the gate as soon as the secret
+    stream ends (found on the chip, where it dropped license findings)."""
+    from trivy_tpu.secret import tpu_scanner
+
+    orig = tpu_scanner._ScanRun._apply_lic
+
+    def slow_hit(self, segs, lic):
+        if lic:  # the header's rows: resolve late
+            import time
+
+            time.sleep(0.5)
+        return orig(self, segs, lic)
+
+    monkeypatch.setattr(tpu_scanner._ScanRun, "_apply_lic", slow_hit)
+    scanner = build_scanner(feed_streams=3, inflight=1)
+    gate = FusedLicenseGate(license_full=True)
+    body = b"".join(b"x%05d = compute(x%05d)\n" % (i, i) for i in range(3000))
+    files = [("pkg/LICENSE", FULL_TEXTS["MIT"].encode() + b"\n" + body)]
+    for res in scanner.scan_files(iter(files), license_gate=gate):
+        assert gate.should_classify(res.file_path)

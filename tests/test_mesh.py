@@ -64,13 +64,19 @@ def test_round_robin_dispatch_parity():
         dispatch="round_robin", devices=jax.devices()[:4],
     )
     assert rr._match.n_streams == 4
+    # warm-up compiles every (rung, device) placement without counting as
+    # traffic: per-device batch counts only ever see real batches
+    rr.warm_buckets()
+    assert rr._staged.busy.batches() == [0, 0, 0, 0]
     files = [
-        (f"f{i}.txt", f"head\n{SAMPLES[r]}\ntail\n".encode() + b"pad line\n" * 400)
+        (f"f{i}.txt", f"head\n{SAMPLES[r]}\ntail\n".encode()
+         + b"".join(b"pad line %d.%d\n" % (i, j) for j in range(400)))
         for i, r in enumerate(ids * 3)
     ]
     for (path, data), secret in zip(files, rr.scan_files(files)):
         want = cpu.scan_bytes(path, data)
         assert secret.to_dict() == want.to_dict()
+    assert all(n > 0 for n in rr._staged.busy.batches())
 
 
 def test_round_robin_auto_stays_single_on_cpu():

@@ -249,6 +249,37 @@ class TestCompileLedger:
         assert frag.startswith("compiles 0 hbm") or "hbm" in frag
 
 
+    @pytest.mark.parametrize(
+        "platform,stats,want",
+        [
+            ("cpu", None, 1024 << 20),  # TRIVY_TPU_HBM_BUDGET_MB stands in
+            ("tpu", {"bytes_limit": 16 << 30}, 16 << 30),
+            ("tpu", {}, RuntimeError),  # an accelerator must report it
+        ],
+    )
+    def test_device_capacity(self, monkeypatch, platform, stats, want):
+        import jax
+
+        class Dev:
+            id = 0
+            device_kind = "TPU v5 lite"
+
+            def __init__(self):
+                self.platform = platform
+
+            def memory_stats(self):
+                return stats
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [Dev()])
+        monkeypatch.delenv("TRIVY_TPU_HBM_BUDGET_MB", raising=False)
+        st = recorder._State()
+        if want is RuntimeError:
+            with pytest.raises(RuntimeError, match="bytes_limit"):
+                st.capacity_bytes()
+        else:
+            assert st.capacity_bytes() == want
+
+
 # -- diagnostic bundles -------------------------------------------------------
 
 

@@ -180,9 +180,9 @@ class TestAutotuneRecord:
 
     def test_merge_preserves_other_topologies(self, tmp_path):
         path = str(tmp_path / "AUTOTUNE.json")
-        tuning.save_autotune(path, "tpu:8:tunnel", {"feed_streams": 8}, [])
+        tuning.save_autotune(path, "tpu:8:pcie", {"feed_streams": 8}, [])
         tuning.save_autotune(path, TOPO, {"feed_streams": 2}, [])
-        assert tuning.load_autotune(path, "tpu:8:tunnel")["best"] == {
+        assert tuning.load_autotune(path, "tpu:8:pcie")["best"] == {
             "feed_streams": 8
         }
         assert tuning.load_autotune(path, TOPO)["best"] == {
@@ -191,7 +191,7 @@ class TestAutotuneRecord:
 
     def test_mismatched_fingerprint_falls_back_loudly(self, tmp_path, caplog):
         path = str(tmp_path / "AUTOTUNE.json")
-        tuning.save_autotune(path, "tpu:8:tunnel", {"feed_streams": 8}, [])
+        tuning.save_autotune(path, "tpu:8:pcie", {"feed_streams": 8}, [])
         with caplog.at_level(logging.WARNING, logger="trivy_tpu.tuning"):
             cfg = resolve_tuning(opts={}, env={}, autotune_path=path,
                                  topology=TOPO)

@@ -23,3 +23,25 @@ collectives over ICI (``trivy_tpu.parallel``), not RPC fan-out.
 """
 
 __version__ = "0.1.0"
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one home and return
+    the directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is the home
+    (JAX reads it itself, so nothing else is set); otherwise it is
+    ``.jax_cache`` at the checkout root. The path is part of the cache's
+    key, so it is fixed: a directory that moved would never hit. Callers
+    are entry points (``cli.main``, ``chip_smoke.py``), never imports."""
+    import os
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if path:
+        return path
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -624,6 +624,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from trivy_tpu import enable_compile_cache
+
+    enable_compile_cache()
     parser = build_parser()
     ns = parser.parse_args(argv)
     if ns.command is None:

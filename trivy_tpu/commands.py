@@ -173,6 +173,7 @@ def _scan_options(opts) -> ScanOptions:
         scanners=opts.get("scanners", ["secret"]),
         license_full=bool(opts.get("license_full")),
         list_all_pkgs=list_all,
+        host_fallback=not opts.get("no_host_fallback"),
     )
 
 
@@ -189,13 +190,20 @@ def _vuln_client(opts):
     return db
 
 
+class ScanTimeout(BaseException):
+    """``--timeout`` expired. A BaseException, like KeyboardInterrupt: the
+    alarm lands in whatever the main thread runs, often inside a per-file
+    or per-analyzer ``except Exception`` containment, which would log it
+    and drop that analyzer's findings from a scan that then exits 0."""
+
+
 def run(command: str, ns, opts) -> int:
     import signal
 
     timeout = int(opts.get("timeout") or 0)
 
     def on_timeout(signum, frame):
-        raise TimeoutError(f"scan exceeded --timeout={timeout}s")
+        raise ScanTimeout(f"scan exceeded --timeout={timeout}s")
 
     # long-running commands (server, watch loop) are not one scan — the
     # per-scan --timeout alarm does not apply to them
@@ -287,7 +295,7 @@ def run(command: str, ns, opts) -> int:
                 raise ValueError(f"unknown command {command}")
             completed = True
             return rc
-        except TimeoutError as e:
+        except (ScanTimeout, TimeoutError) as e:
             logger.error("%s", e)
             return 1
         except PolicyError as e:

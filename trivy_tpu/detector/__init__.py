@@ -32,7 +32,10 @@ def detect_all(db, target: str, detail: ArtifactDetail, options) -> list[Result]
         apps = sorted(detail.applications, key=lambda a: (a.file_path, a.type))
         # whole-SBOM one-pass join: every app's packages hash-join and
         # dispatch together against the HBM-resident global bound matrix
-        for app, vulns in zip(apps, library.detect_batch(db, apps)):
+        verdicts = library.detect_batch(
+            db, apps, host_fallback=getattr(options, "host_fallback", True)
+        )
+        for app, vulns in zip(apps, verdicts):
             fill_infos(db, vulns)
             if not vulns and not options_list_all(options):
                 continue

@@ -647,7 +647,9 @@ def _resident_join(db) -> "_ResidentJoin | None":
     return rj
 
 
-def detect_batch(db, apps: list[Application]) -> list[list[DetectedVulnerability]]:
+def detect_batch(
+    db, apps: list[Application], host_fallback: bool = True
+) -> list[list[DetectedVulnerability]]:
     """Whole-SBOM detection in ONE pass: every application's packages
     hash-join the resident (ecosystem, name) index together, and every
     candidate's constraints evaluate in a single device dispatch against
@@ -655,7 +657,9 @@ def detect_batch(db, apps: list[Application]) -> list[list[DetectedVulnerability
     per-scan bound re-uploads both collapse (ROADMAP item 2). Falls back
     to per-app :func:`detect` when the batch is too small to beat the
     dispatch overhead, an ecosystem never compiled (un-encodable scheme),
-    or the db lacks the merged prefix index."""
+    or the db lacks the merged prefix index. A failed device join degrades
+    to the host comparator unless ``host_fallback`` is off
+    (``--no-host-fallback``), in which case the failure propagates."""
     import numpy as np
 
     from trivy_tpu import obs
@@ -717,6 +721,8 @@ def detect_batch(db, apps: list[Application]) -> list[list[DetectedVulnerability
     except Exception as e:
         # device leg failed: the host comparator is the parity oracle, so
         # degrade to it instead of failing the scan
+        if not host_fallback:
+            raise
         ctx.count("cve.degraded")
         ctx.health_count("cve.degraded")
         logger.warning(

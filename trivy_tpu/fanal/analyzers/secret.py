@@ -82,6 +82,11 @@ def _shared_scanner(
                         feed_streams=feed_streams, inflight=inflight,
                         prefilter=prefilter, tuning=tuning,
                     )
+                    if scanner.backend == "pallas":
+                        # Mosaic compiles take minutes per kernel shape:
+                        # pay them once per process, concurrently, before
+                        # the first batch instead of one by one mid-scan
+                        scanner.warm_buckets()
                 except Exception as e:
                     # --backend failed at init (jax import, device probe,
                     # kernel compile): the ladder's last rung applies here

@@ -1,7 +1,7 @@
 """Deterministic fault-injection registry (the chaos harness).
 
 PAPER.md §7's accelerator path adds failure domains the reference never
-had — device OOM, tunnel resets, preemption, dropped cache connections —
+had — device OOM, link resets, preemption, dropped cache connections —
 and the retry/breaker/fallback ladder that survives them needs a way to be
 *proven* without real hardware faults. This registry arms scripted failures
 at named sites: instrumented code calls :func:`check(site, key=...)` on its
@@ -84,7 +84,7 @@ class InjectedOom(RuntimeError):
 
 
 class InjectedConnError(ConnectionError):
-    """Scripted connection failure (tunnel reset / dropped socket analog)."""
+    """Scripted connection failure (link reset / dropped socket analog)."""
 
 
 class InjectedIOError(OSError):

@@ -187,12 +187,17 @@ class LicenseClassifier:
         if self.mesh is not None:
             return True
         # auto: route to the device kernel only when an accelerator exists
-        # (XLA-CPU scoring beats the host path on nothing but parity tests)
+        # (XLA-CPU scoring beats the host path on nothing but parity tests).
+        # A backend that cannot even list its devices is a device failure:
+        # loud under --no-host-fallback, a degraded host scan otherwise
         import jax
 
         try:
             return jax.devices()[0].platform not in ("cpu", "METAL")
-        except Exception:
+        except RuntimeError as e:
+            if not self.host_fallback:
+                raise
+            self._note_device_failure(e)
             return False
 
     @classmethod
@@ -584,45 +589,49 @@ class LicenseClassifier:
         shingle blooms and anchor floor resident in device memory across
         calls, scans and classifier instances."""
         if self._scorer is None:
-            from trivy_tpu.licensing.corpus_texts import FULL_TEXTS
             from trivy_tpu.ops import ngram_score as ng
 
-            if not hasattr(self, "_gate_keys"):
-                self._build_scoring()
-            # shingle-gate corpus: raw + normalized full texts (the gate
-            # sees raw file bytes, so both spellings of every license must
-            # populate the bloom) plus every gram-bearing long phrase;
-            # anchor corpus: the short fingerprints the substring lane
-            # must never miss
-            gate_texts: list[str] = []
-            for lic in sorted(FULL_TEXTS):
-                gate_texts.append(FULL_TEXTS[lic])
-                gate_texts.append(normalize(FULL_TEXTS[lic]))
-            short_set = {ph for _li, ph, _a in self._short_gate}
-            gate_texts.extend(
-                ph for _li, ph in self.phrases if ph not in short_set
+            self._scorer = ng.get_bytes_scorer(
+                self.corpus_table, mesh=self.mesh
             )
-            anchor_texts = sorted(short_set)
-
-            def build(model_shards: int):
-                return ng.build_corpus_table32(
-                    self.licenses,
-                    self._full_keys,
-                    self._full_weights,
-                    self._phrase_keys,
-                    self._phrase_short,
-                    gate_texts,
-                    anchor_texts,
-                    self._LUT,
-                    int(self._P1),
-                    int(self._P2),
-                    int(self._HASH_P),
-                    self._NGRAM,
-                    model_shards=model_shards,
-                )
-
-            self._scorer = ng.get_bytes_scorer(build, mesh=self.mesh)
         return self._scorer
+
+    def corpus_table(self, model_shards: int = 1):
+        """The device scorer's host-side corpus table
+        (:class:`trivy_tpu.ops.ngram_score.CorpusTable32`), built for
+        ``model_shards`` corpus shards."""
+        from trivy_tpu.licensing.corpus_texts import FULL_TEXTS
+        from trivy_tpu.ops import ngram_score as ng
+
+        if not hasattr(self, "_gate_keys"):
+            self._build_scoring()
+        # shingle-gate corpus: raw + normalized full texts (the gate sees
+        # raw file bytes, so both spellings of every license must populate
+        # the bloom) plus every gram-bearing long phrase; anchor corpus:
+        # the short fingerprints the substring lane must never miss
+        gate_texts: list[str] = []
+        for lic in sorted(FULL_TEXTS):
+            gate_texts.append(FULL_TEXTS[lic])
+            gate_texts.append(normalize(FULL_TEXTS[lic]))
+        short_set = {ph for _li, ph, _a in self._short_gate}
+        gate_texts.extend(
+            ph for _li, ph in self.phrases if ph not in short_set
+        )
+        return ng.build_corpus_table32(
+            self.licenses,
+            self._full_keys,
+            self._full_weights,
+            self._phrase_keys,
+            self._phrase_short,
+            gate_texts,
+            sorted(short_set),
+            self._LUT,
+            int(self._P1),
+            int(self._P2),
+            int(self._HASH_P),
+            self._NGRAM,
+            model_shards=model_shards,
+        )
 
     # -- shared scoring -----------------------------------------------------
 

@@ -13,7 +13,7 @@ from trivy_tpu.types import DetectedLicense
 # Default category assignment for well-known licenses (modeled on the
 # categories the reference inherits from google/licenseclassifier).
 _DEFAULT_CATEGORIES: dict[str, str] = {
-    # forbidden-by-default in the classifier's taxonomy: none — users opt in
+    # forbidden-by-default in the classifier's categories: none — users opt in
     # restricted
     "GPL-2.0": "restricted", "GPL-2.0-only": "restricted",
     "GPL-2.0-or-later": "restricted", "GPL-3.0": "restricted",

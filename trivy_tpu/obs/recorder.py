@@ -198,18 +198,23 @@ class _State:
     def capacity_bytes(self) -> int:
         if self._capacity is not None:
             return self._capacity
-        cap = 0
-        try:
-            import jax
+        import jax
 
-            stats = jax.local_devices()[0].memory_stats() or {}
-            cap = int(stats.get("bytes_limit", 0) or 0)
-        except Exception:
-            cap = 0
-        if cap <= 0:
-            # the admission controller's HBM proxy budget (MB); the CPU
-            # backend has no memory_stats so the budget stands in
+        # device 0 is where the resident tables (license corpus, CVE
+        # bounds) are placed, so its capacity is the one they press on
+        dev = jax.local_devices()[0]
+        if dev.platform == "cpu":
+            # the CPU backend reports no memory_stats: the admission
+            # controller's HBM proxy budget (MB) stands in
             cap = _env_int("TRIVY_TPU_HBM_BUDGET_MB", 1024) * (1 << 20)
+        else:
+            cap = int((dev.memory_stats() or {}).get("bytes_limit", 0) or 0)
+            if cap <= 0:
+                raise RuntimeError(
+                    f"{dev.platform} device {dev.id} ({dev.device_kind}) "
+                    "reports no memory_stats bytes_limit; the HBM ledger "
+                    "has no capacity to score residency against"
+                )
         self._capacity = cap
         return cap
 

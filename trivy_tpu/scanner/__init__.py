@@ -20,6 +20,9 @@ class ScanOptions:
     pkg_types: list[str] = field(default_factory=lambda: ["os", "library"])
     detection_priority: str = "precise"
     list_all_pkgs: bool = False
+    # --no-host-fallback: a failed device leg fails the scan instead of
+    # degrading to the host oracle
+    host_fallback: bool = True
 
 
 class Scanner:

@@ -1,9 +1,8 @@
 """Compressed slab wire format: host-side codec for the secret feed.
 
-The e2e ceiling is the serialized host→device link (BENCH_r05: kernel
-~900 MB/s, link ~10 MB/s), so the only remaining multiplier on that
-harness is shipping fewer bytes per scanned byte. This module is the
-host half of that lever: the feeder compresses assembled arena slabs
+Where the host→device link bounds the scan, the remaining multiplier is
+shipping fewer bytes per scanned byte. This module is the host half of
+that lever: the feeder compresses assembled arena slabs
 with a deliberately *decoder-shaped* codec — every row decodes with a
 fixed-shape vectorizable kernel (``ops/decompress.py``), which rules out
 general LZ (back-references serialize the decode) in favor of three

@@ -31,13 +31,8 @@ The compiled output is a set of flat tables consumed by
 from __future__ import annotations
 
 import re
-
-try:  # 3.11+ spelling
-    import re._constants as sre_c
-    import re._parser as sre_parse
-except ImportError:  # 3.10 and earlier expose the same modules top-level
-    import sre_constants as sre_c
-    import sre_parse
+import re._constants as sre_c
+import re._parser as sre_parse
 from dataclasses import dataclass, field
 
 import numpy as np

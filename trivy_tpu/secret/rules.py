@@ -24,16 +24,11 @@ from functools import cached_property
 
 from trivy_tpu.types import Severity
 
-try:  # 3.11+ spelling of the sre internals
-    import re._compiler as _sre_compile
-    import re._constants as _sre_c
-    import re._parser as _sre_parse
-except ImportError:  # 3.10 and earlier expose them top-level
-    import sre_compile as _sre_compile
-    import sre_constants as _sre_c
-    import sre_parse as _sre_parse
-# 3.10 getwidth() saturates at MAXREPEAT; 3.11+ renamed it MAXWIDTH
-_SRE_MAXWIDTH = getattr(_sre_parse, "MAXWIDTH", _sre_c.MAXREPEAT)
+import re._compiler as _sre_compile
+import re._constants as _sre_c
+import re._parser as _sre_parse
+
+_SRE_MAXWIDTH = _sre_parse.MAXWIDTH
 
 # Matches must not start mid-word: a token preceded by [0-9a-zA-Z] is part of a
 # longer word and not a credential boundary (ref: builtin-rules.go:81 startWord).
