@@ -9,6 +9,8 @@ still runs the suite on the CPU.
 
 import os
 
+import pytest
+
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -30,3 +32,21 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: minutes-long case, left out of tier-1 (-m 'not slow')"
     )
+
+
+@pytest.fixture(autouse=True)
+def restore_program_logging():
+    """The CLI configures the program's logger (its own handler, no
+    propagation); tests run after an in-process ``cli.main`` in the same
+    worker read logs through caplog."""
+    import logging
+
+    from trivy_tpu import log
+
+    root = logging.getLogger("trivy_tpu")
+    saved = (list(root.handlers), root.level, root.propagate, log._configured)
+    yield
+    root.handlers[:] = saved[0]
+    root.setLevel(saved[1])
+    root.propagate = saved[2]
+    log._configured = saved[3]

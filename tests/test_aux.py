@@ -7,7 +7,7 @@ import io
 
 import pytest
 
-from trivy_tpu import plugin, trace
+from trivy_tpu import obs, plugin
 from trivy_tpu.compliance import apply_spec, load_spec, write_report
 from trivy_tpu.scanner.post import (
     PostScanner,
@@ -79,16 +79,16 @@ class TestPostScan:
 
 class TestTrace:
     def test_spans_report(self):
-        trace.reset()
-        trace.enable()
-        with trace.span("unit.test.span"):
+        obs.current().reset()
+        obs.enable()
+        with obs.span("unit.test.span"):
             pass
-        trace.add("unit.test.add", 0.5)
+        obs.add("unit.test.add", 0.5)
         buf = io.StringIO()
-        trace.report(buf)
+        obs.report(buf)
         out = buf.getvalue()
         assert "unit.test.span" in out and "unit.test.add" in out
-        trace.reset()
+        obs.current().reset()
 
 
 class TestCompliance:

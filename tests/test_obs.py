@@ -1,13 +1,13 @@
 """Observability subsystem: span nesting/parenting, per-scan isolation,
 Chrome-trace export schema, stall attribution, the Prometheus registry,
-the trace.* compat shim, and JSON logging."""
+the module-level span surface, and JSON logging."""
 
 import io
 import json
 import threading
 import time
 
-from trivy_tpu import log, obs, trace
+from trivy_tpu import log, obs
 from trivy_tpu.obs import export, metrics, stall
 
 
@@ -247,28 +247,32 @@ class TestRegistry:
 
 
 class TestCompatShim:
+    """The module-level surface (``obs.span``/``add``/``count``/``report``/
+    ``enable``) that the old flat ``trace`` module offered, routed to the
+    current context."""
+
     def test_trace_module_routes_to_current_context(self):
         with obs.scan_context(name="shim", enabled=True) as ctx:
-            assert trace.enabled()
-            with trace.span("unit.shim.span"):
+            assert obs.enabled()
+            with obs.span("unit.shim.span"):
                 pass
-            trace.add("unit.shim.add", 0.5)
-            trace.count("unit.shim.count", 3)
+            obs.add("unit.shim.add", 0.5)
+            obs.count("unit.shim.count", 3)
             buf = io.StringIO()
-            trace.report(buf)
+            obs.report(buf)
             out = buf.getvalue()
             assert "unit.shim.span" in out and "unit.shim.add" in out
             assert ctx.counters["unit.shim.count"] == 3
-            trace.reset()
+            obs.current().reset()
             assert not ctx.durations and not ctx.counters
 
     def test_global_enable_disable(self):
-        trace.enable()
+        obs.enable()
         try:
             assert obs.current().enabled
         finally:
-            trace.disable()
-            trace.reset()
+            obs.disable()
+            obs.current().reset()
         assert not obs.current().enabled
 
 

@@ -190,6 +190,16 @@ def _vuln_client(opts):
     return db
 
 
+def _setup_span():
+    """Span ``commands.setup`` for a scan command's set-up before its
+    artifact's ``inspect()`` (cache and DB open, driver, artifact and
+    scanner construction), and count the run in ``commands.scans``."""
+    from trivy_tpu import obs
+
+    obs.count("commands.scans")
+    return obs.span("commands.setup")
+
+
 class ScanTimeout(BaseException):
     """``--timeout`` expired. A BaseException, like KeyboardInterrupt: the
     alarm lands in whatever the main thread runs, often inside a per-file
@@ -364,6 +374,15 @@ def run(command: str, ns, opts) -> int:
 
 
 def _emit(report, ns, opts) -> int:
+    from trivy_tpu import obs
+
+    with obs.span("report.write"):
+        return _write_report(report, ns, opts)
+
+
+def _write_report(report, ns, opts) -> int:
+    """Filter ``report`` and render it (or its compliance view) to
+    ``--output`` or stdout; returns the exit code."""
     from trivy_tpu import report as report_pkg
     from trivy_tpu.result import FilterOptions, filter_report
 
@@ -477,44 +496,46 @@ def _run_fs_like(command: str, ns, opts) -> int:
             )
             return 2
         return _run_fleet("fs", target, ns, opts, art_opt)
-    if server:
+    if server and incr is not None:
+        # client-mode analysis ships blobs to the server's cache; the
+        # unit-level diff needs a readable local cache — refuse loudly
+        # instead of silently full-scanning
+        logger.error(
+            "--incremental/--diff-base/--since-last require a local "
+            "scan path (drop --server or run the scan on the server)"
+        )
+        return 2
+    with _setup_span():
+        if server:
+            # client mode: analysis is local, blobs ship to the SERVER's
+            # cache and detection runs there (ref: run.go:348-355 split)
+            from trivy_tpu.rpc.client import RemoteCache, RemoteDriver
+
+            cache = RemoteCache(server, token=opts.get("token") or "")
+            driver = RemoteDriver(server, token=opts.get("token") or "")
+        else:
+            from trivy_tpu.scanner.local_driver import LocalDriver
+
+            cache = _make_cache(opts)
+            driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
         if incr is not None:
-            # client-mode analysis ships blobs to the server's cache; the
-            # unit-level diff needs a readable local cache — refuse loudly
-            # instead of silently full-scanning
-            logger.error(
-                "--incremental/--diff-base/--since-last require a local "
-                "scan path (drop --server or run the scan on the server)"
-            )
-            return 2
-        # client mode: analysis is local, blobs ship to the SERVER's cache
-        # and detection runs there (ref: run.go:348-355 split)
-        from trivy_tpu.rpc.client import RemoteCache, RemoteDriver
+            from trivy_tpu.incremental.fs import IncrementalFSArtifact
 
-        cache = RemoteCache(server, token=opts.get("token") or "")
-        driver = RemoteDriver(server, token=opts.get("token") or "")
-    else:
-        from trivy_tpu.scanner.local_driver import LocalDriver
-
-        cache = _make_cache(opts)
-        driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+            artifact = IncrementalFSArtifact(target, cache, art_opt, incr)
+        else:
+            artifact = LocalFSArtifact(target, cache, art_opt)
+        scanner = Scanner(artifact, driver)
     if incr is not None:
-        from trivy_tpu.incremental.fs import IncrementalFSArtifact
         from trivy_tpu.incremental.manifest import GitDiffError
 
-        artifact = IncrementalFSArtifact(target, cache, art_opt, incr)
         try:
-            report = Scanner(artifact, driver).scan_artifact(
-                _scan_options(opts)
-            )
+            report = scanner.scan_artifact(_scan_options(opts))
         except GitDiffError as e:
             # typoed --diff-base ref / not a git worktree: a clean error,
             # not a traceback — and never a silent full scan
             logger.error("--diff-base %s: %s", incr.diff_base, e)
             return 1
         return _emit(report, ns, opts)
-    artifact = LocalFSArtifact(target, cache, art_opt)
-    scanner = Scanner(artifact, driver)
     report = scanner.scan_artifact(_scan_options(opts))
     return _emit(report, ns, opts)
 
@@ -533,8 +554,9 @@ def _run_watch(ns, opts) -> int:
     interval = float(getattr(ns, "watch_interval", 0) or 2.0)
     max_scans = int(getattr(ns, "watch_count", 0) or 0)  # 0 = forever
     art_opt = _artifact_option(ns, opts)
-    cache = _make_cache(opts)
-    driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+    with _setup_span():
+        cache = _make_cache(opts)
+        driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
     incr = IncrementalOptions(enabled=True, since_last=True)
     rc = 0
     n = 0
@@ -602,12 +624,14 @@ def _run_fleet(kind: str, target: str, ns, opts, art_opt) -> int:
                 "pre-seeding", len(fleet_cfg.warm_seed),
                 "y" if len(fleet_cfg.warm_seed) == 1 else "ies",
             )
-    artifact = FleetArtifact(
-        kind, target, cache, art_opt, fleet_cfg, _scan_options(opts)
-    )
-    driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+    with _setup_span():
+        artifact = FleetArtifact(
+            kind, target, cache, art_opt, fleet_cfg, _scan_options(opts)
+        )
+        driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+        scanner = Scanner(artifact, driver)
     try:
-        report = Scanner(artifact, driver).scan_artifact(_scan_options(opts))
+        report = scanner.scan_artifact(_scan_options(opts))
     except FleetError as e:
         logger.error("fleet scan failed: %s", e)
         return 1
@@ -646,8 +670,10 @@ def _run_image(ns, opts) -> int:
             # against this base, so fail loud, never silently full-scan
             logger.error("--diff-base %s: %s", diff_base, e)
             return 1
-    driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
-    report = Scanner(artifact, driver).scan_artifact(_scan_options(opts))
+    with _setup_span():
+        driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+        scanner = Scanner(artifact, driver)
+    report = scanner.scan_artifact(_scan_options(opts))
     return _emit(report, ns, opts)
 
 
@@ -655,10 +681,14 @@ def _run_vm(ns, opts) -> int:
     from trivy_tpu.artifact.vm import VMImageArtifact
     from trivy_tpu.scanner.local_driver import LocalDriver
 
-    cache = _make_cache(opts)
-    artifact = VMImageArtifact(ns.target, cache, _artifact_option(ns, opts))
-    driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
-    report = Scanner(artifact, driver).scan_artifact(_scan_options(opts))
+    with _setup_span():
+        cache = _make_cache(opts)
+        artifact = VMImageArtifact(
+            ns.target, cache, _artifact_option(ns, opts)
+        )
+        driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+        scanner = Scanner(artifact, driver)
+    report = scanner.scan_artifact(_scan_options(opts))
     return _emit(report, ns, opts)
 
 
@@ -666,14 +696,16 @@ def _run_sbom(ns, opts) -> int:
     from trivy_tpu.artifact.sbom import SBOMArtifact
     from trivy_tpu.scanner.local_driver import LocalDriver
 
-    cache = _make_cache(opts)
-    artifact = SBOMArtifact(ns.target, cache)
     opts = dict(opts)
     opts.setdefault("scanners", ["vuln"])
     if "vuln" not in opts["scanners"]:
         opts["scanners"] = ["vuln"]
-    driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
-    report = Scanner(artifact, driver).scan_artifact(_scan_options(opts))
+    with _setup_span():
+        cache = _make_cache(opts)
+        artifact = SBOMArtifact(ns.target, cache)
+        driver = LocalDriver(cache, vuln_client=_vuln_client(opts))
+        scanner = Scanner(artifact, driver)
+    report = scanner.scan_artifact(_scan_options(opts))
     return _emit(report, ns, opts)
 
 

@@ -675,18 +675,20 @@ def detect_batch(
             continue
         supported.append((ai, app, eco[0], eco[1]))
         total += len(app.packages)
-    if total < BATCH_THRESHOLD or not hasattr(db, "prefix_advisories"):
-        for ai, app, _prefix, _scheme in supported:
-            out[ai] = detect(db, app)
-        return out
     ctx = obs.current()
+    if total < BATCH_THRESHOLD or not hasattr(db, "prefix_advisories"):
+        with ctx.span("cve.host_compare"):
+            for ai, app, _prefix, _scheme in supported:
+                out[ai] = detect(db, app)
+        return out
     rj = _resident_join(db)
     join_apps: list[tuple] = []
     for ai, app, prefix, scheme in supported:
         if prefix in rj.prefixes:
             join_apps.append((ai, app, prefix, scheme))
         else:
-            out[ai] = detect(db, app)
+            with ctx.span("cve.host_compare"):
+                out[ai] = detect(db, app)
     if not join_apps:
         return out
     queries: list[tuple[str, str]] = []
@@ -729,32 +731,34 @@ def detect_batch(
             "resident CVE join failed (%s); degrading to the host "
             "comparator for this batch", e,
         )
-        verdicts = np.fromiter(
-            (
-                _is_vulnerable(q_scheme[q], q_pkg[q].version,
-                               rj.adv_objs[a])
-                for q, a in zip(cand_q, cand_adv)
-            ),
-            dtype=bool, count=len(cand_q),
-        )
-    # fixed-version strings repeat heavily across a large SBOM (same
-    # advisory hit at the same installed version by many packages): one
-    # computation per unique (advisory, scheme, version) triple
-    fv_cache: dict[tuple, str] = {}
-    for i in np.nonzero(verdicts)[0]:
-        q = int(cand_q[i])
-        adv = rj.adv_objs[int(cand_adv[i])]
-        pkg = q_pkg[q]
-        scheme = q_scheme[q]
-        k = (id(adv), scheme, pkg.version)
-        fv = fv_cache.get(k)
-        if fv is None:
-            fv = fv_cache[k] = _fixed_version(scheme, pkg.version, adv)
-        out[q_app[q]].append(_finding(scheme, pkg, adv, fv))
-    for ai, _app, _prefix, _scheme in join_apps:
-        out[ai].sort(
-            key=lambda v: (v.pkg_name, v.vulnerability_id, v.pkg_path)
-        )
+        with ctx.span("cve.host_compare"):
+            verdicts = np.fromiter(
+                (
+                    _is_vulnerable(q_scheme[q], q_pkg[q].version,
+                                   rj.adv_objs[a])
+                    for q, a in zip(cand_q, cand_adv)
+                ),
+                dtype=bool, count=len(cand_q),
+            )
+    with ctx.span("cve.assemble"):
+        # fixed-version strings repeat heavily across a large SBOM (same
+        # advisory hit at the same installed version by many packages):
+        # one computation per unique (advisory, scheme, version) triple
+        fv_cache: dict[tuple, str] = {}
+        for i in np.nonzero(verdicts)[0]:
+            q = int(cand_q[i])
+            adv = rj.adv_objs[int(cand_adv[i])]
+            pkg = q_pkg[q]
+            scheme = q_scheme[q]
+            k = (id(adv), scheme, pkg.version)
+            fv = fv_cache.get(k)
+            if fv is None:
+                fv = fv_cache[k] = _fixed_version(scheme, pkg.version, adv)
+            out[q_app[q]].append(_finding(scheme, pkg, adv, fv))
+        for ai, _app, _prefix, _scheme in join_apps:
+            out[ai].sort(
+                key=lambda v: (v.pkg_name, v.vulnerability_id, v.pkg_path)
+            )
     return out
 
 
@@ -777,77 +781,85 @@ def _resident_verdicts(
     if not len(cand_q):
         return verdicts
     ctx = obs.current()
-    # one encode per unique (scheme, installed version); -1 = unencodable
-    inst_rows: list[list[int]] = []
-    inst_pad: list[int] = []
-    memo: dict[tuple, int] = {}
-    inst_of_q = np.full(len(q_pkg), -1, dtype=np.int64)
-    for q in hit_queries:
-        q = int(q)
-        key = (q_scheme[q], q_pkg[q].version)
-        r = memo.get(key)
-        if r is None:
-            enc = encode(key[0], key[1])
-            if enc is None:
-                r = -1
-            else:
-                r = len(inst_rows)
-                inst_rows.append(enc)
-                inst_pad.append(pad_value(key[0]))
-            memo[key] = r
-        inst_of_q[q] = r
-    a_row = inst_of_q[cand_q]
-    host = rj.adv_host[cand_adv] | (a_row < 0)
-    dev = np.nonzero(~host)[0]
-    if len(dev) and inst_rows:
-        starts = rj.adv_start[cand_adv[dev]]
-        lens = rj.adv_len[cand_adv[dev]]
-        groups_np = rj.adv_groups[cand_adv[dev]]
-        gz = groups_np > 0  # no constraint groups -> not vulnerable
-        dev, starts, lens, groups_np = (
-            dev[gz], starts[gz], lens[gz], groups_np[gz],
-        )
-        n_groups = int(groups_np.sum())
-        if n_groups:
+    n_groups = 0
+    ok = None
+    with ctx.span("cve.encode"):
+        # one encode per unique (scheme, installed version); -1 = unencodable
+        inst_rows: list[list[int]] = []
+        inst_pad: list[int] = []
+        memo: dict[tuple, int] = {}
+        inst_of_q = np.full(len(q_pkg), -1, dtype=np.int64)
+        for q in hit_queries:
+            q = int(q)
+            key = (q_scheme[q], q_pkg[q].version)
+            r = memo.get(key)
+            if r is None:
+                enc = encode(key[0], key[1])
+                if enc is None:
+                    r = -1
+                else:
+                    r = len(inst_rows)
+                    inst_rows.append(enc)
+                    inst_pad.append(pad_value(key[0]))
+                memo[key] = r
+            inst_of_q[q] = r
+        a_row = inst_of_q[cand_q]
+        host = rj.adv_host[cand_adv] | (a_row < 0)
+        dev = np.nonzero(~host)[0]
+        if len(dev) and inst_rows:
+            starts = rj.adv_start[cand_adv[dev]]
+            lens = rj.adv_len[cand_adv[dev]]
+            groups_np = rj.adv_groups[cand_adv[dev]]
+            gz = groups_np > 0  # no constraint groups -> not vulnerable
+            dev, starts, lens, groups_np = (
+                dev[gz], starts[gz], lens[gz], groups_np[gz],
+            )
+            n_groups = int(groups_np.sum())
+        nz = lens > 0 if n_groups else None
+        dispatch = nz is not None and nz.any()
+        if dispatch:
+            rows = ragged_arange(starts[nz], lens[nz])
+            ops = rj.ops_flat[rows]
+            b_idx = rj.b_flat[rows]
+            a_idx = np.repeat(a_row[dev][nz], lens[nz]).astype(np.int32)
+            group_base = np.concatenate(([0], np.cumsum(groups_np)[:-1]))
+            row_group = (
+                rj.glocal_flat[rows] + np.repeat(group_base[nz], lens[nz])
+            )
+            La = max(len(r) for r in inst_rows)
+            bounds_dev, L = rj.bounds_device(La)
+            inst_mat = np.empty((len(inst_rows), L), dtype=np.int32)
+            inst_mat[:] = np.asarray(inst_pad, dtype=np.int32)[:, None]
+            for i, r in enumerate(inst_rows):
+                inst_mat[i, : len(r)] = r
+    if dispatch:
+        from trivy_tpu.ops.verscmp import check_ops_gather_bucketed
+
+        faults.check("device.dispatch", key="cve")
+        rj.dispatch_count += 1
+        ctx.count("cve.resident_rows", int(len(ops)))
+        with ctx.span("cve.dispatch"):
+            ok = check_ops_gather_bucketed(
+                inst_mat, bounds_dev, a_idx, b_idx, ops
+            )
+    if n_groups:
+        with ctx.span("cve.assemble"):
             # empty AND-groups stay True through np.ones + contributing no
             # rows to the logical_and reduction — trivially satisfied
             group_ok = np.ones(n_groups, dtype=bool)
-            nz = lens > 0
-            if nz.any():
-                from trivy_tpu.ops.verscmp import check_ops_gather_bucketed
-
-                rows = ragged_arange(starts[nz], lens[nz])
-                ops = rj.ops_flat[rows]
-                b_idx = rj.b_flat[rows]
-                a_idx = np.repeat(a_row[dev][nz], lens[nz]).astype(np.int32)
-                group_base = np.concatenate(([0], np.cumsum(groups_np)[:-1]))
-                row_group = (
-                    rj.glocal_flat[rows] + np.repeat(group_base[nz], lens[nz])
-                )
-                La = max(len(r) for r in inst_rows)
-                bounds_dev, L = rj.bounds_device(La)
-                inst_mat = np.empty((len(inst_rows), L), dtype=np.int32)
-                inst_mat[:] = np.asarray(inst_pad, dtype=np.int32)[:, None]
-                for i, r in enumerate(inst_rows):
-                    inst_mat[i, : len(r)] = r
-                faults.check("device.dispatch", key="cve")
-                rj.dispatch_count += 1
-                ctx.count("cve.resident_rows", int(len(ops)))
-                with ctx.span("cve.dispatch"):
-                    ok = check_ops_gather_bucketed(
-                        inst_mat, bounds_dev, a_idx, b_idx, ops
-                    )
+            if ok is not None:
                 np.logical_and.at(group_ok, row_group, np.asarray(ok))
             # a candidate is vulnerable when ANY of its AND-groups holds
             group_cand = np.repeat(np.arange(len(dev)), groups_np)
             vuln = np.zeros(len(dev), dtype=bool)
             np.logical_or.at(vuln, group_cand[group_ok], True)
             verdicts[dev[vuln]] = True
-    for i in np.nonzero(host)[0]:
-        q = int(cand_q[i])
-        verdicts[i] = _is_vulnerable(
-            q_scheme[q], q_pkg[q].version, rj.adv_objs[int(cand_adv[i])]
-        )
+    with ctx.span("cve.host_compare"):
+        for i in np.nonzero(host)[0]:
+            q = int(cand_q[i])
+            verdicts[i] = _is_vulnerable(
+                q_scheme[q], q_pkg[q].version, rj.adv_objs[int(cand_adv[i])]
+            )
     return verdicts
 
 

@@ -1,8 +1,7 @@
 """Observability subsystem: per-scan trace contexts, span histograms,
 stall attribution, Chrome-trace/metrics export, and a Prometheus registry.
 
-Replaces the old ``trace.py`` flat global span table (kept as a thin compat
-shim). The design borrows the two instrumentation surfaces a training/
+The design borrows the two instrumentation surfaces a training/
 inference stack leans on:
 
 - **Dapper-style span trees** (:class:`TraceContext`): every span carries a
@@ -19,6 +18,12 @@ inference stack leans on:
   (:mod:`trivy_tpu.obs.stall`) — ``feed-starved 72% / device-bound 18% /
   confirm-bound 10%`` — so perf rounds can pick targets from attribution,
   not totals.
+- **Spans on the profiler's clock**: while a JAX profiler session records,
+  each span of an enabled context is also a ``jax.profiler.TraceAnnotation``
+  of its own name, on the host plane of the same trace as the device ops,
+  so device idle time can be attributed to the host work it overlaps.
+  Externally timed spans (:meth:`TraceContext.add`) cannot be back-dated
+  onto that clock and stay totals-only.
 
 Disabled contexts cost one attribute check per span site (the acceptance
 bar: < 1% overhead with tracing off).
@@ -27,6 +32,7 @@ bar: < 1% overhead with tracing off).
 from __future__ import annotations
 
 import contextvars
+import inspect
 import itertools
 import os
 import random
@@ -153,13 +159,34 @@ _NULL = _NullSpan()
 # None-check per recorded span when off.
 _flight_hook = None
 
+# jax.profiler.TraceAnnotation, looked up once JAX is in the process: obs
+# never imports JAX itself, so processes without it stay without it
+_annotation = None
+# a frame that can suspend (generator, coroutine) may resume a span's
+# block on another thread, and a TraceAnnotation records on the thread
+# that ends it: spans opened directly in such frames get none
+_SUSPENDS = (
+    inspect.CO_GENERATOR | inspect.CO_COROUTINE
+    | inspect.CO_ITERABLE_COROUTINE | inspect.CO_ASYNC_GENERATOR
+)
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
 
 class _SpanCM:
-    __slots__ = ("ctx", "name", "sp")
+    __slots__ = ("ctx", "name", "sp", "ann")
 
     def __init__(self, ctx: "TraceContext", name: str):
         self.ctx = ctx
         self.name = name
+        self.ann = None
 
     def __enter__(self) -> Span:
         ctx = self.ctx
@@ -176,9 +203,20 @@ class _SpanCM:
         )
         stack.append(sp)
         self.sp = sp
+        # only while a profiler session records; TraceMe stamps its start
+        # when constructed
+        ann = _annotation or _trace_annotation()
+        if (
+            ann is not None and ann.is_enabled()
+            and not sys._getframe(1).f_code.co_flags & _SUSPENDS
+        ):
+            self.ann = ann(self.name)
         return sp
 
     def __exit__(self, *exc) -> bool:
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
         sp = self.sp
         sp.duration = time.perf_counter() - sp.start
         stack = self.ctx._stack()
@@ -287,7 +325,8 @@ class TraceContext:
         return _SpanCM(self, name)
 
     def add(self, name: str, seconds: float) -> None:
-        """Record an externally-timed duration as a span ending now."""
+        """Record an externally-timed duration as a span ending now (in the
+        totals only: it cannot be back-dated onto the profiler's clock)."""
         if not self.enabled:
             return
         self._record(

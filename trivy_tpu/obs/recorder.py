@@ -417,10 +417,21 @@ def instrument_jit(kernel: str, fn, **jit_kwargs):
     """``jax.jit(fn)`` with compile accounting: the first call per
     argument shape-bucket is timed as a compile (trace + compile wall)
     and fed to :func:`note_compile`. With the recorder off this returns
-    the bare jitted callable — zero wrapper, zero per-call cost."""
+    the bare jitted callable — zero wrapper, zero per-call cost.
+
+    Either way ``fn`` is jitted under the name ``kernel``, so its XLA
+    module is ``jit_<kernel>``: a TPU profiler trace names the device time
+    of each module execution by it (its ops carry no ``op_name``)."""
+    import functools
+
     import jax
 
-    jfn = jax.jit(fn, **jit_kwargs)
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = kernel
+    jfn = jax.jit(named, **jit_kwargs)
     if _state() is None:
         return jfn
     seen: set[str] = set()

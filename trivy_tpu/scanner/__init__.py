@@ -39,10 +39,15 @@ class Scanner:
         # the active trace context even with tracing off; the before/after
         # delta is exactly this scan's share, so back-to-back library scans
         # sharing the process-default context stay disjoint
-        health0 = obs.current().health_snapshot()
-        ref = self.artifact.inspect()
-        results, os_info = self.driver.scan(ref.name, ref.id, ref.blob_ids, options)
-        health = obs.current().health_snapshot()
+        ctx = obs.current()
+        health0 = ctx.health_snapshot()
+        with ctx.span("scan.inspect"):
+            ref = self.artifact.inspect()
+        with ctx.span("scan.detect"):
+            results, os_info = self.driver.scan(
+                ref.name, ref.id, ref.blob_ids, options
+            )
+        health = ctx.health_snapshot()
         delta = {k: v - health0.get(k, 0) for k, v in health.items()}
         metadata = {
             "ImageID": ref.image_metadata.get("id", ""),
