@@ -215,21 +215,36 @@ def test_compress_off_is_zero_cost(cpu):
     assert d["bytes_raw_fallback"] == 0 and d["bytes_gated"] == 0
 
 
-def test_auto_mode_resolves_by_link_class():
+@pytest.mark.parametrize(
+    "link, want",
+    [("host", False), ("pcie", False), ("wan", True)],
+    ids=["host-raw", "pcie-raw", "slower-link-compresses"],
+)
+def test_auto_mode_resolves_by_link_class(monkeypatch, link, want):
+    """``auto`` compresses only on a link class slower than PCIe: on the
+    host bus and on PCIe the device decode costs more than the wire bytes
+    it saves, so those scanners carry no codec state at all."""
     from trivy_tpu.parallel.mesh import link_class
 
+    monkeypatch.setenv("TRIVY_TPU_LINK_CLASS", link)
+    assert link_class() == link
     t = build("auto")
-    want = link_class() != "host"  # CPU backend in the suite -> off
     assert t.compress_on == want
     assert t.tuning_snapshot()["compress"] == want
-    # and a forced link class flips the auto verdict
-    import os
+    assert ("decompress" in t._staged._stages) == want
+    assert bool(t._wire_rungs) == want
+    assert (t._codec is not None) == want
+    warmed = []
+    run = t._staged.run
 
-    os.environ["TRIVY_TPU_LINK_CLASS"] = "pcie"
-    try:
-        assert build("auto").compress_on
-    finally:
-        del os.environ["TRIVY_TPU_LINK_CLASS"]
+    def record(name, *a, **kw):
+        warmed.append(name)
+        return run(name, *a, **kw)
+
+    monkeypatch.setattr(t._staged, "run", record)
+    t.warm_buckets()
+    assert ("decompress" in warmed) == want
+    assert "match" in warmed
 
 
 def test_dedup_keys_are_codec_invariant(cpu):
@@ -264,6 +279,48 @@ def test_round_robin_multi_stream_parity(cpu):
     assert t._match.n_streams == 4 and t.compress_on
     assert_parity(cpu, t, mixed_corpus(16, seed=3))
     assert t.stats.snapshot()["batches_compressed"] > 0
+
+
+def test_pcie_auto_round_robin_ships_raw_with_license_gate(cpu, monkeypatch):
+    """The default on a PCIe link: ``auto`` ships raw slabs round-robin
+    over four devices with the fused license gate on. Findings and gate
+    verdicts match the codec path, and the all-zero rows the codec's zero
+    gate kept off the wire now upload and resolve clean."""
+    from trivy_tpu.licensing.fused import FusedLicenseGate
+
+    monkeypatch.setenv("TRIVY_TPU_LINK_CLASS", "pcie")
+    files = mixed_corpus(16, seed=3)
+    files.append(("zeros.bin", b"\x00" * 5000))
+
+    def scan(compress):
+        t = build(
+            compress, chunk_len=1024, dispatch="round_robin",
+            devices=jax.devices()[:4], dedup=False,
+        )
+        gate = FusedLicenseGate(license_full=True)
+        got = list(t.scan_files(iter(files), license_gate=gate))
+        assert len(got) == len(files)
+        for (path, data), secret in zip(files, got):
+            assert secret.to_dict() == cpu.scan_bytes(path, data).to_dict(), (
+                path
+            )
+        return t, gate
+
+    raw, raw_gate = scan("auto")
+    assert raw._match.n_streams == 4 and not raw.compress_on
+    s = raw.stats.snapshot()
+    assert s["batches_compressed"] == 0 and s["bytes_compressed"] == 0
+    assert s["chunks_gated_zero"] == 0 and s["bytes_gated"] == 0
+    packed, packed_gate = scan("on")
+    p = packed.stats.snapshot()
+    assert p["batches_compressed"] > 0 and p["chunks_gated_zero"] > 0
+    # every row the zero gate resolved on the codec path rides the wire raw
+    assert s["chunks"] == p["chunks"]
+    assert s["chunks_uploaded"] == p["chunks_uploaded"] + p["chunks_gated_zero"]
+    for path, _ in files:
+        assert raw_gate.should_classify(path) == packed_gate.should_classify(
+            path
+        ), path
 
 
 def test_mesh_forces_compress_off(cpu):

@@ -228,8 +228,9 @@ def secret_flags() -> FlagGroup:
             Flag("secret-compress", default=None,
                  config_name="secret.compress",
                  help="compressed slab wire format on the device feed: "
-                      "auto (on for real accelerator links, off on the "
-                      "host backend / under a mesh), on, off "
+                      "auto (off on the host and pcie link classes and "
+                      "under a mesh, on for a slower declared link "
+                      "class), on, off "
                       "(env TRIVY_TPU_SECRET_COMPRESS)"),
             Flag("no-secret-compress", default=False, value_type=bool,
                  config_name="secret.no-compress",

@@ -24,8 +24,14 @@ produces ``out[i, :C]``:
 Rows with ``clen == 0`` (bucket padding) decode to zero rows — exactly
 what the raw path ships for padding, so downstream stages see identical
 planes. XLA (not Pallas) on purpose: the hot ops are gather/cumsum/
-scatter, which Mosaic lowers poorly, and at ~0.875·B·C wire bytes per
-batch the kernel is a rounding error next to the link time it saves.
+scatter, which Mosaic lowers poorly.
+
+The kernel computes all three modes for every row and selects one, so
+it is not cheap: measured on a v5e behind PCIe, it took ~37% of the
+device time of a secret+license fs scan for a ~13% wire saving. It pays
+only behind a link much slower than PCIe, which is why
+``--secret-compress auto`` leaves it off on the ``host`` and ``pcie``
+link classes (``secret/tpu_scanner.py``).
 """
 
 from __future__ import annotations

@@ -55,8 +55,7 @@ whole-file MatchKeywords semantics), then the anchored matcher when
 needed, then (with ``--scanners secret,license``) the license gram gate
 (`licensing/fused.py`) so license candidacy costs zero extra link bytes.
 
-The feed path sends link bytes ≪ corpus bytes (the host→device link, not
-the kernel, is the e2e ceiling):
+The feed path sends link bytes ≪ corpus bytes:
 
 - **chunk-dedup hit cache**: every row is content-hashed (keyed blake2b,
   key = ruleset fingerprint so a rule add/remove/change flips every key)
@@ -131,6 +130,9 @@ ARENA_MARGIN = 2
 # concurrent device_put calls from separate threads overlap transfers with
 # each other as well as with kernels (TRIVY_TPU_FEED_STREAMS=1 serializes)
 SINGLE_DEVICE_STREAMS = 4
+# link classes (parallel.mesh.link_class) on which --secret-compress auto
+# ships raw slabs: the device decode costs more than the wire bytes it saves
+RAW_LINKS = ("host", "pcie")
 # workers for exact host confirmation (overlaps device-result waits)
 CONFIRM_WORKERS = 4
 # bounded in-process LRU for the chunk-dedup hit cache; most entries are an
@@ -301,9 +303,9 @@ class TpuSecretScanner:
         bucket_rungs: int = 0,  # dispatch bucket-ladder depth; 0 = default
         hit_cache_bytes: int = 0,  # dedup LRU byte budget; 0 = tuning's
         # dedup_store_mb (default hitstore.DEFAULT_STORE_MB)
-        compress: str = "",  # compressed slab wire format: 'auto' (on for
-        # real accelerator links, off on the host backend and under a
-        # mesh), 'on', 'off'; "" = tuning's --secret-compress resolution
+        compress: str = "",  # compressed slab wire format: 'auto' (on
+        # only for a link class outside RAW_LINKS, never under a mesh),
+        # 'on', 'off'; "" = tuning's --secret-compress resolution
         compress_min_ratio: float = 0.0,  # per-batch wire budget as a
         # fraction of the raw slab — a batch that can't compress below
         # this ships raw; 0 = tuning / COMPRESS_MIN_RATIO default
@@ -540,12 +542,16 @@ class TpuSecretScanner:
         self._buckets = sorted(buckets)
 
         # -- compressed slab wire format (secret/compress.py) ---------------
-        # 'auto' opts in only where compression can pay: a real accelerator
-        # link (the CPU backend shares one memory bus — compressing for it
-        # only burns host cycles) and no mesh (a flat wire buffer has no
-        # row axis to shard_map over). Zero-cost-when-off bar: an 'off'
-        # scanner builds no codec tables, registers no decompress stage,
-        # and allocates no wire-rung state — bench --smoke asserts this.
+        # 'auto' opts in only on a link slow enough for the codec to pay:
+        # not 'host' (the CPU backend shares one memory bus — compressing
+        # for it only burns host cycles) and not 'pcie' (measured on a
+        # v5e, the device decode took ~37% of device time for a ~13% wire
+        # saving that the transfer streams already overlap with kernels).
+        # A slower link opts in through TRIVY_TPU_LINK_CLASS or 'on'. No
+        # mesh either (a flat wire buffer has no row axis to shard_map
+        # over). Zero-cost-when-off bar: an 'off' scanner builds no codec
+        # tables, registers no decompress stage, and allocates no
+        # wire-rung state — bench --smoke asserts this.
         from trivy_tpu.parallel.mesh import link_class
 
         mode = compress or tuning.compress or "auto"
@@ -554,7 +560,7 @@ class TpuSecretScanner:
                 f"compress={mode!r}: use 'auto', 'on', or 'off'"
             )
         comp_on = mode == "on" or (
-            mode == "auto" and link_class(platform) != "host"
+            mode == "auto" and link_class(platform) not in RAW_LINKS
         )
         if comp_on and mesh is not None:
             if mode == "on":
