@@ -244,11 +244,6 @@ def bench_e2e_best(scanner, files, rng, device_mbs, reps=None):
                 if pre_rows
                 else None
             ),
-            "nfa_skip_rate": (
-                (s1["rows_nfa_skipped"] - s0["rows_nfa_skipped"]) / pre_rows
-                if pre_rows
-                else None
-            ),
             "wire_ratio": (comp + raw_fb) / raw_eq if raw_eq else None,
             "ctx": ctx,
         }
@@ -272,7 +267,6 @@ def bench_e2e_best(scanner, files, rng, device_mbs, reps=None):
             rep_doc["prefilter_selectivity"] = round(
                 r["prefilter_selectivity"], 4
             )
-            rep_doc["nfa_skip_rate"] = round(r["nfa_skip_rate"], 4)
         if r["wire_ratio"] is not None:
             rep_doc["wire_compression_ratio"] = round(r["wire_ratio"], 4)
         reps_out.append(rep_doc)

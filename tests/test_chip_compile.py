@@ -80,22 +80,37 @@ def _rules(n: int | None):
     return compile_rules(rules[:n] if n else rules)
 
 
+def _rule_pass(compiled):
+    from trivy_tpu.ops.rules import build_rules_fn
+    from trivy_tpu.secret.device_compile import build_tables
+
+    tables = build_tables(compiled)
+    fn = build_rules_fn(tables.margin, tables.kw_bits)
+    return fn, [((ROWS, CHUNK), "uint8")] + [(a.shape, a.dtype) for a in tables.operands()]
+
+
 @pytest.mark.parametrize(
     "n_rules",
     [4, pytest.param(None, marks=pytest.mark.slow, id="all-builtin")],
 )
 def test_match_kernel(compile_for, n_rules):
-    from trivy_tpu.ops.match_pallas import build_match_fn_pallas
-
-    fn = build_match_fn_pallas(_rules(n_rules), CHUNK, include_keywords=False)
-    assert "tpu_custom_call" in compile_for(fn, ((ROWS, CHUNK), "uint8"))
+    """The table-driven rule pass (anchored variants and keywords) at the
+    accelerator path's batch shape."""
+    fn, shapes = _rule_pass(_rules(n_rules))
+    assert "ENTRY" in compile_for(fn, *shapes)
 
 
 def test_prefilter_kernel(compile_for):
-    from trivy_tpu.ops.prefilter import build_prefilter_fn
+    """The rule pass over a keyword-only ruleset (no anchored variant):
+    the keyword test alone still compiles to the same program shape."""
+    from trivy_tpu.secret.device_compile import compile_rules
+    from trivy_tpu.secret.rules import builtin_rules
 
-    fn = build_prefilter_fn(_rules(4), CHUNK, backend="pallas")
-    assert "tpu_custom_call" in compile_for(fn, ((ROWS, CHUNK), "uint8"))
+    compiled = compile_rules([r for r in builtin_rules() if r.keywords][:8])
+    kw_only = [r for r in compiled.rule_ids if r not in compiled.anchored_rule_ids]
+    assert kw_only
+    fn, shapes = _rule_pass(compiled)
+    assert "ENTRY" in compile_for(fn, *shapes)
 
 
 def test_decompress_stage(compile_for):

@@ -244,7 +244,7 @@ def test_auto_mode_resolves_by_link_class(monkeypatch, link, want):
     monkeypatch.setattr(t._staged, "run", record)
     t.warm_buckets()
     assert ("decompress" in warmed) == want
-    assert "match" in warmed
+    assert "rules" in warmed
 
 
 def test_dedup_keys_are_codec_invariant(cpu):

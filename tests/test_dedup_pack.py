@@ -111,12 +111,9 @@ def test_ruleset_fingerprint_invalidation():
     assert a.ruleset_fingerprint == c.ruleset_fingerprint  # same ruleset
     assert a.ruleset_fingerprint != d.ruleset_fingerprint  # rule removed
     assert a.ruleset_fingerprint != e.ruleset_fingerprint  # row shape differs
-    # the prefilter table rides the fingerprint: toggling the prefilter
-    # (cached values change schema/meaning) and editing a rule's KEYWORDS
-    # alone (same id/regex order would once have collided in the prefilter
-    # table) must both flip every dedup key
-    f = build(base, prefilter=False)
-    assert a.ruleset_fingerprint != f.ruleset_fingerprint
+    # the keyword table rides the fingerprint: editing a rule's KEYWORDS
+    # alone (same id/regex order would once have collided in the keyword
+    # table) must flip every dedup key
     kw_edit = dict(
         RESTRICTED,
         rules=[

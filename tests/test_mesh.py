@@ -5,9 +5,9 @@ import numpy as np
 import pytest
 
 from tests.secret_samples import SAMPLES
-from trivy_tpu.ops.match import build_match_fn
+from trivy_tpu.ops.rules import build_rules_fn
 from trivy_tpu.parallel.mesh import get_mesh, hit_counts_psum, pad_batch
-from trivy_tpu.secret.device_compile import compile_rules
+from trivy_tpu.secret.device_compile import build_tables, compile_rules
 from trivy_tpu.secret.engine import SecretScanner
 from trivy_tpu.secret.rules import builtin_rules
 from trivy_tpu.secret.tpu_scanner import TpuSecretScanner
@@ -37,17 +37,25 @@ def test_mesh_2d_shapes():
 
 def test_hit_counts_psum():
     compiled = compile_rules(builtin_rules())
+    tables = build_tables(compiled)
     mesh = get_mesh(8)
-    fn = build_match_fn(compiled, 1024)
-    counts_fn = hit_counts_psum(fn, mesh)
+    fn = build_rules_fn(tables.margin, tables.kw_bits)
+    counts_fn = hit_counts_psum(fn, mesh, len(tables.operands()))
     sample = SAMPLES["github-pat"].encode()
     chunk = np.zeros(1024, dtype=np.uint8)
     chunk[: len(sample)] = np.frombuffer(sample, dtype=np.uint8)
-    batch = np.stack([chunk] * 3 + [np.zeros(1024, dtype=np.uint8)] * 5)
-    counts = np.asarray(counts_fn(pad_batch(batch, 8)))
+    batch = pad_batch(np.stack([chunk] * 3 + [np.zeros(1024, dtype=np.uint8)] * 5), 8)
+    counts = np.asarray(counts_fn(batch, *tables.operands()))
+    # the psum'd bit counts equal those of the unsharded pass, row by row
+    out = np.asarray(jax.jit(fn)(batch, *tables.operands()))
+    per_word = np.unpackbits(
+        np.ascontiguousarray(out[:, :-1]).view(np.uint8), bitorder="little"
+    ).reshape(len(out), -1, 32).sum(axis=(0, 2))
+    assert counts.tolist() == per_word.tolist()
+    hit_rows, hit_rules, _, _ = tables.pairs(out)
     ridx = compiled.rule_ids.index("github-pat")
-    assert counts[ridx] == 3
-    assert counts.sum() == 3
+    assert sorted(hit_rows[hit_rules == ridx].tolist()) == [0, 1, 2]
+    assert counts.sum() % 3 == 0 and counts.sum() > 0
 
 
 def test_round_robin_dispatch_parity():

@@ -1,9 +1,8 @@
 """Fused device pass: on-device keyword prefilter (ISSUE 9 tentpole).
 
-Correctness contract: findings are byte-identical with the prefilter on
-(default), off (``prefilter=False`` / --no-secret-prefilter), and against
-the exact CPU engine — across dedup + packing + the multi-stream async
-feed, the 8-device mesh, and the degraded host-fallback path. The
+Correctness contract: findings are byte-identical to the exact CPU engine
+— across dedup + packing + the single- and multi-stream async feed, the
+8-device mesh, and the degraded host-fallback path. The
 prefilter's whole-file candidate semantics mirror the reference's
 MatchKeywords (keyword anywhere in the file), so a rule whose keyword and
 match sit in different chunks — or different batches — must still confirm.
@@ -25,12 +24,10 @@ def cpu():
     return SecretScanner(ScannerConfig.from_dict(RESTRICTED))
 
 
-def build(prefilter=True, **kw):
+def build(**kw):
     kw.setdefault("chunk_len", 2048)
     kw.setdefault("batch_size", 8)
-    return TpuSecretScanner(
-        ScannerConfig.from_dict(RESTRICTED), prefilter=prefilter, **kw
-    )
+    return TpuSecretScanner(ScannerConfig.from_dict(RESTRICTED), **kw)
 
 
 def mixed_corpus():
@@ -69,16 +66,15 @@ def assert_parity(cpu, scanner, files, **scan_kw):
 
 def test_prefilter_parity_mixed_corpus(cpu):
     files = mixed_corpus()
-    on = build(feed_streams=3, inflight=2)
-    off = build(prefilter=False, feed_streams=3, inflight=2)
-    got_on = assert_parity(cpu, on, files)
-    got_off = assert_parity(cpu, off, files)
-    assert [s.to_dict() for s in got_on] == [s.to_dict() for s in got_off]
-    s = on.stats.snapshot()
-    assert s["rows_prefiltered"] > 0
-    assert 0 < s["rows_prefilter_hit"] < s["rows_prefiltered"]
-    # prefilter-off path must record no prefilter traffic at all
-    assert off.stats.snapshot()["rows_prefiltered"] == 0
+    multi = build(feed_streams=3, inflight=2)
+    single = build(feed_streams=1, inflight=1)
+    got_multi = assert_parity(cpu, multi, files)
+    got_single = assert_parity(cpu, single, files)
+    assert [s.to_dict() for s in got_multi] == [s.to_dict() for s in got_single]
+    for scanner in (multi, single):
+        s = scanner.stats.snapshot()
+        assert s["rows_prefiltered"] > 0
+        assert 0 < s["rows_prefilter_hit"] < s["rows_prefiltered"]
 
 
 def test_lure_corpus_skips_nfa_dispatch(cpu):
@@ -89,9 +85,9 @@ def test_lure_corpus_skips_nfa_dispatch(cpu):
     ]
     got = assert_parity(cpu, scanner, files)
     assert all(not s.findings for s in got)
+    # the one rule pass sees every lure row and flags no rule in any
     s = scanner.stats.snapshot()
-    assert s["rows_nfa_skipped"] > 0
-    assert s["batches_nfa_skipped"] > 0
+    assert s["rows_prefiltered"] > 0
     assert s["rows_prefilter_hit"] == 0
 
 
@@ -168,7 +164,7 @@ def test_prefilter_parity_8_device_mesh(cpu):
         ScannerConfig.from_dict(RESTRICTED),
         chunk_len=1024, batch_size=16, mesh=mesh,
     )
-    assert dev.prefilter_on
+    assert dev.compiled.prefilter_keywords
     files = mixed_corpus()
     assert_parity(cpu, dev, files)
     assert dev.stats.snapshot()["rows_prefiltered"] > 0
@@ -230,4 +226,5 @@ def test_prefilter_stage_span_recorded():
     with obs.scan_context(name="prefilter-span", enabled=True) as ctx:
         list(scanner.scan_files(iter(mixed_corpus())))
     recorded = {name for name, durs in ctx.snapshot().items() if durs}
-    assert "secret.prefilter" in recorded
+    # the rule pass's fetch: keyword candidates and anchored hits together
+    assert "secret.device_wait" in recorded

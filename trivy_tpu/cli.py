@@ -203,10 +203,6 @@ def secret_flags() -> FlagGroup:
                  config_name="secret.inflight",
                  help="batches in flight per transfer stream "
                       "(0 = auto: 2, double-buffered)"),
-            Flag("no-secret-prefilter", default=False, value_type=bool,
-                 config_name="secret.no-prefilter",
-                 help="disable the on-device keyword prefilter pass "
-                      "(every batch then pays the full anchored kernel)"),
             Flag("no-shared-arena", default=False, value_type=bool,
                  config_name="secret.no-shared-arena",
                  help="disable the fused secret+license device pass "

@@ -134,7 +134,6 @@ def _artifact_option(ns, opts):
             "java_db_path": opts.get("java_db"),
             "secret_dedup": not opts.get("no_secret_dedup"),
             "secret_pack": not opts.get("no_secret_pack"),
-            "secret_prefilter": not opts.get("no_secret_prefilter"),
             "secret_streams": max(0, int(opts.get("secret_streams") or 0)),
             "secret_inflight": max(0, int(opts.get("secret_inflight") or 0)),
             "host_fallback": not opts.get("no_host_fallback"),

@@ -42,11 +42,35 @@ _scanner_lock = __import__("threading").Lock()
 _scanner_cache: dict = {}
 
 
+# parsed --secret-config files by content digest: each scan of a CLI loop
+# or a server gets the same config object for the same file, so the
+# scanner built for it (compiled rules, device tables) is found again
+_config_cache: dict = {}
+_CONFIG_CACHE_ENTRIES = 8
+
+
+def _load_config(path: str) -> ScannerConfig:
+    import hashlib
+
+    with open(path, "rb") as f:
+        raw = f.read()
+    digest = hashlib.sha256(raw).hexdigest()
+    with _scanner_lock:
+        cfg = _config_cache.get(digest)
+    if cfg is None:
+        cfg = ScannerConfig.from_yaml_bytes(raw)
+        with _scanner_lock:
+            if len(_config_cache) >= _CONFIG_CACHE_ENTRIES:
+                _config_cache.pop(next(iter(_config_cache)))
+            cfg = _config_cache.setdefault(digest, cfg)
+    return cfg
+
+
 def _shared_scanner(
     config, backend: str, parallel: int,
     dedup: bool = True, pack_small: bool = True, hit_cache=None,
     host_fallback: bool = True, feed_streams: int = 0, inflight: int = 0,
-    prefilter: bool = True, tuning=None,
+    tuning=None,
 ):
     # the resolved TuningConfig participates in the cache key by VALUE:
     # two scans tuned differently must not share one compiled scanner's
@@ -61,10 +85,10 @@ def _shared_scanner(
             tuning.compress_min_ratio,
         )
     key = (
-        id(config) if config is not None else None,
+        (config.source_digest or id(config)) if config is not None else None,
         backend, parallel, dedup, pack_small,
         id(hit_cache) if hit_cache is not None else None,
-        host_fallback, feed_streams, inflight, prefilter, tuning_key,
+        host_fallback, feed_streams, inflight, tuning_key,
     )
     with _scanner_lock:
         if key not in _scanner_cache:
@@ -80,12 +104,12 @@ def _shared_scanner(
                         dedup=dedup, pack_small=pack_small,
                         hit_cache=hit_cache, host_fallback=host_fallback,
                         feed_streams=feed_streams, inflight=inflight,
-                        prefilter=prefilter, tuning=tuning,
+                        tuning=tuning,
                     )
                     if scanner.backend == "pallas":
-                        # Mosaic compiles take minutes per kernel shape:
-                        # pay them once per process, concurrently, before
-                        # the first batch instead of one by one mid-scan
+                        # device compiles take seconds to minutes per
+                        # shape: pay them once per process, concurrently,
+                        # before the first batch instead of mid-scan
                         scanner.warm_buckets()
                 except Exception as e:
                     # --backend failed at init (jax import, device probe,
@@ -211,7 +235,7 @@ class SecretAnalyzer(BatchAnalyzer):
             if not rel.startswith(".."):
                 self._config_rel_path = os.path.normpath(rel)
         if self.config_path and os.path.exists(self.config_path):
-            cfg = ScannerConfig.from_yaml_file(self.config_path)
+            cfg = _load_config(self.config_path)
         backend = getattr(options, "backend", "auto")
         self._config = cfg
         self._backend = backend
@@ -233,8 +257,6 @@ class SecretAnalyzer(BatchAnalyzer):
         # per-knob extras above stay as explicit overrides for library
         # callers that never touch the flag layer
         self._tuning = extra.get("tuning")
-        # --no-secret-prefilter opts out of the on-device keyword pass
-        self._prefilter = bool(extra.get("secret_prefilter", True))
         # fused license gate (shared-arena pass), created by commands.py
         # when --scanners includes both secret and license
         self._lic_gate = extra.get("fused_license")
@@ -284,7 +306,7 @@ class SecretAnalyzer(BatchAnalyzer):
                 hit_cache=self._hit_cache,
                 host_fallback=self._host_fallback,
                 feed_streams=self._feed_streams, inflight=self._inflight,
-                prefilter=self._prefilter, tuning=self._tuning,
+                tuning=self._tuning,
             )
             if self._hit_seed and hasattr(self._scanner, "seed_hit_entries"):
                 n = self._scanner.seed_hit_entries(self._hit_seed)

@@ -256,6 +256,8 @@ class TraceContext:
         self.dropped_events = 0
         self.durations: dict[str, _StageAgg] = {}
         self.counters: dict[str, int] = {}
+        # name -> last value of a level set by the scan (rules per lane)
+        self.gauges: dict[str, float] = {}
         # name -> [count, sum, max, bounded raw values]
         self.samples: dict[str, list] = {}
         # scan-health events (degradations, skipped files): recorded even
@@ -474,6 +476,13 @@ class TraceContext:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
 
+    def gauge(self, name: str, value: float) -> None:
+        """Set a level the scan ran at (last value wins)."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self.gauges[name] = value
+
     def sample(self, name: str, value: float) -> None:
         """Record one observation of a fluctuating quantity (queue depth,
         in-flight count); count/sum/max stay exact, raw values are bounded."""
@@ -510,6 +519,7 @@ class TraceContext:
             self.dropped_events = 0
             self.durations.clear()
             self.counters.clear()
+            self.gauges.clear()
             self.samples.clear()
             self.health.clear()
             self.remote.clear()

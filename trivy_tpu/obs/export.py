@@ -364,6 +364,12 @@ def metrics_dict(ctx: TraceContext) -> dict:
         "profile": ctx.merged_profile_dict(),
         "dropped_events": ctx.dropped_events,
     }
+    with ctx._lock:
+        gauges = dict(sorted(ctx.gauges.items()))
+    if gauges:
+        # levels the scan ran at (only present when set, so other exports
+        # stay byte-identical)
+        doc["gauges"] = gauges
     if ctx.timeseries is not None:
         # aggregate view of the live-telemetry series (count/mean/max/
         # p50/p95 per series); full points ride --timeseries-out

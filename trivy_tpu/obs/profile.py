@@ -67,9 +67,9 @@ class ScanProfile:
         self._lock = threading.Lock()
         self._rules: dict[str, list] = {}
         self._buckets: dict[str, list] = {}  # key -> [dispatches, rows, wait_s]
-        # keyword-prefilter pass totals: [rows inspected, rows whose batch
-        # skipped the anchored/NFA dispatch, rows with >=1 candidate rule]
-        self._pre = [0, 0, 0]
+        # keyword-candidate totals of the rule pass: [rows inspected, rows
+        # with >=1 candidate rule]
+        self._pre = [0, 0]
         # fleet cost attribution: per-shard rows (replica, bytes, wall,
         # steal/speculation provenance, attempts) + the per-replica
         # efficiency verdict the coordinator computes at fan-out end
@@ -102,14 +102,12 @@ class ScanProfile:
         with self._lock:
             self._rule(rule_id)[6] += n
 
-    def prefilter_rows(self, rows: int, skipped: int, hit_rows: int = 0) -> None:
-        """The prefilter pass inspected ``rows`` more rows, of which
-        ``skipped`` rode a batch that skipped the anchored dispatch and
-        ``hit_rows`` carried at least one candidate rule."""
+    def prefilter_rows(self, rows: int, hit_rows: int = 0) -> None:
+        """The rule pass inspected ``rows`` more rows for keywords, of
+        which ``hit_rows`` carried at least one candidate rule."""
         with self._lock:
             self._pre[0] += rows
-            self._pre[1] += skipped
-            self._pre[2] += hit_rows
+            self._pre[1] += hit_rows
 
     def confirm(self, rule_id: str, seconds: float, findings: int) -> None:
         """One exact host evaluation of ``rule_id`` took ``seconds`` and
@@ -167,8 +165,7 @@ class ScanProfile:
             with self._lock:
                 rows = int(pre.get("rows", 0))
                 self._pre[0] += rows
-                self._pre[1] += int(pre.get("rows_nfa_skipped", 0))
-                self._pre[2] += int(
+                self._pre[1] += int(
                     pre.get("hit_rows", round(pre.get("selectivity", 0.0) * rows))
                 )
         for rid, f in (doc.get("rules") or {}).items():
@@ -204,7 +201,7 @@ class ScanProfile:
         with self._lock:
             rules = {k: list(v) for k, v in self._rules.items()}
             buckets = {k: list(v) for k, v in self._buckets.items()}
-            pre_rows, pre_skipped, pre_hit_rows = self._pre
+            pre_rows, pre_hit_rows = self._pre
             shards = [dict(s) for s in self._shards]
             fleet_replicas = {k: dict(v)
                               for k, v in self._fleet_replicas.items()}
@@ -247,7 +244,6 @@ class ScanProfile:
         if pre_rows:
             doc["prefilter"] = {
                 "rows": pre_rows,
-                "rows_nfa_skipped": pre_skipped,
                 "hit_rows": pre_hit_rows,
                 # scan-level selectivity: fraction of rows carrying >=1
                 # candidate rule — the knob the smoke gate sanity-checks

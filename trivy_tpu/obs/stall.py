@@ -34,7 +34,6 @@ BUCKETS = {
     "compress": "codec-bound",  # host-side slab encode (compressed feed)
     "decompress": "codec-bound",  # wire-frame placement + decode launch
     "device_wait": "device-bound",
-    "prefilter": "device-bound",  # blocking prefilter-result fetch
     "confirm": "confirm-bound",
     "finalize": "confirm-bound",
     "host_fallback": "confirm-bound",  # degraded-mode exact host rescans

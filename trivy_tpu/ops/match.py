@@ -1,12 +1,10 @@
-"""Batched multi-rule secret matcher kernel.
+"""Plain jnp secret matcher, unrolled per rule: the oracle of the
+table-driven rule pass (``ops/rules.py``).
 
-Replaces the reference's per-file, per-rule Go-regexp loop (ref:
-pkg/fanal/secret/scanner.go:377-463, the north-star hot loop) with one
-data-parallel pass over a batch of fixed-size byte chunks. All device work is
-elementwise boolean/int8 ops over ``[B, C]`` arrays with static shifted
-slices — the shape the TPU VPU executes at HBM bandwidth. Three building
-blocks, chosen specifically to avoid TPU-hostile patterns (int32 multiplies,
-long-axis cumsums, small gathers):
+One data-parallel pass over a batch of fixed-size byte chunks, written
+rule by rule as static jnp code, so its program grows with the ruleset —
+which is why the scanner runs ``ops/rules.py`` instead. Tests hold the
+rule pass to this matcher bit for bit. Three building blocks:
 
 - **Anchor/keyword literals**: the first 4 bytes of every literal compare as
   one packed uint32 word (built once with shifts/ors); remaining bytes are
@@ -58,18 +56,12 @@ def _word32(lit: bytes) -> int:
     return w
 
 
-def build_match_fn(compiled: CompiledRules, chunk_len: int,
-                   include_keywords: bool = True):
+def build_match_fn(compiled: CompiledRules, chunk_len: int):
     """Build the jitted matcher: ``chunks [B, chunk_len] uint8 -> [B, R] bool``.
 
     A True at ``[b, r]`` means rule ``compiled.rule_ids[r]`` *may* match
     within chunk ``b`` (for anchored rules the full device window was
     verified; for keyword rules a keyword substring is present).
-
-    With ``include_keywords=False`` the keyword lane is omitted — the
-    on-device prefilter (ops/prefilter.py) computes exactly those columns
-    in its own cheap first pass, so the full matcher only carries the
-    anchored programs and the two kernels never duplicate work.
     """
     C = chunk_len
     M = max(8, compiled.margin + 4)
@@ -112,7 +104,7 @@ def build_match_fn(compiled: CompiledRules, chunk_len: int,
             + jnp.pad(xw[:, 2:], ((0, 0), (0, 2))) * jnp.uint32(1 << 16)
             + jnp.pad(xw[:, 3:], ((0, 0), (0, 3))) * jnp.uint32(1 << 24)
         )
-        if include_keywords and compiled.keywords:
+        if compiled.keywords:
             is_upper = (x >= 65) & (x <= 90)
             xl = jnp.where(is_upper, x + 32, x)
             xlw = xl.astype(jnp.uint32)
@@ -194,7 +186,7 @@ def build_match_fn(compiled: CompiledRules, chunk_len: int,
                 ok &= shift(na, -v.pre_len - 1)
             per_rule[ridx].append(ok.any(axis=1))
 
-        if include_keywords:
+        if compiled.keywords:
             for ridx, kw in compiled.keywords:
                 ok = literal_hit(kw, xl, word_l)
                 per_rule[ridx].append(ok.any(axis=1))

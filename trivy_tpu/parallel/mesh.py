@@ -511,6 +511,9 @@ class StagedDispatch:
         self.devices = list(devices) if devices is not None else None
         self.rows_multiple = max(1, rows_multiple)
         self._stages: dict = {}
+        # per stage: extra operands (tables) resident on each dispatch
+        # target, keyed by device index (None on the mesh/default flavors)
+        self._operands: dict = {}
         if mesh is not None:
             self.pad_to = int(mesh.shape["data"]) * self.rows_multiple
             self.n_streams = 1
@@ -530,17 +533,39 @@ class StagedDispatch:
         # device); the feed path's probe exposes it as busy_seconds counters
         self.busy = DeviceBusyTracker(self.n_streams)
 
-    def add_stage(self, name: str, fn, out_axes: int = 2) -> None:
+    def add_stage(self, name: str, fn, out_axes: int = 2,
+                  operands: tuple = ()) -> None:
         """Register a row-wise kernel ``[B, C] -> [B, ...]``. ``out_axes``
         is the output rank (2 for per-rule masks, 1 for per-row flags) —
-        the mesh flavor needs it for the shard_map out_specs."""
+        the mesh flavor needs it for the shard_map out_specs. ``operands``
+        (host arrays: a kernel's tables) are placed once on every dispatch
+        target, replicated under a mesh, and passed after the rows, so the
+        compiled program depends on their shapes, not their contents."""
         if self.mesh is not None:
             spec_out = P("data", None) if out_axes == 2 else P("data")
+            # a kernel with loops over row-local state (the rule pass) is
+            # row-local by construction: its carries need no vma check
             fn = _shard_map(
-                fn, mesh=self.mesh, in_specs=(P("data", None),),
-                out_specs=spec_out,
+                fn, mesh=self.mesh,
+                in_specs=(P("data", None),) + (P(),) * len(operands),
+                out_specs=spec_out, check_vma=not operands,
             )
         self._stages[name] = flight.instrument_jit(f"stage.{name}", fn)
+        if operands:
+            if self.mesh is not None:
+                rep = NamedSharding(self.mesh, P())
+                self._operands[name] = {
+                    None: tuple(jax.device_put(o, rep) for o in operands)
+                }
+            elif self.devices:
+                self._operands[name] = {
+                    i: tuple(jax.device_put(o, d) for o in operands)
+                    for i, d in enumerate(self.devices)
+                }
+            else:
+                self._operands[name] = {
+                    None: tuple(jax.device_put(o) for o in operands)
+                }
 
     def has_stage(self, name: str) -> bool:
         return name in self._stages
@@ -644,6 +669,10 @@ class StagedDispatch:
         then called with the parts as positional args."""
         if isinstance(dev, tuple):
             return self._stages[name](*dev)
+        ops = self._operands.get(name)
+        if ops:
+            key = (device_idx or 0) if self.devices else None
+            return self._stages[name](dev, *ops[key])
         return self._stages[name](dev)
 
     def record_result(self, i, ok: bool) -> None:
@@ -776,12 +805,14 @@ def sharded_bytes_score_fn(score_fn, mesh: Mesh):
     return run
 
 
-def hit_counts_psum(match_fn, mesh: Mesh):
-    """Per-rule global hit counts over a sharded batch, reduced with psum
-    over ICI — the telemetry/all-gather path exercised by dryrun_multichip."""
-    def step(chunks):  # local shard [B/d, C]
-        hits = match_fn(chunks)  # [B/d, R] bool
-        local = jnp.sum(hits.astype(jnp.int32), axis=0)  # [R]
+def hit_counts_psum(rules_fn, mesh: Mesh, n_tables: int):
+    """Global set-bit counts per result word of the rule pass
+    (``ops.rules``) over a sharded batch, reduced with psum over ICI — the
+    telemetry/all-gather path exercised by dryrun_multichip. Called as
+    ``fn(rows, *tables)``; the per-row overflow column is left out."""
+    def step(chunks, *tables):  # local shard [B/d, C]
+        words = rules_fn(chunks, *tables)[:, :-1]  # [B/d, out_words - 1]
+        local = jnp.sum(jax.lax.population_count(words).astype(jnp.int32), axis=0)
         return jax.lax.psum(local, axis_name="data")
 
     return flight.instrument_jit(
@@ -789,7 +820,8 @@ def hit_counts_psum(match_fn, mesh: Mesh):
         _shard_map(
             step,
             mesh=mesh,
-            in_specs=(P("data", None),),
+            in_specs=(P("data", None),) + (P(),) * n_tables,
             out_specs=P(),
+            check_vma=False,
         ),
     )

@@ -1,9 +1,9 @@
-"""Compile secret rules into device-executable match programs.
+"""Compile secret rules into the tables of the device rule pass.
 
 The TPU secret path is a two-stage design (the reference itself stages a
 keyword prefilter before the regex, ref: pkg/fanal/secret/scanner.go:174-186,
 377-463): the device evaluates every rule against every chunk and returns a
-per-(chunk, rule) *hit boolean*; the host then runs the exact regex engine
+per-(chunk, rule) *hit bit*; the host then runs the exact regex engine
 (`SecretScanner`) on just the flagged (file, rule) pairs. Device hits may
 contain false positives (they only cost a cheap host confirmation) but must
 never contain false negatives — that invariant is what makes the final
@@ -17,15 +17,33 @@ Each rule compiles into one of three lanes:
   lower (lookarounds, backrefs, optional/variable mid-pattern runs, anchors)
   are *truncated*: dropping a required suffix condition only weakens the
   predicate, which can only add false positives — soundness is preserved.
-- **keyword lane**: rules that don't lower use their keyword prefilter
-  (lowercased substring search, exactly the reference's `MatchKeywords`
-  semantics) on device.
-- **host lane**: rules with neither an anchored program nor keywords are
-  evaluated host-side on every file (the reference also regex-scans every
-  file for keyword-less rules).
+- **keyword lane**: rules that don't lower use their keywords (lowercased
+  substring search, exactly the reference's `MatchKeywords` semantics) on
+  device.
+- **host lane**: rules with neither an anchored program nor keywords of at
+  least ``MIN_ANCHOR`` bytes are evaluated host-side on every file (the
+  reference also regex-scans every file for keyword-less rules).
 
-The compiled output is a set of flat tables consumed by
-`trivy_tpu.ops.match.build_match_fn`.
+The rule axis is data, not code: :func:`build_tables` turns a compiled
+ruleset into flat, power-of-two-padded tables that are arguments of one
+compiled program (`trivy_tpu.ops.rules`), so the program does not depend
+on the rule count and a ruleset of the same padded sizes reuses it.
+
+- **literals**: every keyword and anchor is an *entry* keyed by its
+  ASCII-lowered text (A-Z only, as ``rules.ascii_lower``). Its key is one
+  4-byte window of that text (a 3-byte one for a 3-byte literal, or where
+  every 4-byte window is taken), chosen so that no two entries share a
+  key; the keys sit in a two-choice bucket
+  table of ``BUCKET_SLOTS`` slots, and an entry that finds no free key or
+  slot goes to the overflow list, which every candidate also probes — no
+  literal is ever dropped. A hashed bitmap of the keys is the dense first
+  test at every position; the byte table (lowered text for the keyword
+  test, raw text for the anchor) verifies a candidate exactly, so a hash
+  hit alone never reports.
+- **variants**: each anchored variant's window checks become ``(class,
+  count, delta)`` rows of at most ``CHECK_SPAN`` bytes, padded to a fixed
+  width, evaluated against the class-membership table; the word-boundary
+  test is one more row over the non-alnum class.
 """
 
 from __future__ import annotations
@@ -39,8 +57,8 @@ import numpy as np
 
 from trivy_tpu.secret.rules import Rule
 
-# Minimum anchor literal length: shorter literals are too common to be useful
-# hash anchors and would flood the host-confirm stage.
+# Minimum literal length (anchors and device keywords): shorter literals are
+# too common to be useful hash keys and would flood the host-confirm stage
 MIN_ANCHOR = 3
 # Cap on variants per rule (branch fan-out) before falling back to keywords.
 MAX_VARIANTS = 48
@@ -105,7 +123,7 @@ class Variant:
 class CompiledRules:
     """Device tables for one effective ruleset.
 
-    ``rule_ids`` indexes the output axis of the match kernel; a hit for rule
+    ``rule_ids`` indexes the rule axis of the device pass; a hit for rule
     ``i`` means "run exact rule ``rule_ids[i]`` on this file host-side".
     ``host_rule_ids`` must be evaluated host-side on every file.
     """
@@ -120,11 +138,10 @@ class CompiledRules:
     anchored_rule_ids: list[str] = field(default_factory=list)
     # keyword-prefilter table: (rule_index, ascii-lowered keyword) for EVERY
     # device rule that declares keywords — the keyword lane's own entries
-    # plus anchored-lane rules that also declare keywords. The on-device
-    # prefilter (ops/prefilter.py) runs this table over every arena slab
-    # first; rows with zero candidate rules skip the anchored/NFA dispatch
-    # entirely and candidates gate host confirms at file level (the
-    # reference's MatchKeywords is a whole-file test, scanner.go:174-186).
+    # plus anchored-lane rules that also declare keywords. The rule pass
+    # (ops/rules.py) reports each row's keyword candidates; candidates gate
+    # host confirms at file level (the reference's MatchKeywords is a
+    # whole-file test, scanner.go:174-186).
     prefilter_keywords: list[tuple[int, bytes]] = field(default_factory=list)
 
     @property
@@ -390,6 +407,11 @@ def compile_rules(rules: list[Rule]) -> CompiledRules:
 
     for rule in rules:
         prog = compile_rule(rule)
+        kb = kw_bytes(rule)
+        if any(len(k) < MIN_ANCHOR for k in kb):
+            # a keyword too short to key cannot gate the rule on device: the
+            # rule is unguarded (anchored) or scanned on every file (host)
+            kb = []
         if prog is not None:
             ridx = len(rule_ids)
             rule_ids.append(rule.id)
@@ -402,11 +424,9 @@ def compile_rules(rules: list[Rule]) -> CompiledRules:
                 variants.append((ridx, v))
             # anchored rules that also declare keywords join the prefilter
             # table: their confirms gate on a whole-file keyword candidate
-            kb = kw_bytes(rule)
             if kb:
                 prefilter_keywords.extend((ridx, k) for k in kb)
         elif rule.lower_keywords:
-            kb = kw_bytes(rule)
             if not kb:
                 # no representable keyword: nothing for the device to find
                 host_rule_ids.append(rule.id)
@@ -435,8 +455,8 @@ def compile_rules(rules: list[Rule]) -> CompiledRules:
         margin = max(margin, len(kw))
         span = max(span, len(kw))
     for _, kw in prefilter_keywords:
-        # anchored-lane keywords run only in the prefilter kernel, which
-        # shares the padded-row layout — the overlap must cover them too
+        # anchored-lane keywords are tested in the same rows as their
+        # anchors — the overlap must cover them too
         margin = max(margin, len(kw))
         span = max(span, len(kw))
 
@@ -450,4 +470,332 @@ def compile_rules(rules: list[Rule]) -> CompiledRules:
         span=span,
         anchored_rule_ids=anchored_rule_ids,
         prefilter_keywords=prefilter_keywords,
+    )
+
+
+# -- device tables ----------------------------------------------------------
+
+# bits of the dense key bitmap (the first test at every position): fixed,
+# so every ruleset shares its shape; ~9k literals fill 0.2% of it
+BITMAP_BITS = 22
+# slots per bucket of the key table (two-choice: a key lives in one of two)
+BUCKET_SLOTS = 4
+# bytes per window-check row (longer checks split into several rows)
+CHECK_SPAN = 32
+_GOLD = 0x9E3779B1
+_H1 = 0x85EBCA6B
+_H2 = 0xC2B2AE35
+_SALT3 = 0x5BD1E995
+
+
+def _pow2(n: int, lo: int = 1) -> int:
+    n = max(int(n), lo)
+    return 1 << (n - 1).bit_length()
+
+
+def _mul_hi(key, mult: int, bits: int):
+    """Top ``bits`` bits of the 32-bit product (numpy, uint32 wraparound) —
+    the device computes the same expression on uint32 lanes."""
+    k = np.asarray(key, dtype=np.uint64)
+    return ((k * np.uint64(mult)) & np.uint64(0xFFFFFFFF)) >> np.uint64(32 - bits)
+
+
+def bitmap_hash(key):
+    return _mul_hi(key, _GOLD, BITMAP_BITS)
+
+
+def bucket_hash(key, klen, which: int, bucket_bits: int):
+    k = np.asarray(key, dtype=np.uint64)
+    k = np.where(np.asarray(klen) == 3, k ^ np.uint64(_SALT3), k)
+    return _mul_hi(k, _H1 if which == 1 else _H2, bucket_bits)
+
+
+def _word_le(b: bytes) -> int:
+    return int.from_bytes(b[:4].ljust(4, b"\x00"), "little")
+
+
+def _rarity(w: bytes) -> int:
+    """Key-window preference (lower first): windows with bytes other than
+    lowercase letters occur less often in source text, so they keep the
+    dense first test selective."""
+    return -sum(1 for c in w if not 97 <= c <= 122)
+
+
+@dataclass
+class _Entry:
+    low: bytes  # ASCII-lowered literal: the key text and the keyword test
+    raw: bytes | None = None  # anchor text (case-sensitive test), if any
+    kw_rules: list[int] = field(default_factory=list)
+    variants: list[int] = field(default_factory=list)  # compiled.variants idx
+    off: int = 0
+    klen: int = 4
+    key: int = 0
+
+
+@dataclass
+class RuleTables:
+    """The rule pass's operands (numpy, padded to power-of-two rungs) and
+    the host's maps back from result bits to rule indices.
+
+    Result bit ``k < kw_bits`` is keyword entry ``k``: rules
+    ``kw_rules[kw_start[k]:kw_start[k + 1]]`` have a keyword present;
+    bit ``kw_bits + v`` is anchored variant ``v`` of rule ``var_rule[v]``.
+    """
+
+    arrays: dict[str, np.ndarray]
+    kw_bits: int
+    var_bits: int
+    kw_start: np.ndarray  # [kw_bits + 1] CSR into kw_rules
+    kw_rules: np.ndarray
+    var_rule: np.ndarray  # [var_bits] rule index, -1 for padding
+    entries: int
+    overflow: int  # literals the overflow list holds
+    margin: int  # plane padding the pass needs on each side of a row
+    kw_lane: np.ndarray  # [R] bool: rules whose keyword presence is the hit
+
+    @property
+    def out_words(self) -> int:
+        """u32 words per result row: the bit axis, then the overflow count."""
+        return (self.kw_bits + self.var_bits) // 32 + 1
+
+    def pairs(self, out: np.ndarray) -> tuple[np.ndarray, ...]:
+        """Unpack pass results ``[n, out_words]`` into sparse pairs:
+        ``(hit_rows, hit_rules, cand_rows, cand_rules)``. Candidates are
+        the rules with a keyword in the row; hits are the anchored
+        variants that passed plus the keyword-lane candidates."""
+        words = np.ascontiguousarray(out[:, :-1], dtype=np.uint32)
+        r, w = np.nonzero(words)
+        if len(r):
+            bits = np.unpackbits(
+                words[r, w].view(np.uint8).reshape(-1, 4), axis=1,
+                bitorder="little",
+            )
+            br, bb = np.nonzero(bits)
+            rows = r[br]
+            idx = w[br].astype(np.int64) * 32 + bb
+        else:
+            rows = idx = np.zeros(0, dtype=np.int64)
+        kw = idx < self.kw_bits
+        k_rows, k_bits = rows[kw], idx[kw]
+        lo, hi = self.kw_start[k_bits], self.kw_start[k_bits + 1]
+        n = hi - lo
+        cand_rows = np.repeat(k_rows, n)
+        total = int(n.sum())
+        offs = np.arange(total) - np.repeat(n.cumsum() - n, n)
+        cand_rules = self.kw_rules[np.repeat(lo, n) + offs]
+        # a rule's keywords may share a row: one pair each
+        if len(cand_rows):
+            pair = np.unique(np.stack([cand_rows, cand_rules], 1), axis=0)
+            cand_rows, cand_rules = pair[:, 0], pair[:, 1]
+        v_rows = rows[~kw]
+        v_rules = self.var_rule[idx[~kw] - self.kw_bits]
+        lane = self.kw_lane[cand_rules]
+        hit = np.unique(np.stack([
+            np.concatenate([v_rows, cand_rows[lane]]),
+            np.concatenate([v_rules, cand_rules[lane]]),
+        ], 1), axis=0) if len(v_rows) or lane.any() else np.zeros((0, 2), np.int64)
+        return hit[:, 0], hit[:, 1], cand_rows, cand_rules
+
+    def nbytes(self) -> int:
+        return int(sum(a.nbytes for a in self.arrays.values()))
+
+    def operands(self) -> tuple:
+        return tuple(self.arrays[k] for k in TABLE_ORDER)
+
+
+TABLE_ORDER = (
+    "bitmap", "slot_key", "slot_tag", "ovf_key", "ovf_klen", "ovf_ent",
+    "ent_low", "ent_raw", "ent_mask", "ent_off", "ent_kbit", "ent_anchor",
+    "ent_vstart", "ent_vcount", "var_rows", "classes", "omax",
+)
+
+
+def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
+                 buckets: int = 0) -> RuleTables:
+    """The device tables of ``compiled``. ``buckets`` overrides the key
+    table's bucket count (a power of two; tests force collisions and
+    overflow with a tiny table)."""
+    from trivy_tpu.secret.rules import ascii_lower
+
+    def lower(b: bytes) -> bytes:
+        return ascii_lower(b.decode("latin-1")).encode("latin-1")
+
+    entries: list[_Entry] = []
+    by_low: dict[bytes, int] = {}
+
+    def entry_for(low: bytes) -> _Entry:
+        if low not in by_low:
+            by_low[low] = len(entries)
+            entries.append(_Entry(low=low))
+        return entries[by_low[low]]
+
+    for ridx, kw in compiled.prefilter_keywords:
+        e = entry_for(kw)
+        if ridx not in e.kw_rules:
+            e.kw_rules.append(ridx)
+    for vi, (_ridx, v) in enumerate(compiled.variants):
+        low = lower(v.anchor)
+        e = entry_for(low)
+        if e.raw is None or e.raw == v.anchor:
+            e.raw = v.anchor
+            e.variants.append(vi)
+        else:
+            # same lowered text, other case: an entry of its own, whose key
+            # is taken, so it lands in the overflow list
+            entries.append(_Entry(low=low, raw=v.anchor, variants=[vi]))
+
+    # keys: a unique window per entry, shorter literals first (fewer windows)
+    used: set[tuple[int, int]] = set()
+    overflow: list[int] = []
+    keyed: list[int] = []
+    for i in sorted(range(len(entries)), key=lambda i: (len(entries[i].low), i)):
+        e = entries[i]
+        if len(e.low) == 3:
+            e.off, e.klen, e.key = 0, 3, _word_le(e.low)
+            if (e.key, 3) in used:
+                overflow.append(i)
+            else:
+                used.add((e.key, 3))
+                keyed.append(i)
+            continue
+        # a free 4-byte window, else a free 3-byte one (less selective),
+        # else the overflow list
+        windows = [(o, 4) for o in sorted(range(len(e.low) - 3),
+                                          key=lambda o: (_rarity(e.low[o:o + 4]), o))]
+        windows += [(o, 3) for o in range(len(e.low) - 2)]
+        for o, klen in windows:
+            k = _word_le(e.low[o:o + klen])
+            if (k, klen) not in used:
+                e.off, e.klen, e.key = o, klen, k
+                used.add((k, klen))
+                keyed.append(i)
+                break
+        else:
+            e.off, e.klen, e.key = 0, 4, _word_le(e.low[:4])
+            overflow.append(i)
+
+    nb = buckets or _pow2(2 * len(keyed) // bucket_slots + 1, 16)
+    bucket_bits = nb.bit_length() - 1
+    slot_key = np.zeros(nb * bucket_slots, dtype=np.uint32)
+    slot_tag = np.full(nb * bucket_slots, -1, dtype=np.int32)
+    fill = np.zeros(nb, dtype=np.int32)
+    for i in keyed:
+        e = entries[i]
+        b1 = int(bucket_hash(e.key, e.klen, 1, bucket_bits))
+        b2 = int(bucket_hash(e.key, e.klen, 2, bucket_bits))
+        b = b1 if fill[b1] <= fill[b2] else b2
+        if fill[b] >= bucket_slots:
+            overflow.append(i)
+            continue
+        slot = b * bucket_slots + fill[b]
+        fill[b] += 1
+        slot_key[slot] = e.key
+        slot_tag[slot] = i * 2 + (1 if e.klen == 3 else 0)
+
+    n_ovf = _pow2(len(overflow))
+    ovf_key = np.zeros(n_ovf, dtype=np.uint32)
+    ovf_klen = np.zeros(n_ovf, dtype=np.int32)
+    ovf_ent = np.full(n_ovf, -1, dtype=np.int32)
+    for j, i in enumerate(overflow):
+        ovf_key[j], ovf_klen[j], ovf_ent[j] = entries[i].key, entries[i].klen, i
+
+    # dense bitmap over every key (3-byte keys with each possible 4th byte)
+    bitmap = np.zeros((1 << BITMAP_BITS) // 32, dtype=np.uint32)
+    keys4 = []
+    for e in entries:
+        if e.klen == 4:
+            keys4.append(e.key)
+        else:
+            keys4.extend(e.key | (b << 24) for b in range(256))
+    if keys4:
+        h = bitmap_hash(np.asarray(keys4, dtype=np.uint64)).astype(np.int64)
+        np.bitwise_or.at(bitmap, h >> 5, (np.uint32(1) << (h & 31).astype(np.uint32)))
+
+    # entry byte tables
+    n_ent = _pow2(len(entries))
+    max_len = max([len(e.low) for e in entries] + [4])
+    lw = _pow2(-(-max_len // 4), 4)
+    ent_low = np.zeros((n_ent, lw), dtype=np.uint32)
+    ent_raw = np.zeros((n_ent, lw), dtype=np.uint32)
+    ent_mask = np.zeros((n_ent, lw), dtype=np.uint32)
+    ent_off = np.zeros(n_ent, dtype=np.int32)
+    ent_kbit = np.full(n_ent, -1, dtype=np.int32)
+    ent_anchor = np.zeros(n_ent, dtype=np.int32)
+    ent_vstart = np.zeros(n_ent, dtype=np.int32)
+    ent_vcount = np.zeros(n_ent, dtype=np.int32)
+
+    def words(b: bytes) -> np.ndarray:
+        padded = b.ljust(lw * 4, b"\x00")
+        return np.frombuffer(padded, dtype="<u4").astype(np.uint32)
+
+    kw_entries = [i for i, e in enumerate(entries) if e.kw_rules]
+    kbit = {i: k for k, i in enumerate(kw_entries)}
+    var_order: list[int] = []  # bit v -> compiled.variants index
+    for i, e in enumerate(entries):
+        ent_low[i] = words(e.low)
+        ent_mask[i] = words(b"\xff" * len(e.low))
+        ent_off[i] = e.off
+        if e.raw is not None:
+            ent_raw[i] = words(e.raw)
+            ent_anchor[i] = 1
+            ent_vstart[i] = len(var_order)
+            ent_vcount[i] = len(e.variants)
+            var_order.extend(e.variants)
+        ent_kbit[i] = kbit.get(i, -1)
+
+    # variant window-check rows: (class, count <= CHECK_SPAN, delta)
+    n_cls = compiled.classes.shape[0]
+    nonalnum = n_cls  # extra class row: the word-boundary test
+    classes = np.zeros((_pow2(n_cls + 1), 256), dtype=np.uint8)
+    classes[:n_cls] = compiled.classes
+    classes[nonalnum] = 1
+    for lo, hi in ((48, 57), (65, 90), (97, 122)):
+        classes[nonalnum, lo:hi + 1] = 0
+    rows_of: list[list[tuple[int, int, int]]] = []
+    for vi in var_order:
+        v = compiled.variants[vi][1]
+        rows = []
+        for ch in v.checks:
+            for d in range(0, ch.count, CHECK_SPAN):
+                rows.append((ch.class_id, min(CHECK_SPAN, ch.count - d), ch.delta + d))
+        if v.boundary:
+            rows.append((nonalnum, 1, -v.pre_len - 1))
+        rows_of.append(rows)
+    n_var = _pow2(len(var_order), 32)
+    width = _pow2(max([len(r) for r in rows_of] + [1]), 4)
+    var_rows = np.zeros((n_var, width, 3), dtype=np.int32)
+    for v, rows in enumerate(rows_of):
+        for w, row in enumerate(rows):
+            var_rows[v, w] = row
+    var_rule = np.full(n_var, -1, dtype=np.int64)
+    for v, vi in enumerate(var_order):
+        var_rule[v] = compiled.variants[vi][0]
+
+    n_kw = _pow2(len(kw_entries), 32)
+    kw_start = np.zeros(n_kw + 1, dtype=np.int64)
+    kw_rules: list[int] = []
+    for k, i in enumerate(kw_entries):
+        kw_rules.extend(entries[i].kw_rules)
+        kw_start[k + 1] = len(kw_rules)
+    kw_start[len(kw_entries) + 1:] = len(kw_rules)
+
+    kw_lane = np.zeros(compiled.num_rules, dtype=bool)
+    kw_lane[[ridx for ridx, _ in compiled.keywords]] = True
+    omax = max([e.off for e in entries] + [0])
+    reach = max(compiled.margin, lw * 4) + CHECK_SPAN + 4
+    arrays = {
+        "bitmap": bitmap, "slot_key": slot_key.reshape(nb, bucket_slots),
+        "slot_tag": slot_tag.reshape(nb, bucket_slots),
+        "ovf_key": ovf_key, "ovf_klen": ovf_klen, "ovf_ent": ovf_ent,
+        "ent_low": ent_low, "ent_raw": ent_raw, "ent_mask": ent_mask,
+        "ent_off": ent_off, "ent_kbit": ent_kbit, "ent_anchor": ent_anchor,
+        "ent_vstart": ent_vstart, "ent_vcount": ent_vcount,
+        "var_rows": var_rows, "classes": classes.reshape(-1),
+        "omax": np.asarray(omax, dtype=np.int32),
+    }
+    return RuleTables(
+        arrays=arrays, kw_bits=n_kw, var_bits=n_var, kw_start=kw_start,
+        kw_rules=np.asarray(kw_rules, dtype=np.int64), var_rule=var_rule,
+        entries=len(entries), overflow=len(overflow),
+        margin=-(-reach // 128) * 128, kw_lane=kw_lane,
     )

@@ -109,13 +109,19 @@ class ScannerConfig:
 
     @classmethod
     def from_yaml_file(cls, path: str) -> "ScannerConfig":
+        with open(path, "rb") as f:
+            return cls.from_yaml_bytes(f.read())
+
+    @classmethod
+    def from_yaml_bytes(cls, raw: bytes) -> "ScannerConfig":
         import hashlib
 
         import yaml  # baked in via transformers' dependency set
 
-        with open(path, "rb") as f:
-            raw = f.read()
-        cfg = cls.from_dict(yaml.safe_load(raw.decode("utf-8")) or {})
+        # libyaml's loader where present: an org ruleset of thousands of
+        # rules takes seconds through the pure-Python one
+        loader = getattr(yaml, "CSafeLoader", yaml.SafeLoader)
+        cfg = cls.from_dict(yaml.load(raw.decode("utf-8"), Loader=loader) or {})
         # content digest of the config FILE: the persistent dedup store and
         # the incremental-scan manifest fold this into their namespace keys,
         # so editing the rule file invalidates every persisted entry even

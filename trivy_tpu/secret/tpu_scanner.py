@@ -1,10 +1,10 @@
-"""TPU-backed secret scanner: device prefilter + exact host confirmation.
+"""TPU-backed secret scanner: device rule pass + exact host confirmation.
 
 Pipeline (replaces the reference's walk→goroutine→regexp chain, ref:
 pkg/fanal/secret/scanner.go:377 and SURVEY.md §3.2):
 
-  files → overlapping fixed-size chunks → [B, C] batches → device match
-  kernel → per-(file, rule) candidates → exact `SecretScanner` restricted to
+  files → overlapping fixed-size chunks → [B, C] batches → device rule
+  pass → per-(file, rule) candidates → exact `SecretScanner` restricted to
   candidate rules → findings (byte-identical to the CPU backend).
 
 Chunk overlap equals the compiled ruleset's maximum device window, so every
@@ -48,12 +48,13 @@ guarantee the bench gate enforces.
 
 The device side is a FUSED pass (README "Fused device pass"): each batch
 is placed once (`parallel.mesh.StagedDispatch`) and every detector reads
-the resident rows — the keyword prefilter first (its candidate mask gates
-whether the anchored matcher dispatches at all, feeds keyword-lane hits
-directly, and accumulates per-file candidates that gate host confirms at
-whole-file MatchKeywords semantics), then the anchored matcher when
-needed, then (with ``--scanners secret,license``) the license gram gate
-(`licensing/fused.py`) so license candidacy costs zero extra link bytes.
+the resident rows — the table-driven rule pass (``ops/rules.py``: one
+compiled program per bucket rung whatever the rule count, the ruleset's
+tables its operands) returns one bitset per row of anchored hits and
+keyword candidates; candidates accumulate per file and gate host confirms
+at whole-file MatchKeywords semantics; then (with ``--scanners
+secret,license``) the license gram gate (`licensing/fused.py`) so license
+candidacy costs zero extra link bytes.
 
 The feed path sends link bytes ≪ corpus bytes:
 
@@ -100,9 +101,8 @@ import numpy as np
 
 from trivy_tpu import faults, log, obs
 from trivy_tpu.obs import recorder as flight
-from trivy_tpu.ops.match import build_match_fn
 from trivy_tpu.secret.compress import COMPRESS_MIN_RATIO, CompressedSlab
-from trivy_tpu.secret.device_compile import CompiledRules, compile_rules
+from trivy_tpu.secret.device_compile import CompiledRules, build_tables, compile_rules
 from trivy_tpu.secret.feed import ChunkArena, row_windows
 from trivy_tpu.secret.engine import ScannerConfig, SecretScanner
 from trivy_tpu.types import Secret
@@ -111,14 +111,16 @@ logger = log.logger("secret:tpu")
 
 DEFAULT_CHUNK_LEN = 65536
 DEFAULT_BATCH = 64
-# pallas path: small self-contained rows.
+# accelerator path: small self-contained rows.
 # 1024 x 8 KiB = 8 MiB batches: small enough that pack -> transfer ->
 # kernel -> confirm overlap through the pipeline (a 32 MiB batch serializes
 # the whole corpus behind one blocking device wait), big enough to amortize
-# kernel launch; 8 KiB rows keep the kernel's VMEM working set off the
-# spill cliff that 16 KiB rows hit
+# kernel launch
 PALLAS_CHUNK_LEN = 8192
 PALLAS_BATCH = 1024
+# rows per batch multiple on the accelerator path (the fused license
+# gate's row blocks)
+PALLAS_ROWS_MULTIPLE = 32
 # per-transfer-stream in-flight window: 2 = double buffering (batch N+1's
 # transfer overlaps batch N's kernel on the same stream)
 FEED_INFLIGHT = 2
@@ -149,8 +151,10 @@ HIT_CACHE_ENTRIES = 1 << 16
 # the device decompressor, whose output must be byte-identical to a raw
 # upload; the bump invalidates stores written by builds without that
 # parity guarantee. Keys still hash UNCOMPRESSED row content, so entries
-# stay codec-invariant: toggling --secret-compress never flips a key)
-HIT_CACHE_VERSION = 4
+# stay codec-invariant: toggling --secret-compress never flips a key;
+# v5: the table-driven rule pass checks every rule on every row, so a
+# verdict's nfa_ran is always true)
+HIT_CACHE_VERSION = 5
 # re-dispatches allowed per failed batch before the failure escalates to
 # the scan-level fallback ladder (OOM-shaped splits don't consume this
 # budget: halving strictly shrinks the batch, so it terminates on its own)
@@ -185,6 +189,20 @@ def chunk_spans(n: int, chunk_len: int, overlap: int) -> list[int]:
     return starts
 
 
+def _keyword_spans(keywords, lower: str, windows) -> list[tuple[int, int]]:
+    """Spans of the (lowered) keyword occurrences that start inside the
+    flagged ``windows``: the device flags a row only where a keyword lies
+    whole inside it."""
+    out = []
+    for ws, we in windows:
+        for kw in keywords:
+            i = lower.find(kw, ws, we + len(kw))
+            while i != -1:
+                out.append((i, i + len(kw)))
+                i = lower.find(kw, i + 1, we + len(kw))
+    return out
+
+
 @dataclass
 class _FileState:
     path: str
@@ -192,15 +210,11 @@ class _FileState:
     pending: int  # chunks not yet matched
     # candidate rule index -> chunk windows (byte spans) where it hit
     rules: dict[int, list[tuple[int, int]]] = field(default_factory=dict)
-    # prefilter candidate rules accumulated over EVERY chunk of the file
-    # (None when the prefilter pass is off): guarded rules are confirmed
-    # only when present here — the reference's whole-file MatchKeywords
-    # gate, applied from device data instead of a host lowercase scan
-    cand: set[int] | None = None
-    # guarded anchored rules whose kernel was SKIPPED for >=1 chunk of this
-    # file (batch had no candidates): their windows may be incomplete, so a
-    # candidate among them confirms via full scan instead of windows
-    unchecked: set[int] = field(default_factory=set)
+    # keyword candidate rules accumulated over EVERY chunk of the file:
+    # guarded rules are confirmed only when present here — the
+    # reference's whole-file MatchKeywords gate, applied from device data
+    # instead of a host lowercase scan
+    cand: set[int] = field(default_factory=set)
 
 
 class ScanStats:
@@ -226,10 +240,8 @@ class ScanStats:
         "degraded",          # scans that fell back to the exact host path
         "chunks_warm_hit",   # rows served from the PERSISTENT store
         "bytes_warm_hit",    # corpus bytes those rows covered
-        "rows_prefiltered",  # rows the keyword prefilter pass inspected
+        "rows_prefiltered",  # rows the rule pass inspected for keywords
         "rows_prefilter_hit",  # rows with >=1 candidate rule
-        "rows_nfa_skipped",  # rows whose batch skipped the anchored kernel
-        "batches_nfa_skipped",  # batches resolved by the prefilter alone
         "license_rows_gated",    # arena rows the license gram gate read
         "license_rows_flagged",  # rows that flagged a license candidate
         # compressed wire format (secret/compress.py): bytes_uploaded above
@@ -293,8 +305,6 @@ class TpuSecretScanner:
         # (one per round-robin device; SINGLE_DEVICE_STREAMS on one
         # accelerator; 2 on the CPU backend)
         inflight: int = 0,  # in-flight batches per stream; 0 = FEED_INFLIGHT
-        prefilter: bool = True,  # on-device keyword prefilter first pass
-        # (--no-secret-prefilter); auto-disabled when no rule has keywords
         tuning=None,  # trivy_tpu.tuning.TuningConfig; None = env-resolved
         # defaults (no implicit AUTOTUNE.json discovery — the CLI layer
         # resolves the full CLI > env > autotune > topology chain and
@@ -327,60 +337,26 @@ class TpuSecretScanner:
         self.tuning = tuning
 
         self.exact = SecretScanner(config)
-        self.compiled: CompiledRules = compile_rules(self.exact.rules)
+        with obs.current().span("secret.rules_build"):
+            self.compiled: CompiledRules = compile_rules(self.exact.rules)
+            self.tables = build_tables(self.compiled)
         if backend == "auto":
             platform = jax.devices()[0].platform
             backend = "pallas" if platform not in ("cpu", "METAL") else "xla"
         self.backend = backend
-        # fused-pass prefilter: a cheap keyword-only kernel runs over every
-        # slab first; the full matcher drops its keyword lane and batches
-        # with no anchored candidates skip it entirely (ops/prefilter.py)
-        self.prefilter_on = bool(prefilter) and bool(
-            self.compiled.prefilter_keywords
-        )
         if backend == "pallas":
-            from trivy_tpu.ops.match_pallas import BLOCK_ROWS, build_match_fn_pallas
-
             self.chunk_len = chunk_len or PALLAS_CHUNK_LEN
             self.batch_size = batch_size or PALLAS_BATCH
-            rows_mult = BLOCK_ROWS
-            match_fn = build_match_fn_pallas(
-                self.compiled, self.chunk_len,
-                include_keywords=not self.prefilter_on,
-            )
+            rows_mult = PALLAS_ROWS_MULTIPLE
         else:
             self.chunk_len = chunk_len or DEFAULT_CHUNK_LEN
             self.batch_size = batch_size or DEFAULT_BATCH
             rows_mult = 1
-            match_fn = build_match_fn(
-                self.compiled, self.chunk_len,
-                include_keywords=not self.prefilter_on,
-            )
-        if self.prefilter_on:
-            from trivy_tpu.ops.prefilter import build_prefilter_fn
+        from trivy_tpu.ops.rules import build_rules_fn
 
-            self._prefilter_fn = build_prefilter_fn(
-                self.compiled, self.chunk_len, backend=backend
-            )
-        else:
-            self._prefilter_fn = None
-        # rule-axis index tables the fused pass resolves against
-        g = self.compiled.guarded
-        anchored_idx = {i for i, _ in self.compiled.variants}
-        self._kw_lane_cols = np.asarray(
-            sorted({i for i, _ in self.compiled.keywords}), dtype=np.int64
-        )
-        self._guarded_anchored = frozenset(
-            i for i in anchored_idx if g[i]
-        )
-        self._guarded_anchored_cols = np.asarray(
-            sorted(self._guarded_anchored), dtype=np.int64
-        )
-        # anchored rules with no keywords are never prefilter-gated: their
-        # presence forces the anchored kernel on every batch
-        self._has_unguarded_anchored = any(not g[i] for i in anchored_idx)
+        rules_fn = build_rules_fn(self.tables.margin, self.tables.kw_bits)
         self._guarded_ids = frozenset(
-            self.compiled.rule_ids[i] for i in np.nonzero(g)[0]
+            self.compiled.rule_ids[i] for i in np.nonzero(self.compiled.guarded)[0]
         )
         self.overlap = max(64, self.compiled.span + 1)
         if self.overlap > self.chunk_len // 2:
@@ -390,6 +366,7 @@ class TpuSecretScanner:
                 f">= {2 * self.overlap}"
             )
         self._rules_by_id = {r.id: r for r in self.exact.rules}
+        self._rule_pos = {r.id: i for i, r in enumerate(self.exact.rules)}
         # windowed confirmation is sound only when flagged chunks bound the
         # match START: always true on the anchored lane; true on the keyword
         # lane only for bounded-width rules whose keyword provably sits
@@ -405,6 +382,7 @@ class TpuSecretScanner:
             and r.max_match_width is not None
             and r.max_match_width <= 8192
         }
+        self._keyword_windowed = self._windowed_ids - anchored
         self.confirm_workers = confirm_workers or CONFIRM_WORKERS
 
         # -- dedup hit cache ------------------------------------------------
@@ -424,15 +402,10 @@ class TpuSecretScanner:
         # every persisted namespace, loudly (hitstore namespace marker)
         fp.update(b"cfg:")
         fp.update(getattr(config, "source_digest", "").encode() or b"-")
-        # prefilter table fingerprint: cached vectors now carry candidate
-        # masks derived from the keyword table, so a keyword add/remove/edit
-        # — or toggling the prefilter itself, which changes the cached value
-        # semantics (nfa_ran bookkeeping) — must flip every key
-        if self.prefilter_on:
-            fp.update(b"pf:")
-            fp.update(self.compiled.prefilter_fingerprint())
-        else:
-            fp.update(b"pf-off")
+        # keyword table fingerprint: cached vectors carry candidate masks
+        # derived from it, so a keyword add/remove/edit must flip every key
+        fp.update(b"pf:")
+        fp.update(self.compiled.prefilter_fingerprint())
         self.ruleset_fingerprint = fp.digest()
         self._dedup = dedup
         self._pack_small = pack_small
@@ -474,25 +447,24 @@ class TpuSecretScanner:
                 rr_devices = local
 
         # fused-pass dispatch: ONE placement per batch, every device
-        # detector (prefilter, anchored match, license gram gate) runs
+        # detector (the rule pass, the license gram gate) runs
         # against the resident rows — the upload is shared, not repeated
         self._staged = StagedDispatch(
             mesh=mesh, devices=rr_devices, rows_multiple=rows_mult
         )
-        self._staged.add_stage("match", match_fn, out_axes=2)
-        if self._prefilter_fn is not None:
-            self._staged.add_stage("prefilter", self._prefilter_fn, out_axes=2)
+        self._staged.add_stage(
+            "rules", rules_fn, out_axes=2, operands=self.tables.operands()
+        )
         self._stage_lock = threading.Lock()
         row_multiple = self._staged.pad_to
 
-        # bench/back-compat surface: the raw jitted match kernel (pure and
-        # traceable, pads short batches itself) plus the stream/breaker
-        # attributes tests and warm-up loops key off
-        match_stage = self._staged.stage_fn("match")
+        # bench/back-compat surface: the rule pass on host rows (pads short
+        # batches itself) plus the stream/breaker attributes tests and
+        # warm-up loops key off
         pad_to = self._staged.pad_to
 
         def _compat_match(chunks):
-            return match_stage(pad_batch(chunks, pad_to))
+            return self._staged.run("rules", pad_batch(chunks, pad_to))
 
         if rr_devices is not None:
             _compat_match.n_streams = len(rr_devices)
@@ -526,8 +498,8 @@ class TpuSecretScanner:
         # dispatch-shape bucket ladder: every shape compiles exactly once
         # (variable trailing-batch shapes would recompile per distinct size).
         # The default ladder stops at B/4 (3 rungs): each extra rung costs
-        # a full Mosaic compile of every kernel (about a minute or two for
-        # the built-in ruleset), while padding a short trailing batch up
+        # a full compile of every stage (tens of seconds for the rule pass,
+        # whatever the ruleset), while padding a short trailing batch up
         # to the smallest rung costs microseconds of device time. The
         # depth is a tuning knob (--secret-bucket-rungs) because the
         # tradeoff flips on corpora dominated by tiny trailing batches.
@@ -617,13 +589,10 @@ class TpuSecretScanner:
     # Cached value per row digest (the "row verdict"): a 4-tuple
     #   (hit_rules, cand_rules, nfa_ran, lic)
     # - hit_rules: device hit vector (anchored hits + keyword-lane hits)
-    # - cand_rules: prefilter candidate rules (== hit_rules' keyword part
-    #   plus anchored-lane keyword presences); () when the prefilter is off
-    # - nfa_ran: False when the row's batch skipped the anchored kernel —
-    #   a conservative, row-pure marker: replaying it marks every guarded
-    #   anchored rule unchecked for the row's file, so a candidate there
-    #   confirms via full scan (soundness does not depend on which batch
-    #   the row originally rode)
+    # - cand_rules: keyword candidate rules (== hit_rules' keyword part
+    #   plus anchored-lane keyword presences)
+    # - nfa_ran: always True (the rule pass checks every rule on every
+    #   row); kept so the persisted verdict schema is unchanged
     # - lic: fused license-gate verdict: True/False, or None when the
     #   row's batch never ran the gate (consumers must not trust None)
     # The digest is keyed with the ruleset fingerprint (which now folds in
@@ -670,9 +639,9 @@ class TpuSecretScanner:
 
     def warm_buckets(self) -> None:
         """Compile every (bucket shape × placement × stage) combination
-        outside any timed region — prefilter + match (+ the license gram
-        gate, when registered) per rung, and the decompress stage per
-        (rung, wire rung) — so the first real batch never pays a compile.
+        outside any timed region — the rule pass (+ the license gram gate,
+        when registered) per rung, and the decompress stage per (rung,
+        wire rung) — so the first real batch never pays a compile.
 
         The compiles run concurrently: each is an independent XLA/Mosaic
         compile that releases the GIL, and a cold start of the built-in
@@ -681,24 +650,24 @@ class TpuSecretScanner:
         per-device batch counters see only real traffic."""
         from trivy_tpu.parallel.mesh import pad_batch
 
-        # one job per executable, the slowest (the anchored matcher) first
-        stages = ["match"] + [
-            s for s in ("prefilter", "license") if self._staged.has_stage(s)
+        # one job per executable, the rule pass first
+        stages = ["rules"] + [
+            s for s in ("license",) if self._staged.has_stage(s)
         ]
         jobs = []
         for name in stages:
-            for place in self._staged.placements():
+            for didx, place in enumerate(self._staged.placements()):
                 for b in self._buckets:
                     rows = pad_batch(
                         np.zeros((b, self.chunk_len), dtype=np.uint8),
                         self._staged.pad_to,
                     )
-                    jobs.append((place, (rows,), name))
+                    jobs.append((place, didx, (rows,), name))
         if self._codec is not None:
             # one decompress compile per (rows, wire-rung) pair; the
             # downstream stages reuse the raw-shape executables compiled
             # above (the decoder's [b, C] output IS the raw shape)
-            for place in self._staged.placements():
+            for didx, place in enumerate(self._staged.placements()):
                 for b in self._buckets:
                     for rung in self._wire_rungs[b]:
                         frame = (
@@ -707,13 +676,13 @@ class TpuSecretScanner:
                             np.zeros(b, dtype=np.int32),
                             np.zeros(b, dtype=np.uint8),
                         )
-                        jobs.append((place, frame, "decompress"))
+                        jobs.append((place, didx, frame, "decompress"))
 
         def warm(job) -> None:
-            place, parts, name = job
+            place, didx, parts, name = job
             dev = tuple(place(a) for a in parts)
             np.asarray(
-                self._staged.run(name, dev if len(dev) > 1 else dev[0])
+                self._staged.run(name, dev if len(dev) > 1 else dev[0], didx)
             )
 
         workers = max(1, min(len(jobs), os.cpu_count() or 1))
@@ -754,7 +723,12 @@ class TpuSecretScanner:
         """
         if license_gate is not None:
             self._ensure_license_stage()
-        run = _ScanRun(self, files, obs.current(), license_gate)
+        ctx = obs.current()
+        # rules per lane: on the device (anchored or keyword) and scanned
+        # by the host on every file
+        ctx.gauge("secret.rules_device", self.compiled.num_rules)
+        ctx.gauge("secret.rules_host_lane", len(self.compiled.host_rule_ids))
+        run = _ScanRun(self, files, ctx, license_gate)
         run.start()
         try:
             next_emit = 0
@@ -824,29 +798,22 @@ class TpuSecretScanner:
             self.compiled.rule_ids[i]: w for i, w in st.rules.items()
         }
         host_ids = set(self.compiled.host_rule_ids)
-        cand_ids: set[str] | None = None
-        unchecked_ids: set[str] = set()
-        extra_ids: set[str] = set()
-        if st.cand is not None:
-            rid = self.compiled.rule_ids
-            cand_ids = {rid[i] for i in st.cand}
-            unchecked_ids = {rid[i] for i in st.unchecked}
-            # guarded anchored rules that are file-level candidates but
-            # whose kernel was skipped for some chunk may have recorded no
-            # window at all — they still need a (full-scan) confirmation
-            extra_ids = (unchecked_ids & cand_ids) - set(windows_by_id)
-        if not windows_by_id and not host_ids and not extra_ids:
+        rid = self.compiled.rule_ids
+        cand_ids = {rid[i] for i in st.cand}
+        if not windows_by_id and not host_ids:
             return Secret(file_path=st.path)
         content = st.data.decode("latin-1")
         lower = ascii_lower(content)
         global_blocks = self.exact.global_block_spans(content)
         hits = []
-        for rule in self.exact.rules_for_path(st.path):
-            if (
-                cand_ids is not None
-                and rule.id in self._guarded_ids
-                and rule.id not in cand_ids
-            ):
+        # only flagged and host-lane rules can confirm: walk those, in rule
+        # order, through the path filters (a ruleset of thousands of rules
+        # would otherwise cost thousands of path tests per file)
+        flagged = sorted(set(windows_by_id) | host_ids, key=self._rule_pos.__getitem__)
+        for rule in (self._rules_by_id[i] for i in flagged):
+            if not rule.match_path(st.path) or rule.allow_path(st.path):
+                continue
+            if rule.id in self._guarded_ids and rule.id not in cand_ids:
                 # no keyword of this rule occurs anywhere in the file: the
                 # exact engine's match_keywords would reject it, so the
                 # confirm (and its wasted_confirm cost) is skipped outright
@@ -854,17 +821,15 @@ class TpuSecretScanner:
                 continue
             t0 = time.perf_counter() if prof is not None else 0.0
             if rule.id in windows_by_id:
-                if rule.id in unchecked_ids:
-                    # some chunk of this file never ran the rule's anchored
-                    # kernel (its batch was prefilter-skipped): windows may
-                    # be incomplete, so fall back to the full-content scan
-                    locs = self.exact.find_rule_locations_fullscan(
-                        rule, content, lower, global_blocks
-                    )
-                elif rule.id in self._windowed_ids:
-                    # regex runs only around the device-flagged chunk windows
+                if rule.id in self._windowed_ids:
+                    # regex runs only around the device-flagged chunk windows;
+                    # on the keyword lane, around the keyword occurrences in
+                    # them (every match holds one, within its width)
+                    windows = windows_by_id[rule.id]
+                    if rule.id in self._keyword_windowed:
+                        windows = _keyword_spans(rule.lower_keywords, lower, windows)
                     locs = self.exact.find_rule_locations_in_windows(
-                        rule, content, lower, windows_by_id[rule.id], global_blocks
+                        rule, content, lower, windows, global_blocks
                     )
                 else:
                     # keyword lane without a start bound: the flagged chunk
@@ -873,10 +838,6 @@ class TpuSecretScanner:
                     locs = self.exact.find_rule_locations_fullscan(
                         rule, content, lower, global_blocks
                     )
-            elif rule.id in extra_ids:
-                locs = self.exact.find_rule_locations_fullscan(
-                    rule, content, lower, global_blocks
-                )
             elif rule.id in host_ids:
                 locs = self.exact.find_rule_locations(
                     rule, content, lower, global_blocks
@@ -1328,9 +1289,10 @@ class _ScanRun:
     def _apply_hits(self, batch: list) -> None:
         """Credit resolved rows to their file segments; ``batch`` is
         ``[(segs, hit_rules, cand_rules, nfa_ran, lic)]`` (the row-verdict
-        schema of the dedup cache). Every row hit applies to every segment
-        — cross-segment false candidates are discarded by the exact
-        confirm. Files whose last pending row resolved here go to the
+        schema of the dedup cache; ``nfa_ran`` is always true since every
+        batch runs the whole rule pass). Every row hit applies to every
+        segment — cross-segment false candidates are discarded by the
+        exact confirm. Files whose last pending row resolved here go to the
         confirm pool (the semaphore is taken OUTSIDE the pipeline lock so
         a full confirm queue stalls only the calling thread, not
         resolution bookkeeping on other streams)."""
@@ -1345,7 +1307,6 @@ class _ScanRun:
                     prof.gate_hit(rule_ids[r])
                 for r in cand_rules:
                     prof.prefilter_hit(rule_ids[r])
-        guarded_anchored = sc._guarded_anchored
         # license verdicts land BEFORE the rows count as resolved: once a
         # file's last row resolves, its result can be emitted and the
         # license analyzer reads the gate, while another stream may still
@@ -1354,17 +1315,14 @@ class _ScanRun:
             self._apply_lic(segs, lic)
         ready: list[tuple[int, _FileState]] = []
         with self.lock:
-            for segs, hit_rules, cand_rules, nfa_ran, _ in batch:
+            for segs, hit_rules, cand_rules, _, _ in batch:
                 for fidx, ws, we in segs:
                     st = self.states.get(fidx)
                     if st is None:
                         continue  # already moved to the host path
                     for r in hit_rules:
                         st.rules.setdefault(r, []).append((ws, we))
-                    if st.cand is not None:
-                        st.cand.update(cand_rules)
-                        if not nfa_ran:
-                            st.unchecked.update(guarded_anchored)
+                    st.cand.update(cand_rules)
                 for fidx, _, _ in segs:
                     st = self.states.get(fidx)
                     if st is None:
@@ -1378,47 +1336,38 @@ class _ScanRun:
 
     def _resolve(
         self,
-        batch_hits: np.ndarray | None,
+        out: np.ndarray,
         batch_meta: list,
-        pre: np.ndarray | None = None,
         lic_arr: np.ndarray | None = None,
-        nfa_ran: bool = True,
         lic_ran: bool = False,
     ) -> None:
-        """Fold one fetched batch into file state. ``batch_hits`` is the
-        anchored/full matcher output (None when the batch skipped it),
-        ``pre`` the prefilter candidate mask, ``lic_arr`` the license gate
-        flags; all sliced to the live rows here (rows past
+        """Fold one fetched batch into file state. ``out`` is the rule
+        pass's ``[B, out_words]`` result, ``lic_arr`` the license gate
+        flags; both sliced to the live rows here (rows past
         ``len(batch_meta)`` are bucket padding)."""
         sc = self.sc
         n = len(batch_meta)
+        out = np.asarray(out[:n])
+        hit_rows, hit_rules, cand_rows, cand_rules = sc.tables.pairs(out)
         by_row: dict[int, list[int]] = {}
         cand_by_row: dict[int, list[int]] = {}
-        if pre is not None:
-            pre = np.asarray(pre[:n], dtype=bool)
-            rows, ridx = np.nonzero(pre)
-            for row, r in zip(rows.tolist(), ridx.tolist()):
-                cand_by_row.setdefault(row, []).append(r)
-            # keyword-lane hits come straight from the prefilter mask (the
-            # matcher no longer carries that lane); anchored hits from the
-            # matcher when it ran
-            kw_cols = sc._kw_lane_cols
-            hits = (
-                np.array(batch_hits[:n], dtype=bool, copy=True)
-                if batch_hits is not None
-                else np.zeros((n, sc.compiled.num_rules), dtype=bool)
-            )
-            if len(kw_cols):
-                hits[:, kw_cols] |= pre[:, kw_cols]
-            hit_rows = int(pre.any(axis=1).sum())
-            sc.stats.add(rows_prefiltered=n, rows_prefilter_hit=hit_rows)
-            if self.prof is not None:
-                self.prof.prefilter_rows(n, 0 if nfa_ran else n, hit_rows)
-        else:
-            hits = np.asarray(batch_hits[:n])
-        rows, ridx = np.nonzero(hits)
-        for row, r in zip(rows.tolist(), ridx.tolist()):
+        for row, r in zip(hit_rows.tolist(), hit_rules.tolist()):
             by_row.setdefault(row, []).append(r)
+        for row, r in zip(cand_rows.tolist(), cand_rules.tolist()):
+            cand_by_row.setdefault(row, []).append(r)
+        hit_n = len(cand_by_row)
+        sc.stats.add(rows_prefiltered=n, rows_prefilter_hit=hit_n)
+        if self.prof is not None:
+            self.prof.prefilter_rows(n, hit_n)
+        if self.enabled:
+            R = sc.compiled.num_rules
+            flagged = np.unique(np.concatenate(
+                [hit_rows * R + hit_rules, cand_rows * R + cand_rules]
+            ))
+            self.ctx.count("secret.rule_candidates", len(flagged))
+            ovf = int(out[:, -1].sum())
+            if ovf:
+                self.ctx.count("secret.rule_probe_overflow", ovf)
         lic_by_row: dict[int, tuple[int, ...]] = {}
         if lic_ran and lic_arr is not None:
             lic_arr = np.asarray(lic_arr[:n], dtype=bool)
@@ -1432,10 +1381,10 @@ class _ScanRun:
             )
         apply: list = []
         for row, (key, segs, _) in enumerate(batch_meta):
-            hit_rules = tuple(by_row.get(row, ()))
-            cand_rules = tuple(cand_by_row.get(row, ()))
+            hit_rules_r = tuple(by_row.get(row, ()))
+            cand_rules_r = tuple(cand_by_row.get(row, ()))
             lic = lic_by_row.get(row, ()) if lic_ran else None
-            verdict = (hit_rules, cand_rules, nfa_ran, lic)
+            verdict = (hit_rules_r, cand_rules_r, True, lic)
             apply.append((segs,) + verdict)
             if key is not None:
                 self.sc._hit_put(key, verdict)
@@ -1455,16 +1404,12 @@ class _ScanRun:
         stages against the resident rows, keep a bounded in-flight window
         (double buffering), fetch the oldest, resolve inline.
 
-        Per-batch staging: the PREFILTER (and, when fused, the license
-        gram gate) dispatches immediately with the upload; its fetch is
-        the batch's first sync point and decides whether the anchored
-        matcher runs at all — a batch with no candidate for any anchored
-        rule (and no unguarded anchored rules in the ruleset) resolves
-        from the prefilter mask alone, skipping the expensive kernel AND
-        its host confirms. The slab releases only after the LAST stage
-        reading the resident input has fetched (device_put may alias host
-        memory on the CPU backend — an earlier release would let the
-        feeder refill bytes a later-stage kernel still reads).
+        Per-batch staging: the rule pass (and, when fused, the license
+        gram gate) dispatches immediately with the upload. The slab
+        releases only after the LAST stage reading the resident input has
+        fetched (device_put may alias host memory on the CPU backend — an
+        earlier release would let the feeder refill bytes a later-stage
+        kernel still reads).
 
         Per-batch failure ladder as in README "Robustness": re-dispatch up
         to ``batch_retries`` times (under round-robin the retry lands on
@@ -1476,14 +1421,13 @@ class _ScanRun:
         Stall instrumentation (all on the spawning scan's context):
         ``secret.feed_wait`` is time blocked on the host feed
         (feed-starved), ``secret.dispatch`` the enqueue/transfer handoff
-        (upload-bound), ``secret.prefilter`` the prefilter fetch,
-        ``secret.device_wait`` the blocking matcher fetch (device-bound)."""
+        (upload-bound), ``secret.device_wait`` the blocking rule-pass
+        fetch (device-bound)."""
         from trivy_tpu.parallel.mesh import DevicesUnavailable
 
         sc = self.sc
         ctx = self.ctx
         staged = sc._staged
-        use_pf = staged.has_stage("prefilter")
         lic_gate = self.lic_gate
         prof = self.prof
         stats = sc.stats
@@ -1605,10 +1549,7 @@ class _ScanRun:
                             placed = True
                         h = {}
                     with ctx.span("secret.dispatch"):
-                        if use_pf:
-                            h["pre"] = staged.run("prefilter", dev, didx)
-                        else:
-                            h["match"] = staged.run("match", dev, didx)
+                        h["rules"] = staged.run("rules", dev, didx)
                         if want_lic(m):
                             h["lic"] = staged.run("license", dev, didx)
                 except Exception as e:
@@ -1629,34 +1570,14 @@ class _ScanRun:
                     "device.fetch", key=f"d{didx if didx is not None else 0}"
                 )
                 t0 = time.perf_counter() if prof is not None else 0.0
-                pre = None
-                arr = None
-                nfa_ran = True
-                if use_pf:
-                    with ctx.span("secret.prefilter"):
-                        pre = np.asarray(h["pre"])
-                    live = pre[: len(meta)]
-                    need_nfa = sc._has_unguarded_anchored or bool(
-                        live[:, sc._guarded_anchored_cols].any()
-                        if len(sc._guarded_anchored_cols)
-                        else False
-                    )
-                    if need_nfa:
-                        with ctx.span("secret.dispatch"):
-                            mh = staged.run("match", dev, didx)
-                        with ctx.span("secret.device_wait"):
-                            arr = np.asarray(mh)
-                    else:
-                        nfa_ran = False
-                        stats.add(
-                            rows_nfa_skipped=len(meta),
-                            batches_nfa_skipped=1,
-                        )
-                        if self.enabled:
-                            ctx.count("secret.rows_nfa_skipped", len(meta))
-                else:
-                    with ctx.span("secret.device_wait"):
-                        arr = np.asarray(h["match"])
+                with ctx.span("secret.device_wait"):
+                    arr = np.asarray(h["rules"])
+                if self.enabled:
+                    # the pass's shapes, as bytes: input rows, result rows,
+                    # and the tables each execution reads
+                    ctx.count("secret.rule_row_bytes", arr.shape[0] * chunk_len)
+                    ctx.count("secret.rule_out_bytes", arr.nbytes)
+                    ctx.count("secret.rule_table_bytes", sc.tables.nbytes())
                 lic_ran = "lic" in h
                 lic_arr = np.asarray(h["lic"]) if lic_ran else None
                 # every stage that reads the resident input has now fetched
@@ -1683,10 +1604,7 @@ class _ScanRun:
             if sid is not None:
                 self.arena.release(sid)
             if not self.degraded:
-                self._resolve(
-                    arr, meta, pre=pre, lic_arr=lic_arr,
-                    nfa_ran=nfa_ran, lic_ran=lic_ran,
-                )
+                self._resolve(arr, meta, lic_arr=lic_arr, lic_ran=lic_ran)
 
         def release_pending() -> None:
             while pending:
@@ -1780,7 +1698,6 @@ class _ScanRun:
             if self.lic_gate is None
             else sc.ruleset_fingerprint + b":lic"
         )
-        use_pf = sc.prefilter_on
         lic_gate = self.lic_gate
         # widest gram/anchor byte window the device gate provably sees
         # interior to some chunk (licensing/fused.py host patch covers the
@@ -2115,10 +2032,7 @@ class _ScanRun:
             starts = chunk_spans(len(data), chunk_len, sc.overlap)
             if not register_state(
                 fidx,
-                _FileState(
-                    path=path, data=data, pending=len(starts),
-                    cand=set() if use_pf else None,
-                ),
+                _FileState(path=path, data=data, pending=len(starts)),
             ):
                 lic_skip(path)
                 self._submit_host(fidx, path, data)
@@ -2203,19 +2117,13 @@ class _ScanRun:
                                 lic_gate.cover(path)
                             self._submit_confirm(
                                 fidx,
-                                _FileState(
-                                    path=path, data=data, pending=0,
-                                    cand=set() if use_pf else None,
-                                ),
+                                _FileState(path=path, data=data, pending=0),
                             )
                         elif sc._pack_small and len(data) <= pack_max:
                             stats.add(bytes_in=len(data))
                             if register_state(
                                 fidx,
-                                _FileState(
-                                    path=path, data=data, pending=1,
-                                    cand=set() if use_pf else None,
-                                ),
+                                _FileState(path=path, data=data, pending=1),
                             ):
                                 lic_register(fidx, path, data)
                                 add_small(fidx, data)
