@@ -30,6 +30,18 @@ Per batch ``rows [B, C] uint8``:
    ``(class, count, delta)`` rows gather their bytes and test them
    against the class-membership table. A passing variant sets its bit.
 
+Steps 3 and 4 run on two lanes. The narrow lane tests every entry on its
+first ``lw`` words and every variant on its first ``width`` rows, where
+``build_tables`` derives both widths from the ruleset's own histogram
+(the smallest power of two that holds ``NARROW_PCT``, 95%, of its
+literals and of its variants), not from its widest literal or variant.
+An entry or variant wider than that has a row of its own in the
+full-width spill tables (``ent_spill``, ``var_spill``): the (candidate,
+slot) pairs and items of such entries that pass the narrow test, a
+necessary condition, are compacted into pages of ``SPILL`` the same way
+and tested again at full width, which decides them. A ruleset with
+nothing wider has an empty spill lane, whose loops run zero times.
+
 Work is per candidate position, not per variant per position. The result
 is one ``[B, out_words]`` uint32 array: the keyword and variant bits, then
 per row the number of literals verified through the overflow list.
@@ -58,6 +70,8 @@ from trivy_tpu.secret.device_compile import (
 PAGE = 4096
 # (anchor, variant) items per page of the inner loop
 ITEMS = 1024
+# (candidate, slot) pairs or items per page of a spill lane's loop
+SPILL = 128
 
 
 def _mul_hi(k, mult: int, bits: int):
@@ -93,7 +107,8 @@ def rules_pass(rows, *tables, margin: int, kw_bits: int):
     nbits = kw_bits + t["var_rows"].shape[0]
     nb, S = t["slot_key"].shape
     bucket_bits = nb.bit_length() - 1
-    lw = t["ent_low"].shape[1]
+    lanes = jnp.arange(t["ent_low"].shape[1], dtype=jnp.int32) * 4
+    lanes_w = jnp.arange(t["spill_low"].shape[1], dtype=jnp.int32) * 4
 
     x = jnp.pad(rows, ((0, 0), (M, M + 3)))  # [B, L + 3] real zero bytes
     up = (x >= 65) & (x <= 90)
@@ -117,12 +132,44 @@ def rules_pass(rows, *tables, margin: int, kw_bits: int):
     slot_key, slot_tag = t["slot_key"], t["slot_tag"]
     ovf_key, ovf_klen, ovf_ent = t["ovf_key"], t["ovf_klen"], t["ovf_ent"]
     n_slots = 2 + ovf_ent.shape[0]
-    lanes = jnp.arange(lw, dtype=jnp.int32) * 4
     span = jnp.arange(CHECK_SPAN, dtype=jnp.int32)
 
     def bucket(k, klen, mult):
         k = jnp.where(klen == 3, k ^ jnp.uint32(_SALT3), k)
         return _mul_hi(k, mult, bucket_bits).astype(jnp.int32)
+
+    def literal(r, s, ent_low, ent_raw, ent_mask, lanes):
+        """(lowered, raw) matches of the literals ``ent_*`` (rows of
+        words, ``lanes`` wide) starting at ``s`` of rows ``r``."""
+        pos = jnp.clip(M + s[..., None] + lanes, 0, L - 1)
+        widx = r[..., None] * L + pos
+        low_ok = jnp.all(((lw_flat[widx] ^ ent_low) & ent_mask) == 0, axis=-1)
+        raw_ok = jnp.all(((rw_flat[widx] ^ ent_raw) & ent_mask) == 0, axis=-1)
+        return low_ok, raw_ok
+
+    def variant(vr, r, s):
+        """Whether the window-check rows ``vr [n, width, 3]`` of variants
+        anchored at ``s`` of rows ``r`` all pass."""
+        cls, cnt, dlt = vr[..., 0], vr[..., 1], vr[..., 2]
+        p = jnp.clip(M + s[:, None, None] + dlt[:, :, None] + span, 0, L - 1)
+        byte = xb_flat[r[:, None, None] * L + p].astype(jnp.int32)
+        member = t["classes"][cls[:, :, None] * 256 + byte] == 1
+        return jnp.all(member | (span >= cnt[:, :, None]), axis=(1, 2))
+
+    def compacted(flags, body, init):
+        """Fold ``carry = body(j, ok, carry)`` over pages of ``SPILL`` of
+        the indices ``j`` where ``flags`` is set (``ok``: ``j`` is one)."""
+        fcs = jnp.cumsum(flags.astype(jnp.int32))
+
+        def step(st):
+            k, carry = st
+            i = k * SPILL + jnp.arange(SPILL, dtype=jnp.int32)
+            ok = i < fcs[-1]
+            j = jnp.where(ok, jnp.searchsorted(fcs, i, side="right"), 0)
+            return k + 1, body(j, ok, carry)
+
+        return jax.lax.while_loop(lambda st: st[0] * SPILL < fcs[-1], step,
+                                  (jnp.int32(0), init))[1]
 
     def page(state):
         pg, bits, ovf = state
@@ -150,15 +197,28 @@ def rules_pass(rows, *tables, margin: int, kw_bits: int):
         ents = jnp.concatenate([e4[:, None], e3[:, None], eo], axis=1)
         ents = jnp.where(valid[:, None], ents, -1)  # [PAGE, n_slots]
 
-        # 3. exact literal test at the literal's start
+        # 3. exact literal test at the literal's start: the narrow lane,
+        # then the spill lane for the wider entries that passed it
         e = jnp.maximum(ents, 0)
         s = qq[:, None] - t["ent_off"][e]
         live = (ents >= 0) & (s >= 0) & (s < C)
-        pos = jnp.clip(M + s[:, :, None] + lanes, 0, L - 1)
-        widx = row[:, None, None] * L + pos
-        mask = t["ent_mask"][e]
-        low_ok = jnp.all(((lw_flat[widx] ^ t["ent_low"][e]) & mask) == 0, axis=2)
-        raw_ok = jnp.all(((rw_flat[widx] ^ t["ent_raw"][e]) & mask) == 0, axis=2)
+        low_ok, raw_ok = literal(row[:, None], s, t["ent_low"][e], t["ent_raw"][e],
+                                 t["ent_mask"][e], lanes)
+        wide = t["ent_spill"][e].reshape(-1)
+        r_flat = jnp.broadcast_to(row[:, None], s.shape).reshape(-1)
+        s_flat = s.reshape(-1)
+
+        def spill_literals(jj, ok, oks):
+            w = jnp.maximum(wide[jj], 0)
+            lo, ra = literal(r_flat[jj], s_flat[jj], t["spill_low"][w], t["spill_raw"][w],
+                             t["spill_mask"][w], lanes_w)
+            dst = jnp.where(ok, jj, PAGE * n_slots)
+            return oks[0].at[dst].set(lo, mode="drop"), oks[1].at[dst].set(ra, mode="drop")
+
+        low_ok, raw_ok = compacted(
+            live.reshape(-1) & (wide >= 0) & (low_ok | raw_ok).reshape(-1),
+            spill_literals, (low_ok.reshape(-1), raw_ok.reshape(-1)))
+        low_ok, raw_ok = low_ok.reshape(s.shape), raw_ok.reshape(s.shape)
         kbit = t["ent_kbit"][e]
         kw_ok = live & low_ok & (kbit >= 0)
         an_ok = live & raw_ok & (t["ent_anchor"][e] == 1)
@@ -169,12 +229,12 @@ def rules_pass(rows, *tables, margin: int, kw_bits: int):
         ovf = ovf.at[row].add(jnp.sum((kw_ok | an_ok) & from_ovf, axis=1).astype(jnp.uint32)
                               * valid.astype(jnp.uint32))
 
-        # 4. anchored variants of the verified anchors
+        # 4. anchored variants of the verified anchors: the narrow lane,
+        # then the spill lane for the wider variants that passed it
         counts = jnp.where(an_ok, t["ent_vcount"][e], 0).reshape(-1)
         ics = jnp.cumsum(counts)
         n_items = ics[-1]
         vstart = t["ent_vstart"][e].reshape(-1)
-        s_flat = s.reshape(-1)
 
         def items(st2):
             k, bits = st2
@@ -183,16 +243,19 @@ def rules_pass(rows, *tables, margin: int, kw_bits: int):
             pair = jnp.where(ok_i, jnp.searchsorted(ics, i, side="right"), 0)
             within = i - (ics[pair] - counts[pair])
             var = jnp.where(ok_i, vstart[pair] + within, 0)
-            r_i = row[pair // n_slots]
+            r_i = r_flat[pair]
             s_i = s_flat[pair]
-            vr = t["var_rows"][var]  # [ITEMS, width, 3]
-            cls, cnt, dlt = vr[..., 0], vr[..., 1], vr[..., 2]
-            p = jnp.clip(M + s_i[:, None, None] + dlt[:, :, None] + span, 0, L - 1)
-            byte = xb_flat[r_i[:, None, None] * L + p].astype(jnp.int32)
-            member = t["classes"][cls[:, :, None] * 256 + byte] == 1
-            ok = jnp.all(member | (span >= cnt[:, :, None]), axis=(1, 2)) & ok_i
-            idx = jnp.where(ok, r_i * nbits + kw_bits + var, B * nbits)
-            return k + 1, bits.at[idx].set(True, mode="drop")
+            ok = variant(t["var_rows"][var], r_i, s_i) & ok_i
+            vw = t["var_spill"][var]
+            idx = jnp.where(ok & (vw < 0), r_i * nbits + kw_bits + var, B * nbits)
+            bits = bits.at[idx].set(True, mode="drop")
+
+            def spill_variants(jj, ok_j, bits):
+                ok_w = variant(t["spill_var_rows"][jnp.maximum(vw[jj], 0)], r_i[jj], s_i[jj])
+                idx = jnp.where(ok_w & ok_j, r_i[jj] * nbits + kw_bits + var[jj], B * nbits)
+                return bits.at[idx].set(True, mode="drop")
+
+            return k + 1, compacted(ok & (vw >= 0), spill_variants, bits)
 
         _, bits = jax.lax.while_loop(lambda st2: st2[0] * ITEMS < n_items, items,
                                      (jnp.int32(0), bits))

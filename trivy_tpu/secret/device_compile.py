@@ -41,9 +41,13 @@ on the rule count and a ruleset of the same padded sizes reuses it.
   test, raw text for the anchor) verifies a candidate exactly, so a hash
   hit alone never reports.
 - **variants**: each anchored variant's window checks become ``(class,
-  count, delta)`` rows of at most ``CHECK_SPAN`` bytes, padded to a fixed
-  width, evaluated against the class-membership table; the word-boundary
-  test is one more row over the non-alnum class.
+  count, delta)`` rows of at most ``CHECK_SPAN`` bytes, evaluated against
+  the class-membership table; the word-boundary test is one more row over
+  the non-alnum class.
+- **widths**: the byte and check-row tables are as wide as ``NARROW_PCT``
+  percent of the ruleset's literals and variants need; each wider entry
+  and variant also has a full-width row in the spill tables, which the
+  pass reads only for it.
 """
 
 from __future__ import annotations
@@ -482,6 +486,9 @@ BITMAP_BITS = 22
 BUCKET_SLOTS = 4
 # bytes per window-check row (longer checks split into several rows)
 CHECK_SPAN = 32
+# percent of the entries (literal words) and of the variants (check rows)
+# that the verify steps' narrow lane holds; wider ones take the spill lane
+NARROW_PCT = 95
 _GOLD = 0x9E3779B1
 _H1 = 0x85EBCA6B
 _H2 = 0xC2B2AE35
@@ -491,6 +498,13 @@ _SALT3 = 0x5BD1E995
 def _pow2(n: int, lo: int = 1) -> int:
     n = max(int(n), lo)
     return 1 << (n - 1).bit_length()
+
+
+def _narrow(sizes: list[int]) -> int:
+    """The smallest power of two, at least 4, that holds ``NARROW_PCT``
+    percent of ``sizes``."""
+    k = -(-NARROW_PCT * len(sizes) // 100)
+    return _pow2(sorted(sizes)[k - 1] if sizes else 1, 4)
 
 
 def _mul_hi(key, mult: int, bits: int):
@@ -552,6 +566,10 @@ class RuleTables:
     overflow: int  # literals the overflow list holds
     margin: int  # plane padding the pass needs on each side of a row
     kw_lane: np.ndarray  # [R] bool: rules whose keyword presence is the hit
+    # [out_words - 1] u32: the result bits the spill lane decides (keyword
+    # entries and variants wider than the narrow lane, and the variants of
+    # such anchors)
+    spill_bits: np.ndarray
 
     @property
     def out_words(self) -> int:
@@ -596,6 +614,11 @@ class RuleTables:
         ], 1), axis=0) if len(v_rows) or lane.any() else np.zeros((0, 2), np.int64)
         return hit[:, 0], hit[:, 1], cand_rows, cand_rules
 
+    def spill_hits(self, out: np.ndarray) -> int:
+        """Set (row, bit) pairs of ``out [n, out_words]`` that the spill
+        lane decided."""
+        return int(np.bitwise_count(np.asarray(out)[:, :-1] & self.spill_bits).sum())
+
     def nbytes(self) -> int:
         return int(sum(a.nbytes for a in self.arrays.values()))
 
@@ -607,14 +630,17 @@ TABLE_ORDER = (
     "bitmap", "slot_key", "slot_tag", "ovf_key", "ovf_klen", "ovf_ent",
     "ent_low", "ent_raw", "ent_mask", "ent_off", "ent_kbit", "ent_anchor",
     "ent_vstart", "ent_vcount", "var_rows", "classes", "omax",
+    "ent_spill", "spill_low", "spill_raw", "spill_mask", "var_spill", "spill_var_rows",
 )
 
 
 def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
-                 buckets: int = 0) -> RuleTables:
+                 buckets: int = 0, widths: tuple[int, int] | None = None) -> RuleTables:
     """The device tables of ``compiled``. ``buckets`` overrides the key
     table's bucket count (a power of two; tests force collisions and
-    overflow with a tiny table)."""
+    overflow with a tiny table). ``widths`` overrides the narrow lane's
+    literal words and check rows; tests pass the full widths, which leave
+    the spill lane empty."""
     from trivy_tpu.secret.rules import ascii_lower
 
     def lower(b: bytes) -> bytes:
@@ -711,10 +737,13 @@ def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
         h = bitmap_hash(np.asarray(keys4, dtype=np.uint64)).astype(np.int64)
         np.bitwise_or.at(bitmap, h >> 5, (np.uint32(1) << (h & 31).astype(np.uint32)))
 
-    # entry byte tables
+    # entry byte tables: every entry's first lw words (the narrow lane),
+    # and the entries wider than that whole on the spill lane
     n_ent = _pow2(len(entries))
-    max_len = max([len(e.low) for e in entries] + [4])
-    lw = _pow2(-(-max_len // 4), 4)
+    n_words = [-(-len(e.low) // 4) for e in entries]
+    lw_full = _pow2(max(n_words + [1]), 4)
+    lw = _narrow(n_words) if widths is None else min(widths[0], lw_full)
+    wide = [i for i, n in enumerate(n_words) if n > lw]
     ent_low = np.zeros((n_ent, lw), dtype=np.uint32)
     ent_raw = np.zeros((n_ent, lw), dtype=np.uint32)
     ent_mask = np.zeros((n_ent, lw), dtype=np.uint32)
@@ -723,25 +752,35 @@ def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
     ent_anchor = np.zeros(n_ent, dtype=np.int32)
     ent_vstart = np.zeros(n_ent, dtype=np.int32)
     ent_vcount = np.zeros(n_ent, dtype=np.int32)
+    ent_spill = np.full(n_ent, -1, dtype=np.int32)
+    spill_low = np.zeros((_pow2(len(wide)), lw_full), dtype=np.uint32)
+    spill_raw = np.zeros_like(spill_low)
+    spill_mask = np.zeros_like(spill_low)
 
-    def words(b: bytes) -> np.ndarray:
-        padded = b.ljust(lw * 4, b"\x00")
-        return np.frombuffer(padded, dtype="<u4").astype(np.uint32)
+    def words(b: bytes, n: int) -> np.ndarray:
+        return np.frombuffer(b[:n * 4].ljust(n * 4, b"\x00"), dtype="<u4").astype(np.uint32)
 
     kw_entries = [i for i, e in enumerate(entries) if e.kw_rules]
     kbit = {i: k for k, i in enumerate(kw_entries)}
     var_order: list[int] = []  # bit v -> compiled.variants index
     for i, e in enumerate(entries):
-        ent_low[i] = words(e.low)
-        ent_mask[i] = words(b"\xff" * len(e.low))
+        ent_low[i] = words(e.low, lw)
+        ent_mask[i] = words(b"\xff" * len(e.low), lw)
         ent_off[i] = e.off
         if e.raw is not None:
-            ent_raw[i] = words(e.raw)
+            ent_raw[i] = words(e.raw, lw)
             ent_anchor[i] = 1
             ent_vstart[i] = len(var_order)
             ent_vcount[i] = len(e.variants)
             var_order.extend(e.variants)
         ent_kbit[i] = kbit.get(i, -1)
+    for j, i in enumerate(wide):
+        e = entries[i]
+        ent_spill[i] = j
+        spill_low[j] = words(e.low, lw_full)
+        spill_mask[j] = words(b"\xff" * len(e.low), lw_full)
+        if e.raw is not None:
+            spill_raw[j] = words(e.raw, lw_full)
 
     # variant window-check rows: (class, count <= CHECK_SPAN, delta)
     n_cls = compiled.classes.shape[0]
@@ -761,12 +800,23 @@ def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
         if v.boundary:
             rows.append((nonalnum, 1, -v.pre_len - 1))
         rows_of.append(rows)
+    # every variant's first width rows (the narrow lane), and the variants
+    # wider than that whole on the spill lane
     n_var = _pow2(len(var_order), 32)
-    width = _pow2(max([len(r) for r in rows_of] + [1]), 4)
+    n_rows = [len(r) for r in rows_of]
+    width_full = _pow2(max(n_rows + [1]), 4)
+    width = _narrow(n_rows) if widths is None else min(widths[1], width_full)
+    wide_v = [v for v, n in enumerate(n_rows) if n > width]
     var_rows = np.zeros((n_var, width, 3), dtype=np.int32)
     for v, rows in enumerate(rows_of):
-        for w, row in enumerate(rows):
+        for w, row in enumerate(rows[:width]):
             var_rows[v, w] = row
+    var_spill = np.full(n_var, -1, dtype=np.int32)
+    spill_var_rows = np.zeros((_pow2(len(wide_v)), width_full, 3), dtype=np.int32)
+    for j, v in enumerate(wide_v):
+        var_spill[v] = j
+        for w, row in enumerate(rows_of[v]):
+            spill_var_rows[j, w] = row
     var_rule = np.full(n_var, -1, dtype=np.int64)
     for v, vi in enumerate(var_order):
         var_rule[v] = compiled.variants[vi][0]
@@ -779,10 +829,18 @@ def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
         kw_start[k + 1] = len(kw_rules)
     kw_start[len(kw_entries) + 1:] = len(kw_rules)
 
+    spill_bit = np.zeros(n_kw + n_var, dtype=bool)
+    spill_bit[n_kw + np.asarray(wide_v, dtype=np.int64)] = True
+    for i in wide:
+        if i in kbit:
+            spill_bit[kbit[i]] = True
+        v0 = n_kw + ent_vstart[i]
+        spill_bit[v0:v0 + ent_vcount[i]] = True
+
     kw_lane = np.zeros(compiled.num_rules, dtype=bool)
     kw_lane[[ridx for ridx, _ in compiled.keywords]] = True
     omax = max([e.off for e in entries] + [0])
-    reach = max(compiled.margin, lw * 4) + CHECK_SPAN + 4
+    reach = max(compiled.margin, lw_full * 4) + CHECK_SPAN + 4
     arrays = {
         "bitmap": bitmap, "slot_key": slot_key.reshape(nb, bucket_slots),
         "slot_tag": slot_tag.reshape(nb, bucket_slots),
@@ -792,10 +850,13 @@ def build_tables(compiled: CompiledRules, bucket_slots: int = BUCKET_SLOTS,
         "ent_vstart": ent_vstart, "ent_vcount": ent_vcount,
         "var_rows": var_rows, "classes": classes.reshape(-1),
         "omax": np.asarray(omax, dtype=np.int32),
+        "ent_spill": ent_spill, "spill_low": spill_low, "spill_raw": spill_raw,
+        "spill_mask": spill_mask, "var_spill": var_spill, "spill_var_rows": spill_var_rows,
     }
     return RuleTables(
         arrays=arrays, kw_bits=n_kw, var_bits=n_var, kw_start=kw_start,
         kw_rules=np.asarray(kw_rules, dtype=np.int64), var_rule=var_rule,
         entries=len(entries), overflow=len(overflow),
         margin=-(-reach // 128) * 128, kw_lane=kw_lane,
+        spill_bits=np.packbits(spill_bit, bitorder="little").view("<u4").astype(np.uint32),
     )

@@ -1365,6 +1365,7 @@ class _ScanRun:
                 [hit_rows * R + hit_rules, cand_rows * R + cand_rules]
             ))
             self.ctx.count("secret.rule_candidates", len(flagged))
+            self.ctx.count("secret.rule_spill_hits", sc.tables.spill_hits(out))
             ovf = int(out[:, -1].sum())
             if ovf:
                 self.ctx.count("secret.rule_probe_overflow", ovf)
