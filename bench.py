@@ -225,12 +225,6 @@ def bench_e2e_best(scanner, files, rng, device_mbs, reps=None):
         uploaded = s1["bytes_uploaded"] - s0["bytes_uploaded"]
         chunks = max(1, s1["chunks"] - s0["chunks"])
         pre_rows = s1["rows_prefiltered"] - s0["rows_prefiltered"]
-        # compressed-feed wire ratio (shipped / what raw would have cost);
-        # None when the codec is off for this topology — the rep doc then
-        # simply lacks the key, keeping old rounds comparable
-        comp = s1["bytes_compressed"] - s0["bytes_compressed"]
-        raw_fb = s1["bytes_raw_fallback"] - s0["bytes_raw_fallback"]
-        raw_eq = (s1["bytes_raw_equiv"] - s0["bytes_raw_equiv"]) + raw_fb
         return {
             "mbs": mbs,
             "findings": n_findings,
@@ -244,7 +238,6 @@ def bench_e2e_best(scanner, files, rng, device_mbs, reps=None):
                 if pre_rows
                 else None
             ),
-            "wire_ratio": (comp + raw_fb) / raw_eq if raw_eq else None,
             "ctx": ctx,
         }
 
@@ -267,8 +260,6 @@ def bench_e2e_best(scanner, files, rng, device_mbs, reps=None):
             rep_doc["prefilter_selectivity"] = round(
                 r["prefilter_selectivity"], 4
             )
-        if r["wire_ratio"] is not None:
-            rep_doc["wire_compression_ratio"] = round(r["wire_ratio"], 4)
         reps_out.append(rep_doc)
         link = link_after
     # the traced rep: stall verdict + per-rule/per-bucket profile for the
@@ -318,10 +309,6 @@ def bench_e2e_best(scanner, files, rng, device_mbs, reps=None):
             "buckets": prof.get("buckets") or {},
         },
     }
-    if m.get("wire"):
-        # the traced rep's full wire-accounting block (compression ratio +
-        # gate/fallback counters) — same shape --metrics-out ships
-        traced["wire"] = m["wire"]
     vals = [r["e2e_mbs"] for r in reps_out]
     spread = {
         "min": round(min(vals), 2),
@@ -2400,74 +2387,6 @@ def _smoke_admission_off() -> str | None:
     return None
 
 
-def _smoke_compress() -> str | None:
-    """Compressed-feed gates. (1) Zero-cost-when-off: a compression-off
-    scanner builds no codec tables, registers no decompress stage, keeps
-    no wire-rung state, and its scans never surface the wire-ratio gauge.
-    (2) Compression-on earns its keep: a printable corpus ships strictly
-    below raw (the PACK7 floor guarantees it), and an all-binary corpus
-    books every batch as an exactly-raw fallback — zero compressed bytes.
-    Returns an error string on violation."""
-    from trivy_tpu.obs.metrics import REGISTRY
-    from trivy_tpu.secret.tpu_scanner import TpuSecretScanner
-
-    rng = np.random.default_rng(17)
-    gauge = "trivy_tpu_wire_compression_ratio"
-    # the off leg runs FIRST: the gauge is process-global once a
-    # compressed scan registers it, so absence is only checkable while it
-    # has never fired in this process (skip that check if it already has)
-    gauge_was_absent = gauge not in REGISTRY.render()
-    off = TpuSecretScanner(compress="off", chunk_len=2048, batch_size=8)
-    if off.compress_on or off._codec is not None:
-        return "compression-off scanner built codec tables"
-    if "decompress" in off._staged._stages:
-        return "compression-off scanner registered a decompress stage"
-    if off._wire_rungs:
-        return "compression-off scanner allocated wire-rung state"
-    printable = [
-        (f"smoke/p_{i}.txt",
-         bytes(rng.integers(0x20, 0x7F, 6000, np.uint8)))
-        for i in range(12)
-    ]
-    list(off.scan_files(printable))
-    s = off.stats.snapshot()
-    if s["bytes_compressed"] or s["batches_compressed"] or s["bytes_gated"]:
-        return "compression-off scan booked codec byte counters"
-    if gauge_was_absent and gauge in REGISTRY.render():
-        return "compression-off scan registered the wire-ratio gauge"
-
-    on = TpuSecretScanner(compress="on", chunk_len=2048, batch_size=8)
-    s0 = on.stats.snapshot()
-    list(on.scan_files(printable))
-    s1 = on.stats.snapshot()
-    shipped = (s1["bytes_compressed"] - s0["bytes_compressed"]) + (
-        s1["bytes_raw_fallback"] - s0["bytes_raw_fallback"]
-    )
-    raw_equiv = (s1["bytes_raw_equiv"] - s0["bytes_raw_equiv"]) + (
-        s1["bytes_raw_fallback"] - s0["bytes_raw_fallback"]
-    )
-    if not raw_equiv:
-        return "compression-on printable scan booked no wire accounting"
-    ratio = shipped / raw_equiv
-    if not ratio < 1.0:
-        return (f"compression-on printable corpus ratio {ratio:.4f} "
-                f"not strictly < 1.0")
-    binary = [
-        (f"smoke/b_{i}.bin",
-         bytes(rng.integers(0x80, 0x100, 6000, np.uint8)))
-        for i in range(8)
-    ]
-    s0 = on.stats.snapshot()
-    list(on.scan_files(binary))
-    s1 = on.stats.snapshot()
-    if s1["bytes_compressed"] - s0["bytes_compressed"]:
-        return ("compression-on binary corpus shipped compressed bytes "
-                "(must be exactly raw)")
-    if not s1["batches_raw_fallback"] - s0["batches_raw_fallback"]:
-        return "compression-on binary corpus booked no raw-fallback batches"
-    return None
-
-
 def _smoke_license_device() -> str | None:
     """Raw-bytes license scoring gates. (1) Zero-cost-when-off: a
     cpu-backend classifier must never build the device scorer, upload
@@ -2949,10 +2868,6 @@ def smoke(trace_out=None, metrics_out=None) -> int:
     if adm_err:
         print(f"FATAL: {adm_err}", file=sys.stderr)
         return 1
-    cmp_err = _smoke_compress()
-    if cmp_err:
-        print(f"FATAL: {cmp_err}", file=sys.stderr)
-        return 1
     lic_err = _smoke_license_device()
     if lic_err:
         print(f"FATAL: {lic_err}", file=sys.stderr)
@@ -3009,7 +2924,6 @@ def smoke(trace_out=None, metrics_out=None) -> int:
                 "sampler_overhead_pct": round(overhead_pct, 2),
                 "tuning_controller": "ok",  # schema + zero-cost gates held
                 "admission_off": "ok",  # zero-cost-when-off gate held
-                "compress": "ok",  # off = zero-cost, on = beats raw
                 "license_device": "ok",  # off = zero-cost, on = scores
                 "cve_resident": "ok",  # second scan = zero upload, 1 disp
 
@@ -3142,7 +3056,6 @@ LOWER_IS_BETTER = {
     "device_bytes_uploaded_per_scanned_byte",
     "license_link_bytes_per_text_byte",
     "saturation_p95_ms",
-    "wire_compression_ratio",
     # share of fleet worker capacity spent idle or dead (the efficiency
     # verdict's non-busy, non-coordinator-stalled buckets): rising idle
     # means the coordinator is feeding replicas worse
@@ -3197,7 +3110,7 @@ def _metric_values(doc: dict) -> dict:
     # regression, not an excuse to skip the check (zero PREVIOUS values are
     # excused by check_regression's pv <= 0 guard)
     for key in ("link_mbs_p50", "link_mbs_p95", "device_busy_ratio",
-                "wire_compression_ratio", "compile_count"):
+                "compile_count"):
         v = (doc.get("detail") or {}).get(key)
         if isinstance(v, (int, float)):
             out[key] = float(v)
@@ -3251,8 +3164,8 @@ def _metric_values(doc: dict) -> dict:
                 out["cve_vs_cpu_baseline"] = float(ratio)
     # the link-byte cost joins the guarded set UNCONDITIONALLY: when the
     # fused side bench errored (or a round predates it), fall back to the
-    # headline rep's own link cost instead of silently dropping the one
-    # metric the compressed wire format exists to move
+    # headline rep's own link cost instead of silently dropping the
+    # secret feed's link-cost metric
     if "device_bytes_uploaded_per_scanned_byte" not in out:
         v = (doc.get("detail") or {}).get("link_bytes_per_corpus_byte")
         if isinstance(v, (int, float)):
@@ -3490,13 +3403,6 @@ def main():
                 "link_bytes_per_corpus_byte"
             ],
             "dedup_hit_rate": best["dedup_hit_rate"],
-            # best rep's compressed-wire ratio (absent when the codec is
-            # off for this topology); _metric_values guards it downward
-            **(
-                {"wire_compression_ratio": best["wire_compression_ratio"]}
-                if "wire_compression_ratio" in best
-                else {}
-            ),
             "e2e_spread": spread,
             "e2e_reps": e2e_reps,
             "e2e_traced_rep": traced,

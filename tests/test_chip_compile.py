@@ -113,21 +113,6 @@ def test_prefilter_kernel(compile_for):
     assert "ENTRY" in compile_for(fn, *shapes)
 
 
-def test_decompress_stage(compile_for):
-    from trivy_tpu.ops.decompress import build_decompress_fn
-    from trivy_tpu.secret.compress import COMPRESS_MIN_RATIO, SlabCodec
-
-    codec = SlabCodec(CHUNK)
-    fn = build_decompress_fn(CHUNK, codec.tab_bytes, codec.tab_len)
-    # the top wire rung of the full bucket, as TpuSecretScanner sizes it
-    wire = -(-int(ROWS * CHUNK * COMPRESS_MIN_RATIO) // 128) * 128
-    hlo = compile_for(
-        fn, ((wire,), "uint8"), ((ROWS,), "int32"), ((ROWS,), "int32"),
-        ((ROWS,), "uint8"),
-    )
-    assert "ENTRY" in hlo
-
-
 def test_license_bytes_scorer(compile_for):
     from trivy_tpu.licensing.classify import LicenseClassifier
     from trivy_tpu.ops import ngram_score as ng

@@ -4,8 +4,10 @@ Covers the pipeline rebuild of ``TpuSecretScanner.scan_files``: arena-slab
 reuse with findings parity (packing + dedup on), in-order emission under a
 deliberately slow reader, fault injection with the async in-flight window
 live, the empty/partial-final-slab guard (padding rows must not leak into
-dedup keys or retain arena slabs), and the walk→device streaming handoff
-(:class:`trivy_tpu.secret.feed.FileStream`).
+dedup keys or retain arena slabs), the walk→device streaming handoff
+(:class:`trivy_tpu.secret.feed.FileStream`), and the raw-slab upload
+accounting (``secret.bytes_uploaded``) across dispatch, packing, the fused
+license gate and the dedup stores.
 
 Scanners here run a RESTRICTED ruleset (two builtin rules) to keep device
 compiles cheap — full-ruleset feed parity is already exercised by
@@ -15,11 +17,14 @@ test_tpu_scanner.py through the same pipeline.
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
 from tests.secret_samples import SAMPLES
-from trivy_tpu import faults
+from trivy_tpu import faults, obs
+from trivy_tpu.cache import new_cache
+from trivy_tpu.licensing.fused import FusedLicenseGate
 from trivy_tpu.secret.engine import ScannerConfig, SecretScanner
 from trivy_tpu.secret.feed import ChunkArena, FileStream
 from trivy_tpu.secret.tpu_scanner import TpuSecretScanner
@@ -58,6 +63,38 @@ def corpus(n_big=12, n_small=6):
         files.append((f"small_{i}.h", f"// header {i}\n".encode() * 20))
     files.append(("tok.h", f"a\n{SAMPLES['slack-access-token']}\nb\n".encode()))
     return files
+
+
+def mixed_corpus(n=24, seed=7):
+    """Run-heavy printable text, random printable noise, zero pages and
+    binary blobs — with secrets sprinkled in."""
+    rng = np.random.default_rng(seed)
+    files = []
+    for i in range(n):
+        kind = i % 4
+        if kind == 0:  # run-heavy printable text with a secret
+            rid = ("github-pat", "slack-access-token")[i % 2]
+            body = (
+                b"#" * 120 + b"\n"
+                + SAMPLES[rid].encode() + b"\n"
+                + b"the rate that the land sent was on and in their line\n" * 60
+            )
+        elif kind == 1:  # random printable noise
+            body = rng.integers(0x20, 0x7F, size=5000, dtype=np.uint8).tobytes()
+        elif kind == 2:  # zero page + trailing text
+            body = b"\x00" * 4096 + b"tail text after the hole\n"
+        else:  # binary (top-bit set)
+            body = rng.integers(0, 256, size=3000, dtype=np.uint8).tobytes()
+            body = body.replace(b"\x00", b"\x01")
+        files.append((f"f{i}.dat", body))
+    return files
+
+
+LICENSE_TEXT = (
+    b"Permission is hereby granted, free of charge, to any person "
+    b"obtaining a copy of this software and associated documentation "
+    b"files, to deal in the Software without restriction.\n" * 4
+)
 
 
 def assert_parity(cpu, scanner, files):
@@ -373,3 +410,184 @@ def test_feed_knobs_resolve(cfg, monkeypatch):
     monkeypatch.setenv("TRIVY_TPU_FEED_INFLIGHT", "7")
     s = TpuSecretScanner(cfg, chunk_len=1024, batch_size=4)
     assert s.feed_streams == 6 and s.inflight == 7
+
+
+def test_scanner_rejects_bad_knobs(cfg):
+    with pytest.raises(ValueError):
+        TpuSecretScanner(cfg, chunk_len=1024, dispatch="sideways")
+    # the widest device window needs chunk_len >= 2 * overlap
+    with pytest.raises(ValueError):
+        TpuSecretScanner(cfg, chunk_len=100)
+    # raw slabs are the only wire format: there is no codec to select
+    with pytest.raises(TypeError):
+        TpuSecretScanner(cfg, chunk_len=1024, compress="on")
+
+
+# -- raw-slab feed: dispatch, dedup and upload accounting --------------------
+
+
+def raw_scanner(cfg, dispatch="single", **kw):
+    kw.setdefault("chunk_len", 1024)
+    kw.setdefault("batch_size", 8)
+    if dispatch == "round_robin":
+        kw["devices"] = jax.devices()[:4]
+    return TpuSecretScanner(cfg, dispatch=dispatch, **kw)
+
+
+def test_dedup_keys_are_dispatch_invariant(cfg, cpu):
+    """A hit cache warmed by a single-device scan serves a round-robin
+    scan whole (and the converse): row keys hash raw row content under
+    the ruleset fingerprint, never the dispatch path."""
+    files = mixed_corpus(8)
+    for first, second in (("single", "round_robin"),
+                          ("round_robin", "single")):
+        shared = new_cache("memory")
+        a = raw_scanner(cfg, first, hit_cache=shared)
+        b = raw_scanner(cfg, second, hit_cache=shared)
+        assert a.ruleset_fingerprint == b.ruleset_fingerprint
+        assert_parity(cpu, a, files)
+        before = b.stats.snapshot()
+        assert_parity(cpu, b, files)
+        d = {k: v - before[k] for k, v in b.stats.snapshot().items()}
+        assert d["chunks_uploaded"] == 0 and d["chunks_dedup_hit"] > 0
+        assert d["bytes_uploaded"] == 0
+
+
+def test_warm_rescan_uploads_nothing(cfg, cpu):
+    t = raw_scanner(cfg, chunk_len=2048)
+    files = mixed_corpus(8)
+    list(t.scan_files(files))
+    before = t.stats.snapshot()
+    assert_parity(cpu, t, files)
+    d = {k: v - before[k] for k, v in t.stats.snapshot().items()}
+    assert d["bytes_uploaded"] == 0 and d["chunks_uploaded"] == 0
+
+
+def test_round_robin_multi_stream_parity(cfg, cpu):
+    t = raw_scanner(cfg, "round_robin", dedup=False)
+    assert t._match.n_streams == 4 and t.feed_streams == 4
+    assert_parity(cpu, t, mixed_corpus(16, seed=3))
+    s = t.stats.snapshot()
+    assert s["chunks_uploaded"] == s["chunks"] > 0
+    assert s["bytes_uploaded"] >= s["chunks_uploaded"] * t.chunk_len
+
+
+def test_pcie_auto_round_robin_ships_raw_with_license_gate(
+    cfg, cpu, monkeypatch
+):
+    """A PCIe link with four devices: raw slabs round-robin with the
+    fused license gate on. Findings match the CPU engine, every row
+    (all-zero rows included) crosses the link exactly once, and the gate
+    verdicts match a single-device scan's."""
+    monkeypatch.setenv("TRIVY_TPU_LINK_CLASS", "pcie")
+    # odd files become C sources, which --license-full sends to the gate
+    files = [
+        (p[:-4] + ".c" if i % 2 else p, d)
+        for i, (p, d) in enumerate(mixed_corpus(16, seed=3))
+    ]
+    files.append(("zeros.bin", b"\x00" * 5000))
+    files.append(("LICENSE", LICENSE_TEXT))
+
+    def scan(dispatch):
+        t = raw_scanner(cfg, dispatch, dedup=False)
+        assert t.tuning.topology.endswith(":pcie")
+        gate = FusedLicenseGate(license_full=True)
+        got = list(t.scan_files(iter(files), license_gate=gate))
+        assert len(got) == len(files)
+        for (path, data), secret in zip(files, got):
+            assert secret.to_dict() == cpu.scan_bytes(path, data).to_dict(), (
+                path
+            )
+        return t, gate
+
+    rr, rr_gate = scan("round_robin")
+    assert rr._match.n_streams == 4
+    s = rr.stats.snapshot()
+    assert s["chunks_uploaded"] == s["chunks"]
+    assert 0 < s["license_rows_gated"] <= s["chunks_uploaded"]
+    one, one_gate = scan("single")
+    assert one.stats.snapshot()["chunks_uploaded"] == s["chunks_uploaded"]
+    verdicts = {p: rr_gate.should_classify(p) for p, _ in files}
+    assert verdicts == {p: one_gate.should_classify(p) for p, _ in files}
+    assert verdicts["LICENSE"] and not all(verdicts.values())
+
+
+def test_mid_scan_degraded_fallback_parity(cfg, cpu):
+    """All devices die mid-stream: the scan finishes on the exact host
+    engine, in order, byte-identical."""
+    t = raw_scanner(cfg, batch_size=4)
+    faults.configure("device.dispatch:at=3:times=-1")
+    files = mixed_corpus(20, seed=13)
+    got = list(t.scan_files(iter(files)))
+    assert len(got) == len(files)
+    for (path, data), secret in zip(files, got):
+        assert secret.to_dict() == cpu.scan_bytes(path, data).to_dict(), path
+    assert t.stats.snapshot()["degraded"] >= 1
+
+
+def raw_feed_corpus():
+    """Mixed big files, copied content (dedup material), small
+    packable files and a license header, secrets sprinkled in."""
+    files = mixed_corpus(8, seed=21)
+    files += [(f"lib/copy/{p}", d) for p, d in files[:3]]
+    files += [
+        (f"src/h_{i}.h", f"// header {i}\n".encode() * 12) for i in range(6)
+    ]
+    files.append(("src/tok.h", f"a\n{SAMPLES['github-pat']}\nb\n".encode()))
+    files.append(("LICENSE", LICENSE_TEXT))
+    return files
+
+
+def scan_accounted(cpu, t, files, license_gate):
+    """Scan under an enabled trace context; returns the ScanStats delta
+    and the context's ``secret.bytes_uploaded`` counter."""
+    gate = FusedLicenseGate(license_full=True) if license_gate else None
+    before = t.stats.snapshot()
+    with obs.scan_context(name="raw-feed", enabled=True) as ctx:
+        got = list(t.scan_files(iter(files), license_gate=gate))
+        booked = ctx.counters.get("secret.bytes_uploaded", 0)
+    assert len(got) == len(files)
+    for (path, data), secret in zip(files, got):
+        assert secret.to_dict() == cpu.scan_bytes(path, data).to_dict(), path
+    d = {k: v - before[k] for k, v in t.stats.snapshot().items()}
+    return d, booked
+
+
+@pytest.mark.parametrize("store", ["lru", "persisted"])
+@pytest.mark.parametrize("license_gate", [False, True],
+                         ids=["no_license", "license_gate"])
+@pytest.mark.parametrize("pack_small", [True, False],
+                         ids=["pack", "no_pack"])
+@pytest.mark.parametrize("dispatch", ["single", "round_robin"])
+def test_raw_feed_upload_accounting(cfg, cpu, dispatch, pack_small,
+                                    license_gate, store):
+    """The counter ``secret.upload_share`` reads: every raw batch books
+    its padded slab (``n * chunk_len``) to the scan context and to
+    ScanStats alike, the booked bytes cover every corpus byte dedup did
+    not resolve, and a warm re-scan — from the same scanner's LRU or a
+    fresh scanner's persisted store — books nothing."""
+    kw = {"pack_small": pack_small}
+    if store == "persisted":
+        kw["hit_cache"] = new_cache("memory")
+    t = raw_scanner(cfg, dispatch, **kw)
+    files = raw_feed_corpus()
+    d, booked = scan_accounted(cpu, t, files, license_gate)
+    assert booked == d["bytes_uploaded"] > 0
+    assert booked % t.chunk_len == 0
+    assert booked >= d["chunks_uploaded"] * t.chunk_len
+    assert d["bytes_in"] == sum(len(b) for _, b in files)
+    assert d["bytes_in"] - d["bytes_dedup_hit"] <= booked
+    assert d["chunks_dedup_hit"] > 0  # the copies under lib/
+    assert (d["rows_packed"] > 0) == pack_small
+    if license_gate:
+        assert 0 < d["license_rows_gated"] <= d["chunks_uploaded"]
+    else:
+        assert d["license_rows_gated"] == 0
+
+    if store == "persisted":
+        t = raw_scanner(cfg, dispatch, **kw)  # same backend, cold LRU
+    d, booked = scan_accounted(cpu, t, files, license_gate)
+    assert booked == d["bytes_uploaded"] == 0
+    assert d["chunks_uploaded"] == 0
+    if store == "persisted":
+        assert d["chunks_warm_hit"] > 0

@@ -161,44 +161,40 @@ def test_sharded_license_corpus_device_resident():
 
 
 def test_sharded_license_scores_match_unsharded_kernel():
-    """Kernel-level: m=2 shard tables reassemble to the m=1 scores."""
+    """Kernel-level: a model=2 raw-bytes scorer (shard tables over the
+    mesh 'model' axis, rows over 'data') reassembles to the single-device
+    scorer's gate and scores on the same text rows."""
     from trivy_tpu.licensing.classify import LicenseClassifier
     from trivy_tpu.ops import ngram_score as ng
 
     clf = LicenseClassifier(backend="device")
-    clf._build_scoring()
-
-    def build(m):
-        return ng.build_corpus_table(
-            clf.licenses, clf._full_keys, clf._full_weights,
-            clf._phrase_keys, clf._phrase_short, model_shards=m,
-        )
-
-    whashes, word_text, keys, gt = clf._batch_hashes(_license_texts())
-    groups, overflow = ng.pack_gram_rows(ng.fold32(keys), gt, 200)
-    assert not overflow
-    single = ng.DeviceScorer(build(1))
-    mesh = get_mesh(8, model=2)
-    sharded = ng.DeviceScorer(build(2), mesh=mesh)
+    single = ng.DeviceBytesScorer(clf.corpus_table(1))
+    sharded = ng.DeviceBytesScorer(
+        clf.corpus_table(2), mesh=get_mesh(8, model=2)
+    )
+    assert sharded.table.n_shards == 2
     L = single.table.n_licenses
     dp = sharded.data_parallelism
+    encoded = [t.encode("latin-1", "replace") for t in _license_texts()]
+    groups, _wide = ng.pack_text_rows(encoded)
     any_hit = False
-    for rows, _tis in groups:
+    for width, (rows, _tis) in sorted(groups.items()):
         rows = rows[:16]
         pad = (-len(rows)) % dp
         if pad:
             rows = np.concatenate(
-                [rows, np.full((pad, rows.shape[1]), ng.PAD_KEY, np.int32)]
+                [rows, np.zeros((pad, rows.shape[1]), np.uint8)]
             )
-        fw1, pp1 = (np.asarray(x)[:, :L] for x in single(rows))
-        fw2, pp2 = (np.asarray(x)[:, :L] for x in sharded(rows))
-        np.testing.assert_allclose(fw1, fw2, rtol=1e-6)
-        np.testing.assert_array_equal(pp1, pp2)
-        g1 = np.asarray(single.gate(rows))
-        g2 = np.asarray(sharded.gate(rows))
-        # counts are per-shard sums (a gram in both slabs counts twice
-        # under m=2); only the >0 candidacy boolean is load-bearing
-        np.testing.assert_array_equal(g1 > 0, g2 > 0)
-        assert (g2 >= g1).all()
-        any_hit |= bool((g1 > 0).any())
+        dev1, dev2 = single.put_rows(rows), sharded.put_rows(rows)
+        for a, b in zip(single.gate_bytes(dev1, width),
+                        sharded.gate_bytes(dev2, width)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        fw1, pp1, nu1 = (np.asarray(x) for x in
+                         single.score_from_bytes(dev1, width))
+        fw2, pp2, nu2 = (np.asarray(x) for x in
+                         sharded.score_from_bytes(dev2, width))
+        np.testing.assert_allclose(fw1[:, :L], fw2[:, :L], rtol=1e-6)
+        np.testing.assert_array_equal(pp1[:, :L], pp2[:, :L])
+        np.testing.assert_array_equal(nu1, nu2)
+        any_hit |= bool((pp1[:, :L] > 0).any())
     assert any_hit  # license texts intersect their own corpus

@@ -16,6 +16,7 @@ from trivy_tpu import tuning
 from trivy_tpu.tuning import (
     DECISION_FIELDS,
     DECISION_GAUGES,
+    DEFAULT_FLEET_SPLIT_THRESHOLD,
     TuningConfig,
     TuningController,
     resolve_tuning,
@@ -674,3 +675,37 @@ class TestScannerIntegration:
         )
         assert sc2 is not sc
         assert sc2.feed_streams == 1
+
+
+def test_tuning_resolution_precedence():
+    """Feed and fleet knobs resolve CLI > env > default with provenance
+    (an explicit 0 is a mode for the split threshold), and the feed has
+    no wire-format mode left to resolve."""
+    cfg = resolve_tuning(opts={}, env={}, autotune_path="")
+    assert cfg.fleet_split_threshold == DEFAULT_FLEET_SPLIT_THRESHOLD
+    assert cfg.source["dedup_store_mb"] == "default"
+    cfg = resolve_tuning(
+        opts={},
+        env={"TRIVY_TPU_FLEET_SPLIT_THRESHOLD": "2.5",
+             "TRIVY_TPU_DEDUP_STORE_MB": "8"},
+        autotune_path="",
+    )
+    assert cfg.fleet_split_threshold == 2.5
+    assert cfg.dedup_store_mb == 8 and cfg.source["dedup_store_mb"] == "env"
+    cfg = resolve_tuning(
+        opts={"fleet_split_threshold": 0, "secret_dedup_mb": 4},
+        env={"TRIVY_TPU_FLEET_SPLIT_THRESHOLD": "2.5",
+             "TRIVY_TPU_DEDUP_STORE_MB": "8"},
+        autotune_path="",
+    )
+    assert cfg.fleet_split_threshold == 0  # an explicit 0 is a mode
+    assert cfg.dedup_store_mb == 4 and cfg.source["dedup_store_mb"] == "cli"
+    for bad in ("-1", "nan", "inf"):
+        with pytest.raises(ValueError):
+            resolve_tuning(
+                opts={}, env={"TRIVY_TPU_FLEET_SPLIT_THRESHOLD": bad},
+                autotune_path="",
+            )
+    # raw slabs are the only wire format: no codec mode to resolve
+    assert not any("compress" in k for k in cfg.to_dict())
+    assert not any("compress" in k for k in cfg.source)

@@ -221,24 +221,6 @@ def secret_flags() -> FlagGroup:
                       "LRU (0 = default 32; env TRIVY_TPU_DEDUP_STORE_MB; "
                       "the bound is bytes, not entries, so streaming scans "
                       "keep flat RSS)"),
-            Flag("secret-compress", default=None,
-                 config_name="secret.compress",
-                 help="compressed slab wire format on the device feed: "
-                      "auto (off on the host and pcie link classes and "
-                      "under a mesh, on for a slower declared link "
-                      "class), on, off "
-                      "(env TRIVY_TPU_SECRET_COMPRESS)"),
-            Flag("no-secret-compress", default=False, value_type=bool,
-                 config_name="secret.no-compress",
-                 help="ship raw slabs unconditionally (shorthand for "
-                      "--secret-compress off)"),
-            Flag("secret-compress-min-ratio", default=None,
-                 value_type=float,
-                 config_name="secret.compress-min-ratio",
-                 help="per-batch wire budget as a fraction of the raw "
-                      "slab: a batch that can't compress below this ships "
-                      "raw (default 0.875, the 7-bit-packing line; env "
-                      "TRIVY_TPU_SECRET_COMPRESS_MIN_RATIO)"),
         ],
     )
 

@@ -48,13 +48,6 @@ def _resolve_tuning(opts):
         "parallel": opts.get("parallel"),
         "fleet_inflight": opts.get("fleet_inflight"),
         "secret_dedup_mb": opts.get("secret_dedup_mb"),
-        # --no-secret-compress is the loud opt-out shorthand; an explicit
-        # --secret-compress value wins over the bool's default-False
-        "secret_compress": (
-            "off" if opts.get("no_secret_compress")
-            else opts.get("secret_compress")
-        ),
-        "secret_compress_min_ratio": opts.get("secret_compress_min_ratio"),
         "tuning_file": opts.get("tuning_file"),
         # the store_true default (False) must not shadow the env layer:
         # only an EXPLICIT --tune is a CLI-level decision

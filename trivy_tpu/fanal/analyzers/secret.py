@@ -81,8 +81,7 @@ def _shared_scanner(
         tuning_key = (
             tuning.feed_streams, tuning.inflight, tuning.arena_slabs,
             tuning.bucket_rungs, tuning.controller, tuning.tuning_interval,
-            tuning.dedup_store_mb, tuning.compress,
-            tuning.compress_min_ratio,
+            tuning.dedup_store_mb,
         )
     key = (
         (config.source_digest or id(config)) if config is not None else None,

@@ -277,11 +277,6 @@ class TraceContext:
         # controller is on) — tuning_doc() merges both for export
         self.tuning: dict | None = None
         self.tuning_controller = None
-        # compressed-feed wire accounting (trivy_tpu/secret/compress.py):
-        # run-level compression ratio + byte counters, set by the scan run
-        # on close when the codec is active; None on uncompressed scans so
-        # exports show no empty block
-        self.wire: dict | None = None
         # fleet telemetry (trivy_tpu/fleet/telemetry.py): the per-replica
         # health doc attached at poller stop, plus the coordinator's
         # heartbeat-status and --live-fragment callables — all None on
@@ -529,7 +524,6 @@ class TraceContext:
             self.timeseries = None
             self.tuning = None
             self.tuning_controller = None
-            self.wire = None
             if getattr(self, "_flight_ring", None) is not None:
                 self._flight_ring = None
 

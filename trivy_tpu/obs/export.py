@@ -129,10 +129,6 @@ def context_doc(ctx: TraceContext, max_events: int = WIRE_MAX_EVENTS) -> dict:
         # resolved knobs + the controller decision log: a client-mode scan
         # can replay the SERVER's mid-scan adaptations from its own export
         doc["tuning"] = tuning
-    wire = getattr(ctx, "wire", None)
-    if wire is not None:
-        # the server's compressed-feed wire accounting rides its response
-        doc["wire"] = wire
     return doc
 
 
@@ -382,12 +378,6 @@ def metrics_dict(ctx: TraceContext) -> dict:
         # bench reps embedding this dict) see WHAT the scan ran with and
         # every mid-scan change the controller made
         doc["tuning"] = tuning
-    wire = getattr(ctx, "wire", None)
-    if wire is not None:
-        # compressed-feed wire accounting: run-level compression ratio plus
-        # the gate/fallback byte counters behind it — only present when the
-        # codec actually ran, so compression-off exports stay byte-identical
-        doc["wire"] = wire
     dev = _recorder.device_doc()
     if dev is not None:
         # device-lane accounting (flight recorder): compile ledger per

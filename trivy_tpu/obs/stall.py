@@ -31,8 +31,6 @@ BUCKETS = {
     # a warm re-scan's time goes here instead of upload/device buckets
     "feed_wait": "feed-starved",
     "dispatch": "upload-bound",
-    "compress": "codec-bound",  # host-side slab encode (compressed feed)
-    "decompress": "codec-bound",  # wire-frame placement + decode launch
     "device_wait": "device-bound",
     "confirm": "confirm-bound",
     "finalize": "confirm-bound",
@@ -47,7 +45,6 @@ ORDER = [
     "warm-hit",
     "feed-starved",
     "upload-bound",
-    "codec-bound",
     "device-bound",
     "confirm-bound",
     "parse-bound",

@@ -4,33 +4,32 @@ shards on the mesh 'model' axis).
 
 The classifier's two gram lanes (full-text distinctiveness weights +
 pooled fingerprint-phrase grams, see ``licensing/classify.py``) compile
-into one table per corpus shard: a sorted int32 key column and a dense
-per-key *credit matrix* ``[Ku, 2*Ls]`` holding each key's full-lane
-weight and phrase-lane credit for every license in the shard's slab.
-Texts are tokenized and hashed host-side into sorted int32 gram rows;
-the device kernel intersects each row with the key column (vmap'd
-binary search) and reduces the hit rows of the credit matrix — a pure
-gather + weighted-sum (embedding-lookup shape, no scatter anywhere),
-returning per-(text, license) full-lane matched weight and phrase-lane
-gram hit counts.
+into one table per corpus shard (:class:`CorpusTable32`): a sorted uint32
+key column and a dense per-key *credit matrix* ``[Ku, 2*Ls]`` holding
+each key's full-lane weight and phrase-lane credit for every license in
+the shard's slab. :class:`DeviceBytesScorer` ships zero-padded uint8 text
+rows; the device lowers, tokenizes and hashes them, gates them against
+two shingle blooms, intersects each row's sorted unique grams with the
+key column (binary search) and reduces the hit rows of the credit matrix
+— a pure gather + weighted-sum (embedding-lookup shape, no scatter
+anywhere), returning per-(text, license) full-lane matched weight and
+phrase-lane gram hit counts.
 
 Sharding: rows shard over the mesh 'data' axis, the corpus table over
 'model' (each model shard owns a contiguous slab of the license axis and
 only that slab's gram keys), via :func:`trivy_tpu.parallel.mesh.
-sharded_score_fn`. The table is uploaded once per (corpus, mesh) and
-stays HBM-resident across scans — the ``check_ops_gather`` layout
-(advisory bounds resident, host ships indices): per scan only the int32
-gram rows cross the link.
+sharded_bytes_score_fn`. The table is uploaded once per (corpus, mesh)
+and stays HBM-resident across scans: per scan only the text rows cross
+the link.
 
-Soundness of the int32 fold: corpus and text keys fold from the same
-int64 hashes, so every true int64 match survives the fold, and credit
-tables count fold multiplicity — collisions can only *add* matched
-weight or phrase credit (never remove it). Device-gated candidate sets
-are therefore supersets of the host scorer's and thresholding on device
+Soundness of the 32-bit key image: corpus and text keys reduce from the
+same int64 hashes, so every true int64 match survives, and credit tables
+count fold multiplicity — collisions can only *add* matched weight or
+phrase credit (never remove it). Device-gated candidate sets are
+therefore supersets of the host scorer's and thresholding on device
 scores never drops a passing license; the reported confidence itself can
-exceed the host oracle's only on a fold collision (~T*Ku/2^32 expected
-per text, i.e. <0.06 even for the largest row against the full corpus),
-and never undershoots it.
+exceed the host oracle's only on a collision (~T*Ku/2^32 expected per
+text), and never undershoots it.
 """
 
 from __future__ import annotations
@@ -40,8 +39,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-# padding sentinel for both text rows and corpus key slots; pads sort last
-# and a pad-pad "hit" gathers the all-zero pad credit row (a no-op)
+# int32 gram-row padding sentinel (fold32 / pack_gram_rows); pads sort last
 PAD_KEY = np.int32(np.iinfo(np.int32).max)
 
 # raw-bytes path sentinel (uint32 hash domain): real keys clamp to
@@ -231,103 +229,17 @@ def build_shingle_gate(
 
 
 @dataclass
-class CorpusTable:
+class CorpusTable32:
     """Host-side corpus fingerprint table, pre-split into model shards.
 
     Arrays carry a leading shard axis ``m`` so the same buffers serve the
     single-device path (m=1) and the sharded path (axis sharded over
     'model'). The credit matrix is license-local per shard; concatenating
     per-shard score blocks along the license axis restores global order.
-    """
-
-    keys: np.ndarray  # [m, Ku] int32, sorted per shard, PAD_KEY padded
-    credit: np.ndarray  # [m, Ku, 2*Ls] f32: [:Ls] full weight, [Ls:] phrase
-    n_shards: int
-    lic_per_shard: int  # Ls; padded global license axis = m * Ls
-    n_licenses: int  # real license count (<= m * Ls)
-    # per-license finalization constants (host side, float64 like the oracle)
-    wtot: np.ndarray = field(default=None)  # [L] full-lane weight totals
-    n_units: np.ndarray = field(default=None)  # [L] phrase-lane unit counts
-    n_short: np.ndarray = field(default=None)  # [L] short phrases per license
-
-    @property
-    def padded_licenses(self) -> int:
-        return self.n_shards * self.lic_per_shard
-
-
-def build_corpus_table(
-    licenses: list[str],
-    full_keys: dict[str, np.ndarray],
-    full_weights: dict[str, np.ndarray],
-    phrase_keys: dict[str, np.ndarray],
-    phrase_short: dict[str, list[str]],
-    model_shards: int = 1,
-) -> CorpusTable:
-    """Compile the classifier's scoring tables into the flat device table.
-
-    Inputs are the host scorer's own structures (int64 gram keys +
-    distinctiveness weights per license), so device scores agree with the
-    host oracle by construction, modulo the sound int32 fold.
-    """
-    m = max(1, int(model_shards))
-    L = len(licenses)
-    Ls = -(-L // m)  # ceil: licenses per shard, last shard zero-padded
-    # per shard: folded key -> {local license: [full_w, phrase_credit]}
-    shard_pairs: list[dict[int, dict[int, list[float]]]] = [
-        {} for _ in range(m)
-    ]
-    for li, lic in enumerate(licenses):
-        shard, local = divmod(li, Ls)
-        tbl = shard_pairs[shard]
-        fk = full_keys.get(lic)
-        if fk is not None and len(fk):
-            w = full_weights[lic]
-            for k, kw in zip(fold32(fk).tolist(), w.tolist()):
-                ent = tbl.setdefault(k, {}).setdefault(local, [0.0, 0.0])
-                ent[0] += kw
-        pk = phrase_keys.get(lic)
-        if pk is not None and len(pk):
-            # pk is unique in int64 space; credit each folded key with the
-            # COUNT of distinct int64 grams mapping to it, so an intra-
-            # license fold collision overcounts (sound: the gate and the
-            # phrase confidence may only ever exceed the host oracle,
-            # never undershoot it)
-            for k in fold32(np.unique(pk)).tolist():
-                ent = tbl.setdefault(k, {}).setdefault(local, [0.0, 0.0])
-                ent[1] += 1.0
-    Ku = max(1, max(len(t) for t in shard_pairs))
-    keys = np.full((m, Ku), PAD_KEY, dtype=np.int32)
-    credit = np.zeros((m, Ku, 2 * Ls), dtype=np.float32)
-    for s, tbl in enumerate(shard_pairs):
-        for ki, k in enumerate(sorted(tbl)):
-            keys[s, ki] = k
-            for local, (w, p) in tbl[k].items():
-                credit[s, ki, local] = w
-                credit[s, ki, Ls + local] = p
-    wtot = np.zeros(L, dtype=np.float64)
-    n_units = np.zeros(L, dtype=np.int64)
-    n_short = np.zeros(L, dtype=np.int64)
-    for li, lic in enumerate(licenses):
-        w = full_weights.get(lic)
-        wtot[li] = float(w.sum()) if w is not None and len(w) else 0.0
-        pk = phrase_keys.get(lic)
-        shorts = phrase_short.get(lic, [])
-        n_short[li] = len(shorts)
-        n_units[li] = (len(pk) if pk is not None else 0) + len(shorts)
-    return CorpusTable(
-        keys=keys, credit=credit,
-        n_shards=m, lic_per_shard=Ls, n_licenses=L,
-        wtot=wtot, n_units=n_units, n_short=n_short,
-    )
-
-
-@dataclass
-class CorpusTable32:
-    """Raw-bytes-path corpus table: the same per-shard credit layout as
-    :class:`CorpusTable` but keyed in the uint32 low-32 hash domain the
-    device computes natively from arena bytes (ops/gram_gate.py's ring-
-    homomorphism trick), plus the classifier constants the kernels need
-    (LUT + mix constants) and the shingle-bloom gate bitmask."""
+    Keys live in the uint32 low-32 hash domain the device computes
+    natively from arena bytes (ops/gram_gate.py's ring-homomorphism
+    trick); the table also carries the classifier constants the kernels
+    need (LUT + mix constants) and the shingle-bloom gate bitmask."""
 
     keys: np.ndarray  # [m, Ku] uint32, sorted per shard, SENT32 padded
     credit: np.ndarray  # [m, Ku, 2*Ls] f32: [:Ls] full weight, [Ls:] phrase
@@ -366,11 +278,17 @@ def build_corpus_table32(
 ) -> CorpusTable32:
     """Compile the classifier's scoring tables for the raw-bytes kernel.
 
-    Identical credit accumulation to :func:`build_corpus_table`, but keys
-    fold with :func:`fold_u32` (the image the device reproduces from raw
-    bytes) instead of the xor-fold — which a byte-level kernel cannot
-    compute. Dedup note: the device dedups text grams in the FOLDED
-    domain while the host dedups in int64 first, so two distinct int64
+    Inputs are the host scorer's own structures (int64 gram keys +
+    distinctiveness weights per license), so device scores agree with the
+    host oracle by construction, modulo the sound 32-bit image. Keys fold
+    with :func:`fold_u32` (the image the device reproduces from raw
+    bytes); a phrase key is credited with the COUNT of distinct int64
+    grams mapping to it, so an intra-license collision overcounts (sound:
+    the gate and the phrase confidence may only ever exceed the host
+    oracle, never undershoot it).
+
+    Dedup note: the device dedups text grams in the FOLDED domain while
+    the host dedups in int64 first, so two distinct int64
     grams of one text colliding in their low 32 bits score once on
     device and twice on host (~T^2/2^33 per text); the classifier's EPS
     confirm band absorbs it like every other device/host rounding gap.
@@ -422,166 +340,6 @@ def build_corpus_table32(
         n_shards=m, lic_per_shard=Ls, n_licenses=L,
         wtot=wtot, n_units=n_units, n_short=n_short,
     )
-
-
-def build_gate_fn(psum_axis: str | None = None):
-    """Cheap candidate gate: (rows [B, T], keys [.., Ku]) -> per-row
-    corpus-intersection counts [B] int32 — the binary search without the
-    credit gather. ~99% of scanned files share no gram with any license
-    text, so the expensive scoring gather (build_score_fn) only runs on
-    rows this gate flags. Under shard_map, pass the mesh axis to psum
-    the per-shard counts into global counts (a gram owned by several
-    shards' slabs then counts once per shard — only the >0 candidacy
-    boolean is load-bearing, and it is exact)."""
-    import jax
-    import jax.numpy as jnp
-
-    def gate(rows, keys):
-        keys = keys.reshape(-1)
-        Ku = keys.shape[0]
-
-        def one(tg):
-            idx = jnp.minimum(jnp.searchsorted(keys, tg), Ku - 1)
-            return jnp.sum(
-                ((keys[idx] == tg) & (tg != PAD_KEY)).astype(jnp.int32)
-            )
-
-        counts = jax.vmap(one)(rows)
-        if psum_axis is not None:
-            counts = jax.lax.psum(counts, axis_name=psum_axis)
-        return counts
-
-    return gate
-
-
-def build_score_fn(lic_per_shard: int):
-    """Pure scoring function for one corpus shard, suitable for jit,
-    vmap and shard_map: (rows [B, T], keys [.., Ku], credit [.., Ku,
-    2*Ls]) -> (full_w [B, Ls] f32, phrase_hits [B, Ls] f32).
-
-    Rows are sorted-ascending int32 gram keys padded with PAD_KEY. The
-    membership test is a binary search of each text gram in the shard's
-    sorted key column (O(T log Ku), the cheap direction: texts carry far
-    fewer unique grams than the corpus); the license-axis reduction is a
-    gather of the hit credit rows + a weighted sum — no scatter, the
-    embedding-lookup shape accelerators are built for.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    Ls = int(lic_per_shard)
-
-    def score(rows, keys, credit):
-        keys = keys.reshape(-1)  # [Ku] (shard_map hands [1, Ku])
-        Ku = keys.shape[0]
-        credit_ = credit.reshape(Ku, -1)
-
-        def one(tg):  # [T] sorted int32
-            idx = jnp.searchsorted(keys, tg)
-            idx = jnp.minimum(idx, Ku - 1)
-            hit = keys[idx] == tg  # [T]
-            vals = jnp.take(credit_, idx, axis=0)  # [T, 2*Ls]
-            # masked sum, not a matmul: TPU lowers f32 matmuls to bf16
-            # multiplies by default (~2^-8 relative error — far outside
-            # the classifier's EPS band), while a where+sum reduces in
-            # exact f32 on every backend
-            s = jnp.sum(jnp.where(hit[:, None], vals, 0.0), axis=0)
-            return s[:Ls], s[Ls:]
-
-        return jax.vmap(one)(rows)
-
-    return score
-
-
-class DeviceScorer:
-    """Jitted scorer with the corpus table committed to device memory.
-
-    The table is uploaded exactly once (at construction); every
-    subsequent call ships only the gram rows. With a mesh, rows shard
-    over 'data' and the table over 'model' via shard_map; output is the
-    gathered [B, m*Ls] score pair. Instances are cached per (mesh) by
-    :func:`get_scorer`, so repeated scans — and repeated classifier
-    instances — reuse the same HBM-resident buffers.
-    """
-
-    def __init__(self, table: CorpusTable, mesh=None):
-        import jax
-
-        self.table = table
-        self.mesh = mesh
-        score = build_score_fn(table.lic_per_shard)
-        host_arrays = (table.keys, table.credit)
-        from trivy_tpu.obs import recorder as flight
-
-        if mesh is None:
-            self._fn = flight.instrument_jit("ops.ngram_score", score)
-            self._gate = flight.instrument_jit(
-                "ops.ngram_gate", build_gate_fn()
-            )
-            self.corpus_device = tuple(jax.device_put(a) for a in host_arrays)
-            self.data_parallelism = 1
-        else:
-            from trivy_tpu.parallel.mesh import (
-                corpus_sharding,
-                sharded_gate_fn,
-                sharded_score_fn,
-            )
-
-            if int(mesh.shape["model"]) != table.n_shards:
-                raise ValueError(
-                    f"corpus built for {table.n_shards} model shards but "
-                    f"mesh has model={int(mesh.shape['model'])}"
-                )
-            self._fn = sharded_score_fn(score, mesh)
-            self._gate = sharded_gate_fn(build_gate_fn("model"), mesh)
-            self.corpus_device = tuple(
-                jax.device_put(a, corpus_sharding(mesh, a.ndim))
-                for a in host_arrays
-            )
-            self.data_parallelism = int(mesh.shape["data"])
-        # HBM ledger: the corpus commit is the license lane's resident
-        # footprint (uploaded once per process, lives across scans)
-        flight.note_resident(
-            "corpus", sum(int(a.nbytes) for a in host_arrays)
-        )
-        self.dispatch_count = 0  # telemetry: distinct device dispatches
-
-    def __call__(self, rows: np.ndarray):
-        """Async-dispatch one [B, T] row batch; returns the device result
-        pair (fetch with np.asarray when needed). B must be a multiple of
-        ``data_parallelism``."""
-        self.dispatch_count += 1
-        return self._fn(rows, *self.corpus_device)
-
-    def gate(self, rows: np.ndarray):
-        """Async-dispatch the candidate gate over one [B, T] row batch;
-        returns device per-row hit counts [B] int32."""
-        self.dispatch_count += 1
-        return self._gate(rows, self.corpus_device[0])
-
-
-_SCORER_CACHE: dict = {}
-_SCORER_LOCK = threading.Lock()
-
-
-def get_scorer(build_table, mesh=None) -> DeviceScorer:
-    """Process-wide scorer cache: the corpus table is device-resident
-    across scans and across classifier instances. ``build_table`` is a
-    one-arg callable (model shard count) invoked only on a cache miss;
-    the key is the mesh identity (None = default single-device
-    placement). Locked: analyzer finalizes may race from worker threads
-    and the table must upload exactly once."""
-    if mesh is None:
-        key = None
-    else:
-        key = (tuple(mesh.devices.flat), mesh.axis_names, mesh.shape["model"])
-    with _SCORER_LOCK:
-        scorer = _SCORER_CACHE.get(key)
-        if scorer is None:
-            model = 1 if mesh is None else int(mesh.shape["model"])
-            scorer = DeviceScorer(build_table(model), mesh=mesh)
-            _SCORER_CACHE[key] = scorer
-    return scorer
 
 
 def pack_gram_rows(
@@ -848,8 +606,10 @@ def build_bytes_score_fn(
         hit = (jnp.take(keys, pos) == kg) & mg
 
         # chunked credit gather: [B, CH, 2*Ls] transient per step instead
-        # of one [B, G, 2*Ls] monster (f32 matmul would be bf16 on TPU —
-        # same exactness reasoning as build_score_fn)
+        # of one [B, G, 2*Ls] monster; a masked sum, not a matmul: TPU
+        # lowers f32 matmuls to bf16 multiplies by default (~2^-8 relative
+        # error — far outside the classifier's EPS band), while a
+        # where+sum reduces in exact f32 on every backend)
         nch = Geff // CH
         pos_c = pos[:, : nch * CH].reshape(B, nch, CH).transpose(1, 0, 2)
         hit_c = hit[:, : nch * CH].reshape(B, nch, CH).transpose(1, 0, 2)
@@ -877,7 +637,8 @@ class DeviceBytesScorer:
     text rows cross the link (the arena-slab traffic the link budget
     already pays) — no host tokenization, no gram rows. Kernels compile
     lazily per width bucket. With a mesh, rows shard over 'data' and the
-    corpus over 'model' exactly like :class:`DeviceScorer`."""
+    corpus over 'model' (:func:`trivy_tpu.parallel.mesh.corpus_sharding`);
+    instances are cached per mesh by :func:`get_bytes_scorer`."""
 
     def __init__(self, table: CorpusTable32, mesh=None):
         import jax
@@ -1019,10 +780,17 @@ class DeviceBytesScorer:
         return fn(rows_dev, full)
 
 
+_SCORER_CACHE: dict = {}
+_SCORER_LOCK = threading.Lock()
+
+
 def get_bytes_scorer(build_table, mesh=None) -> DeviceBytesScorer:
-    """Process-wide raw-bytes scorer cache (same discipline as
-    :func:`get_scorer`, disjoint key space): corpus + bloom upload once
-    per (corpus, mesh) and stay HBM-resident across scans."""
+    """Process-wide raw-bytes scorer cache: corpus + bloom upload once
+    per (corpus, mesh) and stay HBM-resident across scans, and across
+    classifier instances. ``build_table`` is a one-arg callable (model
+    shard count) invoked only on a cache miss; the key is the mesh
+    identity. Locked: analyzer finalizes may race from worker threads and
+    the table must upload exactly once."""
     if mesh is None:
         key = ("bytes", None)
     else:

@@ -12,5 +12,4 @@ from trivy_tpu.parallel.mesh import (  # noqa: F401
     batch_sharding,
     get_mesh,
     pad_batch,
-    sharded_match_fn,
 )

@@ -360,149 +360,28 @@ def pad_batch(chunks: np.ndarray, multiple: int) -> np.ndarray:
     return np.concatenate([chunks, np.zeros((rem,) + chunks.shape[1:], chunks.dtype)])
 
 
-def sharded_match_fn(match_fn, mesh: Mesh, rows_multiple: int = 1):
-    """Shard a match kernel's batch axis over the mesh 'data' axis.
-
-    Uses shard_map so the kernel (XLA graph or pallas_call) runs as-is on
-    each device's local shard with zero communication; only the
-    caller-visible output gather rides ICI. Batch size must be padded to a
-    multiple of data_parallelism * rows_multiple (see :func:`pad_batch`).
-    """
-    fn = flight.instrument_jit(
-        "mesh.sharded_match",
-        _shard_map(
-            match_fn, mesh=mesh, in_specs=(P("data", None),), out_specs=P("data", None)
-        ),
-    )
-
-    def run(chunks: np.ndarray) -> jax.Array:
-        return fn(jnp.asarray(chunks))
-
-    run.data_parallelism = int(mesh.shape["data"]) * rows_multiple
-    return run
-
-
-def single_stream_match_fn(match_fn):
-    """Uniform dispatch surface for the single-stream kernel paths (plain
-    XLA graph fn, rows-multiple pallas wrapper, mesh-sharded shard_map).
-
-    The secret scanner's transfer workers drive every dispatch flavor
-    through the same ``run.dispatch(chunks) -> (async_result, device_idx)``
-    API that :func:`round_robin_match_fn` exposes; this wrapper gives the
-    one-stream paths that API (device index fixed at 0) and owns the
-    ``device.dispatch`` fault-injection gate for them, so the per-batch
-    retry ladder sees identical failure shapes on every path. Multiple
-    worker threads may call ``dispatch`` concurrently: jax dispatch is
-    async and thread-safe, which is exactly how transfers for batch N+1
-    overlap the kernel for batch N on a single device.
-    """
-
-    def dispatch(chunks: np.ndarray):
-        faults.check("device.dispatch", key="d0")
-        return match_fn(chunks), 0
-
-    def run(chunks: np.ndarray):
-        return dispatch(chunks)[0]
-
-    # deliberately no ``n_streams``: its presence is how callers (and
-    # tests) distinguish real multi-device round-robin dispatch
-    run.dispatch = dispatch
-    return run
-
-
-def round_robin_match_fn(
-    match_fn, devices=None, rows_multiple: int = 1, breaker: CircuitBreaker | None = None
-):
-    """Multi-stream dispatch: whole batches round-robin across local devices.
-
-    The mesh-sharded collective splits ONE batch across devices — every
-    batch still rides a single host→device transfer stream. This wrapper
-    instead sends each whole batch to the next device in turn, so the
-    transfer for batch N+1 (device k) overlaps the kernel for batch N
-    (device j): on multi-chip hosts the effective host→device link
-    bandwidth multiplies by the device count. No collectives are involved;
-    each dispatch is an independent per-device program (jit compiles one
-    executable per placement), and callers fetch results in dispatch order
-    exactly as with the single-device path. ``dispatch`` is thread-safe —
-    the secret scanner runs one transfer-worker thread per device so the
-    per-device host→device copies themselves overlap, not just the
-    transfer-vs-kernel phases.
-
-    Failure domain: a :class:`CircuitBreaker` (``run.breaker``) excludes a
-    device from the rotation after K consecutive failures and re-probes it
-    on a backoff schedule. Dispatch-time failures are recorded here;
-    fetch-time outcomes are attributed by the caller via
-    ``run.record_result(device, ok)`` — use ``run.dispatch(chunks)`` to get
-    the ``(out, device)`` pair that makes attribution possible. When every
-    device is open, dispatch raises :class:`DevicesUnavailable` so the
-    caller can take its last rung (host fallback) instead of spinning.
-    """
-    devices = list(devices) if devices is not None else jax.local_devices()
-    if not devices:
-        raise ValueError("round_robin_match_fn needs at least one device")
-    fn = flight.instrument_jit("mesh.round_robin_match", match_fn)
-    lock = threading.Lock()
-    state = {"next": 0}
-    breaker = breaker or CircuitBreaker(len(devices))
-
-    def dispatch(chunks: np.ndarray) -> tuple[jax.Array, int]:
-        with lock:
-            i = breaker.next_device(state["next"])
-            if i is None:
-                raise DevicesUnavailable(
-                    f"all {len(devices)} dispatch devices are circuit-broken"
-                )
-            state["next"] = (i + 1) % len(devices)
-        if rows_multiple > 1:
-            chunks = pad_batch(chunks, rows_multiple)
-        # per-stream span: each device stream gets its own trace track, so
-        # a Perfetto view shows whether transfers actually interleave
-        ctx = obs.current()
-        try:
-            faults.check("device.dispatch", key=f"d{i}")
-            with ctx.span(f"mesh.d{i}.dispatch"):
-                out = fn(jax.device_put(chunks, devices[i]))
-        except Exception:
-            breaker.record_failure(i)
-            raise
-        ctx.count(f"mesh.d{i}.batches")
-        return out, i
-
-    def run(chunks: np.ndarray) -> jax.Array:
-        return dispatch(chunks)[0]
-
-    def record_result(i: int, ok: bool) -> None:
-        if ok:
-            breaker.record_success(i)
-        else:
-            breaker.record_failure(i)
-
-    run.dispatch = dispatch
-    run.record_result = record_result
-    run.breaker = breaker
-    run.n_streams = len(devices)
-    run.devices = devices
-    return run
-
-
 class StagedDispatch:
     """Place a batch on a device ONCE, then run several kernels against the
     resident rows — the fused-pass dispatch surface (prefilter + anchored
     match + license gram gate all read the same upload).
 
-    Three placement flavors behind one API, mirroring the match-fn wrappers
-    above:
+    Three placement flavors behind one API:
 
     - ``mesh``: rows shard over 'data' via one sharded ``device_put``;
       stages are shard_map'd row-wise kernels.
     - ``devices`` (round-robin): whole batches to the next healthy device,
       per-device :class:`CircuitBreaker`, per-stage jit cached per device.
+      The transfer for batch N+1 (device k) overlaps the kernel for batch
+      N (device j), so the host→device link bandwidth multiplies by the
+      device count; when every device is open, :meth:`put` raises
+      :class:`DevicesUnavailable` so the caller takes its host fallback.
     - neither: default placement, device index fixed at 0.
 
     ``put`` owns batch-axis padding and the ``device.dispatch`` fault gate
-    (one check per batch, exactly like the legacy ``dispatch``); ``run``
-    launches a named stage asynchronously on the resident array. Fetch-time
-    outcomes feed back through ``record_result`` as before.
+    (one check per batch); ``run`` launches a named stage asynchronously on
+    the resident array. Fetch-time outcomes feed back through
+    ``record_result``, which closes the busy interval and feeds the
+    breaker.
     """
 
     def __init__(self, mesh=None, devices=None, rows_multiple: int = 1,
@@ -624,51 +503,8 @@ class StagedDispatch:
         self.busy.begin(None)
         return dev, None
 
-    def put_parts(self, arrays: tuple):
-        """Pad-free multi-array placement: ship a tuple of host arrays to
-        ONE device as a unit — the compressed-slab wire frame (flat byte
-        buffer + per-row offs/clen/mode), whose decompress stage expands
-        them into the resident ``[B, C]`` rows the other stages read.
-        Same breaker/fault/span ladder as :meth:`put`; the caller owns
-        shape discipline (arrays are shipped exactly as given). The mesh
-        flavor is unsupported — a flat wire buffer has no row axis to
-        shard, so the scanner gates compression off under a mesh."""
-        if self.mesh is not None:
-            raise ValueError(
-                "put_parts: compressed frames cannot shard over a mesh"
-            )
-        if self.devices:
-            with self._lock:
-                i = self.breaker.next_device(self._next)
-                if i is None:
-                    raise DevicesUnavailable(
-                        f"all {len(self.devices)} dispatch devices are "
-                        f"circuit-broken"
-                    )
-                self._next = (i + 1) % len(self.devices)
-            try:
-                faults.check("device.dispatch", key=f"d{i}")
-                with obs.current().span(f"mesh.d{i}.dispatch"):
-                    dev = tuple(
-                        jax.device_put(a, self.devices[i]) for a in arrays
-                    )
-            except Exception:
-                self.breaker.record_failure(i)
-                raise
-            obs.current().count(f"mesh.d{i}.batches")
-            self.busy.begin(i)
-            return dev, i
-        faults.check("device.dispatch", key="d0")
-        dev = tuple(jax.device_put(a) for a in arrays)
-        self.busy.begin(None)
-        return dev, None
-
     def run(self, name: str, dev, device_idx=None):
-        """Launch stage ``name`` on an already-resident batch (async).
-        ``dev`` may be a tuple (a :meth:`put_parts` frame) — the stage is
-        then called with the parts as positional args."""
-        if isinstance(dev, tuple):
-            return self._stages[name](*dev)
+        """Launch stage ``name`` on an already-resident batch (async)."""
         ops = self._operands.get(name)
         if ops:
             key = (device_idx or 0) if self.devices else None
@@ -692,60 +528,6 @@ def corpus_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
     replicated across 'data'. Used to commit the license n-gram corpus
     (ops/ngram_score) to device memory once, HBM-resident across scans."""
     return NamedSharding(mesh, P(*(("model",) + (None,) * (ndim - 1))))
-
-
-def sharded_score_fn(score_fn, mesh: Mesh):
-    """Shard an n-gram corpus scoring kernel over the 2D mesh: text gram
-    rows over 'data', corpus-fingerprint shards over 'model' (PAPER.md §7
-    — the first user of the mesh 'model' axis). Each device scores its
-    local row block against its local license slab with zero
-    communication; out_specs reassemble the global [B, L] score pair.
-
-    ``score_fn`` is :func:`trivy_tpu.ops.ngram_score.build_score_fn`'s
-    (rows, keys, credit) -> (full_w, phrase_hits). Batch size must be a
-    multiple of the mesh data parallelism (see ``run.data_parallelism``).
-    """
-    fn = flight.instrument_jit(
-        "mesh.sharded_score",
-        _shard_map(
-            score_fn,
-            mesh=mesh,
-            in_specs=(
-                P("data", None),  # gram rows [B/d, T]
-                P("model", None),  # corpus keys [m, Ku] -> local [1, Ku]
-                P("model", None, None),  # credit [m, Ku, 2*Ls]
-            ),
-            out_specs=(P("data", "model"), P("data", "model")),
-        ),
-    )
-
-    def run(rows, keys, credit):
-        return fn(jnp.asarray(rows), keys, credit)
-
-    run.data_parallelism = int(mesh.shape["data"])
-    return run
-
-
-def sharded_gate_fn(gate_fn, mesh: Mesh):
-    """Shard the n-gram candidate gate: rows over 'data', corpus keys
-    over 'model'; ``gate_fn`` must be built with ``psum_axis='model'``
-    (ops/ngram_score.build_gate_fn) so per-shard intersection counts
-    reduce to global counts over ICI."""
-    fn = flight.instrument_jit(
-        "mesh.sharded_gate",
-        _shard_map(
-            gate_fn,
-            mesh=mesh,
-            in_specs=(P("data", None), P("model", None)),
-            out_specs=P("data"),
-        ),
-    )
-
-    def run(rows, keys):
-        return fn(jnp.asarray(rows), keys)
-
-    run.data_parallelism = int(mesh.shape["data"])
-    return run
 
 
 def sharded_bytes_gate_fn(gate_fn, mesh: Mesh):
@@ -773,9 +555,10 @@ def sharded_bytes_gate_fn(gate_fn, mesh: Mesh):
 def sharded_bytes_score_fn(score_fn, mesh: Mesh):
     """Shard the raw-bytes scoring kernel (ops/ngram_score
     build_bytes_score_fn): uint8 rows over 'data', corpus shards over
-    'model'. Score pairs reassemble as [B, m*Ls] like sharded_score_fn;
-    the third output (per-row unique-gram count, corpus-independent and
-    identical on every model shard) stays partitioned over 'data' only.
+    'model'. Score pairs reassemble as [B, m*Ls] (each device scores its
+    local row block against its local license slab); the third output
+    (per-row unique-gram count, corpus-independent and identical on every
+    model shard) stays partitioned over 'data' only.
     """
     def body(rows, keys, credit):
         full_w, phrase, n_uniq = score_fn(rows, keys, credit)
@@ -795,6 +578,10 @@ def sharded_bytes_score_fn(score_fn, mesh: Mesh):
                 P("model", None, None),  # credit [m, Ku, 2*Ls]
             ),
             out_specs=(P("data", "model"), P("data", "model"), P("data")),
+            # the credit gather's scan carries row-local state (its zero
+            # init is not 'data'-varying): no vma check, as for the rule
+            # pass stage
+            check_vma=False,
         ),
     )
 

@@ -72,9 +72,10 @@ The feed path sends link bytes ≪ corpus bytes:
   only match bytes (+1 boundary byte), so packing can never suppress a hit;
   cross-file windows only add false candidates that the exact host confirm
   discards.
-- **round-robin multi-stream dispatch** (parallel.mesh.round_robin_match_fn)
-  sends whole batches to each local device in turn so transfers overlap
-  kernels across devices, multiplying effective link bandwidth.
+- **round-robin multi-stream dispatch** (parallel.mesh.StagedDispatch
+  over several devices) sends whole batches to each local device in turn
+  so transfers overlap kernels across devices, multiplying effective link
+  bandwidth.
 
 Failure domains (README "Robustness"): a failed batch re-dispatches up to
 ``batch_retries`` times (OOM-shaped errors split the batch in half
@@ -101,7 +102,6 @@ import numpy as np
 
 from trivy_tpu import faults, log, obs
 from trivy_tpu.obs import recorder as flight
-from trivy_tpu.secret.compress import COMPRESS_MIN_RATIO, CompressedSlab
 from trivy_tpu.secret.device_compile import CompiledRules, build_tables, compile_rules
 from trivy_tpu.secret.feed import ChunkArena, row_windows
 from trivy_tpu.secret.engine import ScannerConfig, SecretScanner
@@ -132,9 +132,6 @@ ARENA_MARGIN = 2
 # concurrent device_put calls from separate threads overlap transfers with
 # each other as well as with kernels (TRIVY_TPU_FEED_STREAMS=1 serializes)
 SINGLE_DEVICE_STREAMS = 4
-# link classes (parallel.mesh.link_class) on which --secret-compress auto
-# ships raw slabs: the device decode costs more than the wire bytes it saves
-RAW_LINKS = ("host", "pcie")
 # workers for exact host confirmation (overlaps device-result waits)
 CONFIRM_WORKERS = 4
 # bounded in-process LRU for the chunk-dedup hit cache; most entries are an
@@ -147,11 +144,8 @@ HIT_CACHE_ENTRIES = 1 << 16
 # (v2: values grew prefilter candidate masks + nfa/license flags;
 # v3: the fingerprint folds the --secret-config file content and persisted
 # lookups/writes are batched through secret/hitstore.py;
-# v4: compressed slab wire format — rows may now reach the kernels through
-# the device decompressor, whose output must be byte-identical to a raw
-# upload; the bump invalidates stores written by builds without that
-# parity guarantee. Keys still hash UNCOMPRESSED row content, so entries
-# stay codec-invariant: toggling --secret-compress never flips a key;
+# v4: invalidated stores written by builds whose device input was not
+# guaranteed byte-identical to the raw rows (keys hash raw row content);
 # v5: the table-driven rule pass checks every rule on every row, so a
 # verdict's nfa_ran is always true)
 HIT_CACHE_VERSION = 5
@@ -244,22 +238,6 @@ class ScanStats:
         "rows_prefilter_hit",  # rows with >=1 candidate rule
         "license_rows_gated",    # arena rows the license gram gate read
         "license_rows_flagged",  # rows that flagged a license candidate
-        # compressed wire format (secret/compress.py): bytes_uploaded above
-        # always counts ACTUAL link traffic (compressed wire + framing when
-        # a batch ships compressed); bytes_raw_equiv is what those batches
-        # would have cost raw, so ratio = uploaded/raw_equiv-side math
-        # needs no second bookkeeping path
-        "bytes_compressed",      # wire+framing bytes of compressed batches
-        "bytes_raw_equiv",       # raw padded bytes those batches replaced
-        "bytes_raw_fallback",    # padded bytes shipped raw (didn't pay /
-                                 # codec error / binary-heavy batch)
-        "bytes_gated",           # corpus bytes the zero gate kept off the
-                                 # link (all-zero rows resolve via dedup)
-        "bytes_gated_binary",    # raw bytes of binary rows shipped RAW
-                                 # inside compressed frames
-        "chunks_gated_zero",     # rows the zero gate resolved
-        "batches_compressed",    # batches that shipped compressed
-        "batches_raw_fallback",  # batches that fell back to raw slabs
     )
 
     def __init__(self):
@@ -313,12 +291,6 @@ class TpuSecretScanner:
         bucket_rungs: int = 0,  # dispatch bucket-ladder depth; 0 = default
         hit_cache_bytes: int = 0,  # dedup LRU byte budget; 0 = tuning's
         # dedup_store_mb (default hitstore.DEFAULT_STORE_MB)
-        compress: str = "",  # compressed slab wire format: 'auto' (on
-        # only for a link class outside RAW_LINKS, never under a mesh),
-        # 'on', 'off'; "" = tuning's --secret-compress resolution
-        compress_min_ratio: float = 0.0,  # per-batch wire budget as a
-        # fraction of the raw slab — a batch that can't compress below
-        # this ships raw; 0 = tuning / COMPRESS_MIN_RATIO default
     ):
         import jax
 
@@ -513,77 +485,6 @@ class TpuSecretScanner:
             buckets.append(buckets[-1] // 2)
         self._buckets = sorted(buckets)
 
-        # -- compressed slab wire format (secret/compress.py) ---------------
-        # 'auto' opts in only on a link slow enough for the codec to pay:
-        # not 'host' (the CPU backend shares one memory bus — compressing
-        # for it only burns host cycles) and not 'pcie' (measured on a
-        # v5e, the device decode took ~37% of device time for a ~13% wire
-        # saving that the transfer streams already overlap with kernels).
-        # A slower link opts in through TRIVY_TPU_LINK_CLASS or 'on'. No
-        # mesh either (a flat wire buffer has no row axis to shard_map
-        # over). Zero-cost-when-off bar: an 'off' scanner builds no codec
-        # tables, registers no decompress stage, and allocates no
-        # wire-rung state — bench --smoke asserts this.
-        from trivy_tpu.parallel.mesh import link_class
-
-        mode = compress or tuning.compress or "auto"
-        if mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"compress={mode!r}: use 'auto', 'on', or 'off'"
-            )
-        comp_on = mode == "on" or (
-            mode == "auto" and link_class(platform) not in RAW_LINKS
-        )
-        if comp_on and mesh is not None:
-            if mode == "on":
-                logger.warning(
-                    "--secret-compress is unsupported under a sharded mesh "
-                    "(a flat wire buffer has no row axis to shard); "
-                    "shipping raw slabs"
-                )
-            comp_on = False
-        if comp_on and self.chunk_len % 8:
-            logger.warning(
-                "--secret-compress needs chunk_len %% 8 == 0 (7-bit "
-                "packing), got %d; shipping raw slabs", self.chunk_len,
-            )
-            comp_on = False
-        self.compress_min_ratio = float(
-            compress_min_ratio or tuning.compress_min_ratio
-            or COMPRESS_MIN_RATIO
-        )
-        if not 0.0 < self.compress_min_ratio <= 1.0:
-            raise ValueError(
-                f"compress_min_ratio={self.compress_min_ratio} out of (0, 1]"
-            )
-        self._codec = None
-        # per rows-bucket wire-size ladder {top, top/2, top/4, top/8}: the
-        # wire buffer buckets to a rung so decompress compiles once per
-        # (rows, rung) pair, and a very compressible batch (zero pages,
-        # config trees) rides a small rung instead of padding to the top
-        self._wire_rungs: dict[int, list[int]] = {}
-        if comp_on:
-            from trivy_tpu.ops.decompress import build_decompress_fn
-            from trivy_tpu.secret.compress import SlabCodec
-
-            self._codec = SlabCodec(self.chunk_len)
-            self._staged.add_stage(
-                "decompress",
-                build_decompress_fn(
-                    self.chunk_len, self._codec.tab_bytes,
-                    self._codec.tab_len,
-                ),
-                out_axes=2,
-            )
-            for b in self._buckets:
-                top = -(-int(b * self.chunk_len * self.compress_min_ratio)
-                        // 128) * 128
-                rungs = [top]
-                while len(rungs) < 4 and rungs[-1] // 2 >= 1024:
-                    rungs.append(rungs[-1] // 2)
-                self._wire_rungs[b] = sorted(rungs)
-        self.compress_on = comp_on
-
     # -- dedup hit cache ----------------------------------------------------
     #
     # Cached value per row digest (the "row verdict"): a 4-tuple
@@ -640,8 +541,8 @@ class TpuSecretScanner:
     def warm_buckets(self) -> None:
         """Compile every (bucket shape × placement × stage) combination
         outside any timed region — the rule pass (+ the license gram gate,
-        when registered) per rung, and the decompress stage per (rung,
-        wire rung) — so the first real batch never pays a compile.
+        when registered) per rung — so the first real batch never pays a
+        compile.
 
         The compiles run concurrently: each is an independent XLA/Mosaic
         compile that releases the GIL, and a cold start of the built-in
@@ -662,28 +563,11 @@ class TpuSecretScanner:
                         np.zeros((b, self.chunk_len), dtype=np.uint8),
                         self._staged.pad_to,
                     )
-                    jobs.append((place, didx, (rows,), name))
-        if self._codec is not None:
-            # one decompress compile per (rows, wire-rung) pair; the
-            # downstream stages reuse the raw-shape executables compiled
-            # above (the decoder's [b, C] output IS the raw shape)
-            for didx, place in enumerate(self._staged.placements()):
-                for b in self._buckets:
-                    for rung in self._wire_rungs[b]:
-                        frame = (
-                            np.zeros(rung, dtype=np.uint8),
-                            np.zeros(b, dtype=np.int32),
-                            np.zeros(b, dtype=np.int32),
-                            np.zeros(b, dtype=np.uint8),
-                        )
-                        jobs.append((place, didx, frame, "decompress"))
+                    jobs.append((place, didx, rows, name))
 
         def warm(job) -> None:
-            place, didx, parts, name = job
-            dev = tuple(place(a) for a in parts)
-            np.asarray(
-                self._staged.run(name, dev if len(dev) > 1 else dev[0], didx)
-            )
+            place, didx, rows, name = job
+            np.asarray(self._staged.run(name, place(rows), didx))
 
         workers = max(1, min(len(jobs), os.cpu_count() or 1))
         with ThreadPoolExecutor(workers, thread_name_prefix="warm") as pool:
@@ -765,8 +649,6 @@ class TpuSecretScanner:
             "arena_slabs": self.arena_slabs,  # 0 = derived per scan
             "bucket_ladder": list(self._buckets),
             "controller": bool(self.tuning.controller),
-            "compress": self.compress_on,
-            "compress_min_ratio": self.compress_min_ratio,
             "topology": self.tuning.topology,
             "source": dict(self.tuning.source),
         }
@@ -866,7 +748,7 @@ class _ScanRun:
       one vectorized strided gather per slab run, not per-row Python —
       then hands full slabs to the bounded dispatch queue.
     - **transfer streams** (``scanner.feed_streams`` workers): each pulls
-      slabs, dispatches through ``scanner._match.dispatch`` (round-robin
+      slabs, places each through ``scanner._staged`` (round-robin
       across devices or concurrent streams into one device), keeps a
       bounded in-flight window (double buffering: transfer N+1 overlaps
       kernel N), fetches the oldest result, releases the slab, and
@@ -908,9 +790,6 @@ class _ScanRun:
         self.error: BaseException | None = None
         self.degraded = False
         self.stop = threading.Event()
-        # wire-accounting baseline: scanner stats are cumulative across
-        # scans, so this run's compression ratio needs a delta
-        self._stats0 = sc.stats.snapshot()
         self.feed_done = threading.Event()  # input exhausted (or failed)
         streams = sc.feed_streams
         # online tuning (trivy_tpu/tuning.py): the controller adapts the
@@ -1110,32 +989,6 @@ class _ScanRun:
             "arena_slabs": self.arena.n_slabs,
             "controller": ctl_summary,
         }
-        # wire-format accounting for THIS run: the `wire` block in
-        # --metrics-out, the per-rep wire_compression_ratio in bench, and
-        # the process-global gauge on GET /metrics. Compression-off scans
-        # publish nothing (no block, no gauge registration) — the
-        # zero-cost-when-off bar bench --smoke enforces
-        if self.sc._codec is not None:
-            from trivy_tpu.obs.metrics import REGISTRY
-
-            d = self.sc.stats.snapshot()
-            w = {k: d[k] - self._stats0[k] for k in (
-                "bytes_compressed", "bytes_raw_equiv", "bytes_raw_fallback",
-                "bytes_gated", "bytes_gated_binary", "chunks_gated_zero",
-                "batches_compressed", "batches_raw_fallback",
-            )}
-            raw_equiv = w["bytes_raw_equiv"] + w["bytes_raw_fallback"]
-            shipped = w["bytes_compressed"] + w["bytes_raw_fallback"]
-            ratio = shipped / raw_equiv if raw_equiv else 1.0
-            wire = {"compress": True, "compression_ratio": ratio, **w}
-            self.sc._last_wire = wire
-            if self.ctx is not None:
-                self.ctx.wire = wire
-            REGISTRY.gauge(
-                "trivy_tpu_wire_compression_ratio",
-                "Link bytes shipped per raw slab byte on the most recent "
-                "compressed-feed scan (1.0 = raw)",
-            ).set(ratio)
 
     # -- shared control -----------------------------------------------------
 
@@ -1449,27 +1302,7 @@ class _ScanRun:
         def recover(batch, meta, slab_id, retries, err) -> list:
             """Ladder decision for one failed batch: work items to
             re-dispatch, or raise when the ladder is exhausted. Always
-            ends the source slab's ownership.
-
-            A compressed batch degrades to raw rows FIRST (the host
-            reference decoder, byte-identical to the device kernel by the
-            codec fuzz contract): every rung of the ladder — whole-batch
-            retry, OOM halves, host fallback — then runs on plain rows,
-            so a decoder-side failure can never loop through the codec."""
-            if isinstance(batch, CompressedSlab):
-                try:
-                    batch = sc._codec.decode_slab(batch)
-                except Exception as dec_err:
-                    # an undecodable frame is an encoder bug, not a device
-                    # fault: no retry can fix it — escalate to the exact
-                    # host path, which rereads original file bytes
-                    logger.warning(
-                        "compressed batch unrecoverable after device error "
-                        "(%s); decode failed: %s", err, dec_err,
-                    )
-                    if slab_id is not None:
-                        self.arena.release(slab_id)
-                    raise _DeviceFailed(err)
+            ends the source slab's ownership."""
             if isinstance(err, DevicesUnavailable):
                 if slab_id is not None:
                     self.arena.release(slab_id)
@@ -1530,25 +1363,10 @@ class _ScanRun:
                 placed = False
                 didx = None
                 try:
-                    if isinstance(b, CompressedSlab):
-                        # ship the wire frame, expand on device ahead of
-                        # every other stage; the decompressed rows are
-                        # the resident array the stages read, so
-                        # downstream dispatch is shape-identical to the
-                        # raw path. The frame placement stays in the
-                        # upload bucket (it IS the link transfer); only
-                        # the decode launch is codec time
-                        with ctx.span("secret.dispatch"):
-                            parts, didx = staged.put_parts(b.arrays())
-                            placed = True
-                        with ctx.span("secret.decompress"):
-                            dev = staged.run("decompress", parts, didx)
-                        h: dict = {}
-                    else:
-                        with ctx.span("secret.dispatch"):
-                            dev, didx = staged.put(b)
-                            placed = True
-                        h = {}
+                    with ctx.span("secret.dispatch"):
+                        dev, didx = staged.put(b)
+                        placed = True
+                    h: dict = {}
                     with ctx.span("secret.dispatch"):
                         h["rules"] = staged.run("rules", dev, didx)
                         if want_lic(m):
@@ -1723,9 +1541,6 @@ class _ScanRun:
                 lic_gate.skip(path)
 
         persist_on = dedup and sc._hit_store.backend is not None
-        # compressed feed on -> the zero gate is on (all-zero rows resolve
-        # through a forced dedup key instead of crossing the link again)
-        zero_gate = sc._codec is not None
         slab_id: int | None = None
         slab: np.ndarray | None = None
         used = 0
@@ -1845,57 +1660,6 @@ class _ScanRun:
                 slab[: len(live)] = slab[np.asarray(live)]
             meta = [meta[i] for i in live]
 
-        def compress_slab(n: int):
-            """Try to compress the assembled slab's live rows into a wire
-            frame riding a SECOND arena slab (the wire stays in pinned,
-            reused memory and inherits arena backpressure). Returns the
-            dispatch-queue item ``(dst_slab_id, CompressedSlab, meta)``,
-            or None for the raw fallback: the batch can't beat the
-            min-ratio wire budget, or the encoder errored (degrade to raw
-            is the codec's failure contract, never a failed scan)."""
-            dst_id = None
-            try:
-                with ctx.span("secret.compress"):
-                    plan = sc._codec.plan(slab[: len(meta)])
-                    total = plan.total()
-                    rung = next(
-                        (r for r in sc._wire_rungs[n] if r >= total), None
-                    )
-                    if rung is None:
-                        return None  # doesn't pay — ship the raw slab
-                    got = self.arena.acquire(self._aborted)
-                    if got is None:
-                        raise _FeedAbort
-                    dst_id, dst = got
-                    cs = sc._codec.emit(plan, n, rung, dst.reshape(-1))
-            except _FeedAbort:
-                raise
-            except Exception as e:
-                logger.warning(
-                    "slab compression failed (%s: %s); shipping raw",
-                    type(e).__name__, e,
-                )
-                if dst_id is not None:
-                    self.arena.release(dst_id)
-                return None
-            wire = rung + cs.frame_bytes()
-            bin_rows = int(plan.binary.sum())
-            stats.add(
-                bytes_uploaded=wire,
-                bytes_compressed=wire,
-                bytes_raw_equiv=n * chunk_len,
-                bytes_gated_binary=bin_rows * chunk_len,
-                batches_compressed=1,
-            )
-            if enabled:
-                ctx.count("secret.bytes_uploaded", wire)
-                ctx.count("secret.bytes_compressed", wire)
-                if bin_rows:
-                    ctx.count(
-                        "secret.bytes_gated_binary", bin_rows * chunk_len
-                    )
-            return (dst_id, cs, meta)
-
         def flush() -> None:
             nonlocal slab_id, slab, used, meta
             flush_copies()
@@ -1917,33 +1681,13 @@ class _ScanRun:
                     emit_pack()
                 return
             n = next(b for b in sc._buckets if b >= len(meta))
-            item = None
-            if sc._codec is not None:
-                item = compress_slab(n)
-            if item is None:
-                # raw slab (codec off, fallback, or incompressible batch)
-                stats.add(bytes_uploaded=n * chunk_len)
-                if sc._codec is not None:
-                    stats.add(
-                        bytes_raw_fallback=n * chunk_len,
-                        batches_raw_fallback=1,
-                    )
-                    if enabled:
-                        ctx.count(
-                            "secret.bytes_raw_fallback", n * chunk_len
-                        )
-                if enabled:
-                    ctx.count("secret.bytes_uploaded", n * chunk_len)
-                item = (slab_id, slab[:n], meta)
-            else:
-                # the wire frame rides its own slab; the source slab is
-                # done the moment the encoder copied out of it
-                self.arena.release(slab_id)
+            stats.add(bytes_uploaded=n * chunk_len)
             if enabled:
+                ctx.count("secret.bytes_uploaded", n * chunk_len)
                 ctx.sample("secret.queue_depth", self.in_q.qsize())
-            ok = self._put_slab(item)
+            ok = self._put_slab((slab_id, slab[:n], meta))
             if not ok:
-                self.arena.release(item[0])
+                self.arena.release(slab_id)
             slab_id = None
             slab = None
             used = 0
@@ -1967,15 +1711,7 @@ class _ScanRun:
             pack_pending.clear()
             pack_len = 0
             key = None
-            # the zero gate extends to single-file pack rows (a tree of
-            # zero-filled placeholder files): same forced-key trick as
-            # feed_big's chunk rows, same digest domain
-            single_zero = (
-                zero_gate
-                and len(items) == 1
-                and not any(items[0][1])
-            )
-            if dedup or single_zero:
+            if dedup:
                 if len(items) == 1:
                     # single-segment row == plain chunk-row layout: share the
                     # plain digest domain so it dedups across both paths
@@ -1994,10 +1730,6 @@ class _ScanRun:
             nbytes = sum(len(d) for _, d in items)
             stats.add(chunks=1)
             if route_row(key, segs, nbytes):
-                if single_zero:
-                    stats.add(bytes_gated=nbytes, chunks_gated_zero=1)
-                    if enabled:
-                        ctx.count("secret.bytes_gated", nbytes)
                 return
             ensure_slab()
             row = slab[used]
@@ -2046,28 +1778,13 @@ class _ScanRun:
             uploaded = 0
             for s in starts:
                 end = min(s + chunk_len, n)
-                # zero gate (compressed feed's "never ship unscannable
-                # bytes"): all-zero rows — sparse images, zero pages —
-                # get a forced dedup key even with dedup off, so the
-                # first one ships (possibly compressed 8x) and every
-                # other resolves through the ordinary dedup/coalesce
-                # machinery. Soundness-free by construction: the row
-                # still rides the real verdict path once, so a ruleset
-                # that somehow matches NUL runs keeps its findings
-                is_zero = zero_gate and not arr[s:end].any()
                 key = (
                     blake2b(arr[s:end], digest_size=16, key=fp_key).digest()
-                    if dedup or is_zero
+                    if dedup
                     else None
                 )
                 segs = [(fidx, s, s + chunk_len)]
                 if route_row(key, segs, end - s):
-                    if is_zero:
-                        stats.add(
-                            bytes_gated=end - s, chunks_gated_zero=1
-                        )
-                        if enabled:
-                            ctx.count("secret.bytes_gated", end - s)
                     continue
                 ensure_slab()
                 if end - s == chunk_len:

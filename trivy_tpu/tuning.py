@@ -115,19 +115,6 @@ def validate_interval(value, name: str) -> float:
     return v
 
 
-def validate_ratio(value, name: str) -> float:
-    """A (0, 1] fraction from flag/env input, rejected loudly otherwise —
-    a wire budget of 0 would force every batch raw silently, and > 1
-    would 'compress' batches into more bytes than raw."""
-    try:
-        v = float(value)
-    except (TypeError, ValueError):
-        raise ValueError(f"{name}: not a number: {value!r}") from None
-    if math.isnan(v) or not 0.0 < v <= 1.0:
-        raise ValueError(f"{name}: must be in (0, 1], got {value!r}")
-    return v
-
-
 def topology_fingerprint(devices=None, link: str | None = None) -> str:
     """``<device kind>:<device count>:<link class>`` — the key autotune
     records live under. Device kind/count come from the jax device set;
@@ -161,12 +148,6 @@ class TuningConfig:
     dedup_store_mb: int = 0  # dedup hit-store LRU byte budget (0 = 32 MB)
     license_gate_block_min: int = 0  # shingle-gate density floor (0 = 16)
     license_row_width: int = 0  # license row-width ladder cap (0 = full)
-    # compressed slab wire format (secret/compress.py). Modes, not int
-    # optima — like controller/tuning_interval they resolve CLI > env >
-    # default with provenance, but never from an autotune record
-    compress: str = ""          # 'auto' | 'on' | 'off' ('' = auto at use)
-    compress_min_ratio: float = 0.0  # per-batch wire budget fraction
-    # (0 = codec default 0.875, the 7-bit-packing line)
     controller: bool = False          # online mid-scan adaptation
     tuning_interval: float = DEFAULT_TUNING_INTERVAL
     # fleet replica-poller cadence (0 = off: no poller thread, no parser
@@ -191,8 +172,6 @@ class TuningConfig:
             "dedup_store_mb": self.dedup_store_mb,
             "license_gate_block_min": self.license_gate_block_min,
             "license_row_width": self.license_row_width,
-            "compress": self.compress,
-            "compress_min_ratio": self.compress_min_ratio,
             "controller": self.controller,
             "tuning_interval": self.tuning_interval,
             "fleet_telemetry_interval": self.fleet_telemetry_interval,
@@ -359,46 +338,6 @@ def resolve_tuning(opts: dict | None = None, env: dict | None = None,
             value, source = 0, "default"
         setattr(cfg, knob, value)
         cfg.source[knob] = source
-    # compressed-feed mode + wire budget (CLI > env > default, with
-    # provenance; no autotune layer — the codec is a mode, not an optimum)
-    raw_cmp = opts.get("secret_compress")
-    if raw_cmp is None or raw_cmp == "":
-        env_cmp = str(env.get("TRIVY_TPU_SECRET_COMPRESS", "")).lower()
-        if env_cmp:
-            if env_cmp in ("1", "true", "yes", "on"):
-                env_cmp = "on"
-            elif env_cmp in ("0", "false", "no", "off"):
-                env_cmp = "off"
-            if env_cmp not in ("auto", "on", "off"):
-                raise ValueError(
-                    f"TRIVY_TPU_SECRET_COMPRESS: use auto/on/off, got "
-                    f"{env_cmp!r}"
-                )
-            cfg.compress, cfg.source["compress"] = env_cmp, "env"
-        else:
-            cfg.source["compress"] = "default"
-    else:
-        v = str(raw_cmp).lower()
-        if v not in ("auto", "on", "off"):
-            raise ValueError(
-                f"--secret-compress: use auto/on/off, got {raw_cmp!r}"
-            )
-        cfg.compress, cfg.source["compress"] = v, "cli"
-    raw_mr = opts.get("secret_compress_min_ratio")
-    if raw_mr is None or raw_mr == 0:
-        env_mr = env.get("TRIVY_TPU_SECRET_COMPRESS_MIN_RATIO") or None
-        if env_mr is not None:
-            cfg.compress_min_ratio = validate_ratio(
-                env_mr, "TRIVY_TPU_SECRET_COMPRESS_MIN_RATIO"
-            )
-            cfg.source["compress_min_ratio"] = "env"
-        else:
-            cfg.source["compress_min_ratio"] = "default"
-    else:
-        cfg.compress_min_ratio = validate_ratio(
-            raw_mr, "--secret-compress-min-ratio"
-        )
-        cfg.source["compress_min_ratio"] = "cli"
     # controller + cadence (no autotune layer: they are modes, not optima)
     raw_ctl = opts.get("tuning_controller")
     if raw_ctl is None:
